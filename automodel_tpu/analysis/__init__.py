@@ -8,7 +8,7 @@ Two pillars (see ``docs/guides/static_analysis.md``):
   flagship legs are checked in under ``tests/data/golden_census/`` and
   asserted by tier-1 (``tests/unit_tests/test_analysis.py``).
 * :mod:`automodel_tpu.analysis.lint` — AST-based repo invariant linter
-  (rules L001-L005), zero third-party deps; run by ``tools/lint.py`` and
+  (rules L002-L007), zero third-party deps; run by ``tools/lint.py`` and
   the tier-1 ``tests/unit_tests/test_lint_clean.py``.
 """
 

@@ -32,14 +32,18 @@ import math
 import re
 from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple
 
-# Jaxpr-level collective primitives (the shard_map vocabulary).
+# Jaxpr-level collective primitives (the shard_map vocabulary), by the
+# names the installed JAX emits.  ``check_vma=True`` shard_maps spell psum /
+# all_gather as ``*_invariant``; the census counts both under the base name.
 _COLLECTIVE_PRIMS = {
-    "psum", "pmax", "pmin", "ppermute", "pbroadcast",
-    "all_gather", "all_to_all", "reduce_scatter", "psum_scatter",
+    "psum", "pmax", "pmin", "ppermute", "psend", "precv", "pbroadcast",
+    "all_gather", "all_to_all", "ragged_all_to_all", "reduce_scatter",
 }
+_INVARIANT_SUFFIX = "_invariant"
 # Host-transfer / callback primitives: none of these belong in a hot-path
-# step function.
-_HOST_PRIMS = {"infeed", "outfeed", "copy_to_host_async"}
+# step function.  Every ``*callback*`` primitive counts as well;
+# ``jax.debug.print`` lowers to its own ``debug_print``.
+_HOST_PRIMS = {"infeed", "outfeed", "debug_print"}
 
 # Matches both sync ops ("= f32[64,64]{1,0} all-gather(...)") and the async
 # -start forms XLA:TPU emits by default, whose TUPLE result types contain
@@ -226,7 +230,7 @@ def jaxpr_census(closed_jaxpr) -> CollectiveCensus:
     """Walk a ClosedJaxpr (recursively) into a :class:`CollectiveCensus`."""
     census = CollectiveCensus()
     for eqn in iter_eqns(closed_jaxpr):
-        name = eqn.primitive.name
+        name = eqn.primitive.name.removesuffix(_INVARIANT_SUFFIX)
         if name in _COLLECTIVE_PRIMS:
             key = _axis_key(eqn)
             table = census.collectives.setdefault(name, {})

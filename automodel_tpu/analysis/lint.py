@@ -4,11 +4,6 @@ Every rule encodes an invariant that a past PR was bitten by (or that the
 next frontier — pp, multi-slice, the kernel library — will be bitten by if
 it drifts silently):
 
-* **L001** — direct use of version-moved JAX APIs (``jax.experimental.
-  shard_map`` / ``jax.shard_map``, ``lax.axis_size``, ``pltpu.
-  CompilerParams`` / ``TPUCompilerParams``) outside the one sanctioned
-  shim, ``utils/jax_compat.py``.  PR-3/4 each lost a debugging session to
-  one of these moving between the JAX releases this framework spans.
 * **L002** — enum-like config domains (module-level ``FOO_LAYOUTS``-style
   constants of string literals) not registered in
   ``config/loader.py::_enum_fields``: an unregistered knob means a typo'd
@@ -29,8 +24,8 @@ it drifts silently):
   least one ``pytest.mark.fault`` test — an undrilled crash site is a
   crash-safety claim nobody ever tested.
 * **L006** — raw Pallas construction (``pl.BlockSpec`` / ``pl.GridSpec`` /
-  ``pltpu.PrefetchScalarGridSpec``, or direct ``pallas_tpu_compiler_params``
-  calls) outside ``ops/kernel_lib/``: every kernel builds its blocks,
+  ``pltpu.PrefetchScalarGridSpec`` / ``pltpu.CompilerParams``) outside
+  ``ops/kernel_lib/``: every kernel builds its blocks,
   grids and compiler params through the substrate
   (``ops/kernel_lib/tiling.py``) so block-size choices stay on the
   autotuner and the VMEM-limit defaults stay uniform — a kernel that
@@ -60,7 +55,6 @@ import re
 from typing import Dict, Iterable, List, Optional, Sequence, Set, Tuple
 
 RULES: Dict[str, str] = {
-    "L001": "version-moved JAX API used outside utils/jax_compat.py",
     "L002": "enum-like config domain not registered in "
             "config/loader.py::_enum_fields",
     "L003": "nondeterminism/wall-clock inside a jit-traced function",
@@ -71,34 +65,6 @@ RULES: Dict[str, str] = {
             "outside ops/kernel_lib/",
     "L007": "jax.lax.ppermute constructed outside ops/ and "
             "training/train_step.py",
-}
-
-# L001: the moved-API table.  Keys are dotted attribute chains / import
-# targets; values say where the sanctioned shim lives.
-_MOVED_ATTR_CHAINS: Dict[str, str] = {
-    "jax.experimental.shard_map": "utils/jax_compat.py::shard_map",
-    "jax.experimental.shard_map.shard_map": "utils/jax_compat.py::shard_map",
-    "jax.shard_map": "utils/jax_compat.py::shard_map",
-    "lax.axis_size": "utils/jax_compat.py::axis_size",
-    "jax.lax.axis_size": "utils/jax_compat.py::axis_size",
-}
-# Attribute NAMES flagged regardless of base spelling (the pallas tpu module
-# is imported under many aliases; the class rename is what bites).
-_MOVED_ATTR_NAMES: Dict[str, str] = {
-    "TPUCompilerParams": "utils/jax_compat.py::pallas_tpu_compiler_params",
-    "CompilerParams": "utils/jax_compat.py::pallas_tpu_compiler_params",
-}
-# ...but only when accessed on a pallas-tpu-looking base, so e.g. a future
-# ``mosaic.CompilerParams`` on an unrelated object does not false-positive.
-_PALLAS_TPU_BASES = {"pltpu", "tpu", "pallas_tpu"}
-
-# L001 import forms: (module, name) pairs from ``from module import name``.
-_MOVED_IMPORT_FROMS: Dict[Tuple[str, str], str] = {
-    ("jax.experimental", "shard_map"): "utils/jax_compat.py::shard_map",
-    ("jax.experimental.shard_map", "shard_map"):
-        "utils/jax_compat.py::shard_map",
-    ("jax", "shard_map"): "utils/jax_compat.py::shard_map",
-    ("jax.lax", "axis_size"): "utils/jax_compat.py::axis_size",
 }
 
 # L002: a module-level ALL_CAPS constant with one of these suffixes whose
@@ -125,8 +91,12 @@ _SYNC_CALLS = {"jax.device_get", "jax.block_until_ready"}
 _SYNC_METHODS = {"item", "block_until_ready"}
 _METRIC_NAMES_RE = re.compile(r"^(m|dm|dmv|metrics|device_metrics)$")
 
-# L006: Pallas grid/block construction belongs to the kernel substrate.
-_L006_GRID_NAMES = {"BlockSpec", "GridSpec", "PrefetchScalarGridSpec"}
+# L006: Pallas grid/block/compiler-params construction belongs to the
+# kernel substrate; flagged only on a pallas-looking base so an unrelated
+# ``foo.CompilerParams`` does not false-positive.
+_L006_GRID_NAMES = {"BlockSpec", "GridSpec", "PrefetchScalarGridSpec",
+                    "CompilerParams"}
+_PALLAS_BASES = {"pl", "pallas", "pltpu", "tpu", "pallas_tpu"}
 _L006_EXEMPT_PREFIX = "automodel_tpu/ops/kernel_lib/"
 
 # L007: every ppermute's home must be known to the census.  Allowed: any
@@ -326,8 +296,6 @@ class _FileLinter(ast.NodeVisitor):
         self.tree = tree
         self.ctx = ctx
         self.findings: List[Finding] = []
-        self.is_compat_shim = rel_path.replace(os.sep, "/").endswith(
-            "utils/jax_compat.py")
         posix = rel_path.replace(os.sep, "/")
         self.is_kernel_lib = _L006_EXEMPT_PREFIX in posix
         self.is_ppermute_home = (_L007_ALLOWED_PREFIX in posix
@@ -344,33 +312,10 @@ class _FileLinter(ast.NodeVisitor):
         self.findings.append(Finding(rule, self.rel,
                                      getattr(node, "lineno", 0), msg))
 
-    # -- L001 ---------------------------------------------------------------
-    def visit_Import(self, node: ast.Import) -> None:
-        if not self.is_compat_shim:
-            for alias in node.names:
-                if (alias.name == "jax.experimental.shard_map"
-                        or alias.name.startswith(
-                            "jax.experimental.shard_map.")):
-                    self._emit(
-                        "L001", node,
-                        f"import of moved module {alias.name!r}; use "
-                        f"{_MOVED_ATTR_CHAINS['jax.experimental.shard_map']}")
-        self.generic_visit(node)
-
+    # -- L006 / L007 at imports ----------------------------------------------
     def visit_ImportFrom(self, node: ast.ImportFrom) -> None:
-        if not self.is_compat_shim and node.module:
-            for alias in node.names:
-                shim = _MOVED_IMPORT_FROMS.get((node.module, alias.name))
-                if shim is None and "pallas" in node.module and alias.name in (
-                        _MOVED_ATTR_NAMES):
-                    shim = _MOVED_ATTR_NAMES[alias.name]
-                if shim is not None:
-                    self._emit(
-                        "L001", node,
-                        f"'from {node.module} import {alias.name}' is a "
-                        f"version-moved API; use {shim}")
-        if (not self.is_compat_shim and not self.is_kernel_lib
-                and node.module and "pallas" in node.module):
+        if (not self.is_kernel_lib and node.module
+                and "pallas" in node.module):
             for alias in node.names:
                 if alias.name in _L006_GRID_NAMES:
                     self._emit(
@@ -389,23 +334,6 @@ class _FileLinter(ast.NodeVisitor):
                         "permutes live in ops/ or training/train_step.py "
                         "so the golden censuses can name every permute's "
                         "home")
-        self.generic_visit(node)
-
-    def visit_Attribute(self, node: ast.Attribute) -> None:
-        if not self.is_compat_shim:
-            chain = _dotted(node)
-            if chain in _MOVED_ATTR_CHAINS:
-                self._emit("L001", node,
-                           f"{chain!r} is a version-moved API; use "
-                           f"{_MOVED_ATTR_CHAINS[chain]}")
-            elif node.attr in _MOVED_ATTR_NAMES:
-                base = _dotted(node.value)
-                if base and base.split(".")[-1] in _PALLAS_TPU_BASES:
-                    self._emit(
-                        "L001", node,
-                        f"'{base}.{node.attr}' rides the TPUCompilerParams"
-                        f" -> CompilerParams rename; use "
-                        f"{_MOVED_ATTR_NAMES[node.attr]}")
         self.generic_visit(node)
 
     # -- scope tracking (L003 / L004) ---------------------------------------
@@ -441,23 +369,17 @@ class _FileLinter(ast.NodeVisitor):
                            "jax.random key instead")
         if self.hot_file or self._hot_depth > 0:
             self._check_sync_call(node, chain)
-        if not (self.is_kernel_lib or self.is_compat_shim) and chain:
+        if not self.is_kernel_lib and chain:
             tail = chain.split(".")[-1]
             base = chain.rsplit(".", 1)[0] if "." in chain else ""
             if (tail in _L006_GRID_NAMES
-                    and base.split(".")[-1] in _PALLAS_TPU_BASES
-                    | {"pl", "pallas"}):
+                    and base.split(".")[-1] in _PALLAS_BASES):
                 self._emit(
                     "L006", node,
                     f"raw {chain!r} construction: build Pallas block/grid "
-                    "specs through ops/kernel_lib/tiling.py (the "
-                    "substrate's single construction path)")
-            elif tail == "pallas_tpu_compiler_params":
-                self._emit(
-                    "L006", node,
-                    "call kernel_lib.tiling.compiler_params (which applies "
-                    "the substrate's VMEM-limit default) instead of the "
-                    "raw jax_compat shim")
+                    "specs and compiler params through ops/kernel_lib/"
+                    "tiling.py (the substrate's single construction path, "
+                    "which also applies the VMEM-limit default)")
         if (not self.is_ppermute_home and chain
                 and chain.split(".")[-1] == "ppermute"):
             self._emit(

@@ -233,8 +233,7 @@ def snapshot_to_host(tree: Any) -> Any:
 
     Fully-addressable leaves ride ONE batched ``jax.device_get`` of the
     whole tree (parallel transfers; per-leaf fetches serialize a round trip
-    per tensor, which is what makes the inline save path latency-bound on
-    tunneled/remote runtimes).  Non-addressable leaves whose LOCAL shards
+    per tensor, which makes the inline save path latency-bound).  Non-addressable leaves whose LOCAL shards
     cover the full array (replicated, or HSDP replica-complete on this
     host) are assembled from those shards — no cross-host traffic at all.
     A leaf genuinely sharded ACROSS hosts falls back to

@@ -92,13 +92,10 @@ class NanogptDataset:
         if align_to_bos:
             assert bos_token is not None, "align_to_bos requires bos_token"
         if rank is None:
-            try:
-                import jax
+            import jax
 
-                rank = jax.process_index()
-                world_size = jax.process_count()
-            except Exception:
-                rank, world_size = 0, 1
+            rank = jax.process_index()
+            world_size = jax.process_count()
         self.rank = rank
         self.world_size = world_size or 1
 

@@ -218,18 +218,18 @@ class MeshManager:
             devices, dcn_dp_size)
         inner_shape = self.shape[1:]
         slabs = []
-        for slice_devs in self._slice_devices:
-            try:
-                from jax.experimental import mesh_utils
+        from jax.experimental import mesh_utils
 
-                slab = mesh_utils.create_device_mesh(
-                    inner_shape,
-                    devices=slice_devs,
-                    allow_split_physical_axes=allow_split_physical_axes,
-                )
-            except Exception:
-                slab = np.asarray(slice_devs).reshape(inner_shape)
-            slabs.append(slab)
+        for slice_devs in self._slice_devices:
+            # topology-aware device order; a shape the physical topology
+            # cannot host raises here (JAX names the remedy,
+            # ``allow_split_physical_axes``) instead of silently running on
+            # a topology-blind reshape
+            slabs.append(mesh_utils.create_device_mesh(
+                inner_shape,
+                devices=slice_devs,
+                allow_split_physical_axes=allow_split_physical_axes,
+            ))
         dev_array = np.stack(slabs, axis=0)
         self.mesh_shape: Tuple[int, ...] = self.shape
         self.mesh = Mesh(dev_array.reshape(self.mesh_shape), MESH_AXES)

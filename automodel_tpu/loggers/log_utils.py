@@ -19,12 +19,9 @@ class RankFilter(logging.Filter):
     def __init__(self, rank: Optional[int] = None):
         super().__init__()
         if rank is None:
-            try:
-                import jax
+            import jax
 
-                rank = jax.process_index()
-            except Exception:
-                rank = 0
+            rank = jax.process_index()
         self.rank = rank
 
     def filter(self, record: logging.LogRecord) -> bool:

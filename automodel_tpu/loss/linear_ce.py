@@ -72,11 +72,9 @@ def _sharded_lse_pick(hidden, kernel, labels, mesh, rules, bwd_mode):
         v_local = w.shape[1]
         b, s, hd = h.shape
         t = b * s
-        from automodel_tpu.utils.jax_compat import axis_size
-
         offset = jnp.int32(0)
         for ax in vocab_ax:
-            offset = offset * axis_size(ax) + lax.axis_index(ax)
+            offset = offset * lax.axis_size(ax) + lax.axis_index(ax)
         lab_flat = lab.reshape(t).astype(jnp.int32) - offset * v_local
         if linear_ce_kernel_available(t, hd, v_local):
             lse, pick = lse_and_pick(h.reshape(t, hd), w, lab_flat, bwd_mode)
@@ -96,9 +94,7 @@ def _sharded_lse_pick(hidden, kernel, labels, mesh, rules, bwd_mode):
         valid = lab.reshape(t) != IGNORE_INDEX
         return jnp.where(valid, lse - pick, 0.0).reshape(b, s)
 
-    from automodel_tpu.utils.jax_compat import shard_map
-
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh, in_specs=(h_spec, w_spec, lab_spec),
         out_specs=lab_spec, check_vma=False,
     )(hidden, kernel, labels)

@@ -25,6 +25,10 @@ _BUILD_DIR = os.path.join(_DIR, "_build")
 
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
+# how this process got the library: "built" (compiled here from
+# src/packing.cpp), "cached" (an existing _build/*.so of the same source
+# hash), "python" (no compiler: the Python packer/collater is the path)
+_source: Optional[str] = None
 
 
 def _compiler() -> Optional[str]:
@@ -56,16 +60,22 @@ def _bind(dll: ctypes.CDLL) -> ctypes.CDLL:
 
 
 def lib() -> Optional[ctypes.CDLL]:
-    """The bound native library, or None (no toolchain / build failure)."""
-    global _lib, _tried
+    """The bound native library, or None when no C++ compiler is on PATH
+    (the one case where the Python reference path is the path).  A compiler
+    that fails to build, or a library that fails to load, raises: those are
+    bugs, not a reason to run a different input pipeline in silence."""
+    global _lib, _tried, _source
     if _lib is not None or _tried:
         return _lib
     _tried = True
     cc = _compiler()
     if cc is None:
-        logger.info("native core disabled: no C++ compiler on PATH")
+        _source = "python"
+        logger.warning("native core disabled (no C++ compiler on PATH): "
+                       "the Python packer/collater runs instead")
         return None
     so = _so_path(cc)
+    _source = "cached"
     if not os.path.exists(so):
         os.makedirs(_BUILD_DIR, exist_ok=True)
         fd, tmp = tempfile.mkstemp(suffix=".so", dir=_BUILD_DIR)
@@ -74,18 +84,24 @@ def lib() -> Optional[ctypes.CDLL]:
         try:
             subprocess.run(cmd, check=True, capture_output=True, timeout=120)
             os.replace(tmp, so)  # atomic: racing builders converge
-        except Exception as e:
-            logger.warning("native core build failed (%s); using Python "
-                           "fallbacks", e)
+        except subprocess.CalledProcessError as e:
+            raise RuntimeError(
+                f"native core build failed: {' '.join(cmd)}\n"
+                f"{e.stderr.decode(errors='replace')}") from e
+        finally:
             if os.path.exists(tmp):
                 os.unlink(tmp)
-            return None
-    try:
-        _lib = _bind(ctypes.CDLL(so))
-    except OSError as e:
-        logger.warning("native core load failed (%s)", e)
-        return None
+        _source = "built"
+    _lib = _bind(ctypes.CDLL(so))
+    logger.info("native core %s: %s", _source, so)
     return _lib
+
+
+def source() -> str:
+    """How this process got the library: built | cached | python (see
+    ``_source``)."""
+    lib()
+    return _source
 
 
 def available() -> bool:

@@ -35,12 +35,8 @@ _BLOCK_CANDIDATES = (512, 256, 128)
 
 
 def flash_attention_available(q_seq: int, kv_seq: int, head_dim: int) -> bool:
-    try:
-        backend = jax.default_backend()
-    except Exception:
-        return False
     return (
-        backend == "tpu"
+        registry.on_tpu()
         and q_seq % _BLOCK == 0
         and kv_seq % _BLOCK == 0
         and head_dim >= 8
@@ -145,7 +141,6 @@ def sharded_flash_attention(
     instead).  ``batch_axes=None`` (default) uses the dp-family axes
     PRESENT in the mesh; an explicit tuple is used verbatim, so a typo'd
     axis still fails loudly at spec resolution."""
-    from automodel_tpu.utils.jax_compat import shard_map
     from jax.sharding import PartitionSpec as P
 
     from automodel_tpu.distributed.mesh import BATCH_AXES
@@ -167,11 +162,11 @@ def sharded_flash_attention(
             q, k, v, causal=causal, segment_ids=seg, scale=scale)
 
     if segment_ids is None:
-        return shard_map(
+        return jax.shard_map(
             lambda q, k, v: inner(q, k, v, None), mesh=mesh,
             in_specs=(qspec, kvspec, kvspec), out_specs=qspec,
             check_vma=False)(q, k, v)
-    return shard_map(
+    return jax.shard_map(
         inner, mesh=mesh,
         in_specs=(qspec, kvspec, kvspec, sspec), out_specs=qspec,
         check_vma=False)(q, k, v, segment_ids.astype(jnp.int32))

@@ -70,10 +70,7 @@ def gmm_kernel_available(m: int, k: int, n: int) -> bool:
         return True
     if k % _LANE or n % _LANE:
         return False
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return registry.on_tpu()
 
 
 def _tile_bytes(tm: int, tn: int, k: int) -> int:

@@ -95,13 +95,10 @@ def shape_bucket(n: int) -> int:
 
 
 def topology() -> str:
-    try:
-        import jax
+    import jax
 
-        dev = jax.devices()[0]
-        return f"{dev.device_kind}x{jax.device_count()}".replace(" ", "_")
-    except Exception:
-        return "unknown"
+    dev = jax.devices()[0]
+    return f"{dev.device_kind}x{jax.device_count()}".replace(" ", "_")
 
 
 def make_key(kernel: str, fields: Mapping[str, Any]) -> str:
@@ -303,13 +300,9 @@ class BlockAutotuner:
 
         ensure_default_kernels()        # kernel modules register their sweeps
         report = {"requested": 0, "cached": 0, "swept": 0, "errors": 0}
-        try:
-            import jax
+        import jax
 
-            multihost = jax.process_count() > 1
-        except Exception:
-            multihost = False
-        if multihost:
+        if jax.process_count() > 1:
             missing = [k for k, r in requests
                        if k in _SWEEPS and make_key(
                            k, _SWEEPS[k].key_fields(r)) not in self.table]
@@ -392,19 +385,11 @@ _ACTIVE = BlockAutotuner(mode="off")
 
 
 def default_cache_path() -> str:
-    """Alongside the persistent XLA compile cache when one is configured
-    (``compile.cache_dir``, applied before this is read at setup), else the
-    user cache dir."""
-    try:
-        import jax
+    """Beside the persistent XLA compile cache, under its placement rule
+    (``utils/compile_utils.cache_dir``)."""
+    from automodel_tpu.utils.compile_utils import cache_dir
 
-        cache_dir = jax.config.jax_compilation_cache_dir
-    except Exception:
-        cache_dir = None
-    if cache_dir:
-        return os.path.join(cache_dir, CACHE_BASENAME)
-    return os.path.join(os.path.expanduser("~"), ".cache", "automodel_tpu",
-                        CACHE_BASENAME)
+    return os.path.join(cache_dir(), CACHE_BASENAME)
 
 
 def configure_autotune(mode: Any = None,

@@ -1,28 +1,31 @@
-"""Shared interpret-mode parity harness: every registered kernel vs its
-XLA reference.
+"""Shared parity harness: every registered kernel vs its XLA reference.
 
-Replaces the per-kernel parity scaffolding the five kernel test modules
-each used to carry: ONE case matrix (shape / dtype / GQA / packed-segment
-variants) and ONE runner per kernel family, executed under
-``JAX_PLATFORMS=cpu`` with the Pallas kernels in interpret mode
-(:func:`interpret_mode`), so the REAL kernel logic — tiling, masking,
-online softmax, scalar-prefetch schedules — runs on the CPU suite and is
-held to the registry's ``reference`` oracle (``kernel_lib/registry``).
+ONE case matrix (shape / dtype / GQA / packed-segment variants) and ONE
+runner per kernel family, executed two ways:
+
+* **interpret** (the CPU suite, ``tests/``): ``JAX_PLATFORMS=cpu`` with the
+  Pallas kernels in interpret mode (:func:`interpret_mode`), so the REAL
+  kernel logic — tiling, masking, online softmax, scalar-prefetch
+  schedules — runs on the CPU and is held to the registry's ``reference``
+  oracle (``kernel_lib/registry``) at small shapes;
+* **native** (the chip suite, ``tpu_tests/``): ``native=True`` runs the same
+  builders and runners with every ``_INTERPRET`` flag off — Mosaic compiles
+  the kernel — at the published-width shapes of :func:`chip_cases`, against
+  the same reference evaluated at highest matmul precision.
 
 The harness bypasses probes deliberately: a probe answers "should dispatch
 pick you HERE" (backend, alignment), while parity asks "is your math right
-anywhere" — interpret mode exists exactly to decouple the two.  Tests
-declare which rungs execute off-TPU (``CPU_EXECUTABLE``); the flash rung's
-upstream kernel exposes no interpret path, so its parity stays a TPU-only
-concern (``tpu_tests/``).
+anywhere".  Tests declare which rungs execute off-TPU (``CPU_EXECUTABLE``);
+the flash rung's upstream kernel exposes no interpret path.
 
-Note on this container's splash: the upstream MQA kernel requires
-``head_dim % 128 == 0`` at trace time, so attention cases use D=128.
+Every runner returns the measured error (max |out - ref| / max |ref|) so
+the chip suite can record it per rung.
 """
 
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import importlib
 from typing import Dict, List, Optional
 
@@ -56,12 +59,7 @@ def interpret_mode():
     """Flip every Pallas kernel module's ``_INTERPRET`` flag on (restored
     on exit): the CPU suite executes real kernel logic through the Pallas
     interpreter."""
-    mods = []
-    for name in _INTERPRET_MODULES:
-        try:
-            mods.append(importlib.import_module(name))
-        except ImportError:
-            pass
+    mods = [importlib.import_module(name) for name in _INTERPRET_MODULES]
     saved = [(m, m._INTERPRET) for m in mods]
     for m in mods:
         m._INTERPRET = True
@@ -70,6 +68,64 @@ def interpret_mode():
     finally:
         for m, v in saved:
             m._INTERPRET = v
+
+
+def interpret_flags_on() -> List[str]:
+    """Kernel modules whose ``_INTERPRET`` flag is set right now — must be
+    empty wherever a result is attributed to the chip."""
+    return [name for name in _INTERPRET_MODULES
+            if importlib.import_module(name)._INTERPRET]
+
+
+# native tolerances (normalized max error, see _compare): bf16 operands
+# with f32 accumulation against an f32 oracle differ by output rounding
+# (2^-8) plus accumulation order; the exact-accumulate int8 rungs differ
+# from their XLA spelling only where a quantization tie rounds the other
+# way (one quantum in 127 on a few elements)
+NATIVE_TOL = {"bfloat16": 2e-2, "float32": 2e-2, "int8": 2e-3,
+              "float8": 5e-2}
+
+
+def _tol(dtype: str, native: bool, interpret_tol: float) -> float:
+    """The case's tolerance: the interpret run's own, or the native one of
+    its operand dtype (any int8 / float8 flavour shares one entry)."""
+    if not native:
+        return interpret_tol
+    for family in ("int8", "float8"):
+        if family in dtype:
+            return NATIVE_TOL[family]
+    return NATIVE_TOL[dtype]
+
+
+def _execute(spec, request, args, kwargs, native: bool):
+    """(out, ref) of one rung on one built case."""
+    assert spec.reference is not None, f"{spec.name} has no XLA reference"
+    if not native:
+        with interpret_mode():
+            out = spec.impl(request, *args, **kwargs)
+        return out, spec.reference(request, *args, **kwargs)
+    on = interpret_flags_on()
+    assert not on, f"native parity with _INTERPRET on in {on}"
+    out = jax.jit(lambda *a: spec.impl(request, *a, **kwargs))(*args)
+    with jax.default_matmul_precision("highest"):
+        ref = jax.jit(
+            lambda *a: spec.reference(request, *a, **kwargs))(*args)
+    return out, ref
+
+
+def _compare(out, ref, tol: float, native: bool, what: str) -> float:
+    """Assert closeness and return the normalized max error.  Interpret
+    runs keep the elementwise ``atol = rtol = tol`` they always had; native
+    runs scale ``atol`` by the reference's magnitude (published-width
+    outputs are not O(1))."""
+    out = np.asarray(out, np.float32)
+    ref = np.asarray(ref, np.float32)
+    scale = float(np.max(np.abs(ref)))
+    assert scale > 0.0, f"{what}: all-zero reference, nothing compared"
+    np.testing.assert_allclose(
+        out, ref, atol=tol * scale if native else tol, rtol=tol,
+        err_msg=f"{what} diverged from its XLA reference")
+    return float(np.max(np.abs(out - ref))) / scale
 
 
 # ---------------------------------------------------------------------------
@@ -87,8 +143,8 @@ def sdpa_reference(request, q, k, v, **kwargs):
 def dense_lse_pick_reference(request, h, w, labels):
     """The linear_ce family's oracle: dense-XLA (lse, picked) with the
     chain's out-of-range-label contract (ignore rows / other shards' vocab
-    pick 0).  jnp-only, so the chunked anchor rung can register it even on
-    a JAX where the Pallas kernel module cannot import."""
+    pick 0).  jnp-only: the chunked anchor rung registers it without
+    importing the Pallas kernel module."""
     logits = jnp.dot(h, w.astype(h.dtype), preferred_element_type=jnp.float32)
     lse = jax.nn.logsumexp(logits, axis=-1)
     v_dim = w.shape[1]
@@ -104,7 +160,9 @@ def dense_lse_pick_reference(request, h, w, labels):
 # ---------------------------------------------------------------------------
 def attention_cases() -> List[Dict]:
     """The shape/dtype/GQA/packed-segment matrix every attention rung is
-    held to (one list — not five per-file copies)."""
+    held to (one list — not five per-file copies).  Shape keys (``B S Hq
+    Hk D``) default to the small interpret-mode shape; the chip cases set
+    them to published widths."""
     return [
         dict(name="causal_gqa", causal=True, dtype="float32"),
         dict(name="causal_bf16", causal=True, dtype="bfloat16"),
@@ -120,6 +178,8 @@ def attention_cases() -> List[Dict]:
 
 
 def build_attention_case(case: Dict, *, B=1, S=256, Hq=4, Hk=2, D=128):
+    B, S = case.get("B", B), case.get("S", S)
+    Hq, Hk, D = case.get("Hq", Hq), case.get("Hk", Hk), case.get("D", D)
     dtype = jnp.dtype(case.get("dtype", "float32"))
     kq, kk, kv = jax.random.split(jax.random.key(0), 3)
     q = jax.random.normal(kq, (B, S, Hq, D), jnp.float32).astype(dtype)
@@ -151,27 +211,23 @@ def build_attention_case(case: Dict, *, B=1, S=256, Hq=4, Hk=2, D=128):
 
 
 def run_attention_parity(spec_name: str, case: Dict,
-                         mesh=None, B: int = 1) -> None:
-    """Execute one rung on one case (interpret mode) and assert parity
-    against its registered XLA reference.  ``mesh`` routes the sharded
-    rungs (ring) through their shard_map wrapper on the test mesh."""
+                         mesh=None, B: int = 1,
+                         native: bool = False) -> float:
+    """Execute one rung on one case and assert parity against its
+    registered XLA reference.  ``mesh`` routes the sharded rungs (ring)
+    through their shard_map wrapper on the test mesh."""
     spec = registry.get_kernel(spec_name)
-    assert spec.reference is not None, f"{spec_name} has no XLA reference"
     q, k, v, kwargs, request = build_attention_case(case, B=B)
     if mesh is not None:
         request.update(mesh=mesh, cp_active=True, cp_layout="contiguous")
-    with interpret_mode():
-        out = spec.impl(request, q, k, v, **kwargs)
-    ref = spec.reference(request, q, k, v, **kwargs)
-    tol = 2e-2 if case.get("dtype") == "bfloat16" else 2e-3
+    out, ref = _execute(spec, request, (q, k, v), kwargs, native)
+    tol = _tol(str(q.dtype), native,
+               2e-2 if q.dtype == jnp.bfloat16 else 2e-3)
     valid_rows = slice(None, -case["padding"]) if case.get("padding") \
         else slice(None)
-    np.testing.assert_allclose(
-        np.asarray(out, np.float32)[:, valid_rows],
-        np.asarray(ref, np.float32)[:, valid_rows],
-        atol=tol, rtol=tol,
-        err_msg=f"{spec_name} diverged from its XLA reference on "
-                f"{case['name']}")
+    return _compare(np.asarray(out, np.float32)[:, valid_rows],
+                    np.asarray(ref, np.float32)[:, valid_rows], tol, native,
+                    f"{spec_name} on {case['name']}")
 
 
 # ---------------------------------------------------------------------------
@@ -181,7 +237,7 @@ def paged_attention_cases() -> List[Dict]:
     """Decode (q=1), speculative-verify (q=spec_k+1) and chunked-prefill
     (q>1) traffic over scrambled block tables with ragged per-row context
     lengths; the int8 cases exercise the quantized-KV dequant inside each
-    rung."""
+    rung.  Shape keys: ``B Hq Hk D BS MB``."""
     return [
         dict(name="decode_gqa", q_seq=1, dtype="float32"),
         dict(name="decode_bf16", q_seq=1, dtype="bfloat16"),
@@ -203,6 +259,8 @@ def paged_attention_cases() -> List[Dict]:
 
 def build_paged_attention_case(case: Dict, *, B=2, Hq=4, Hk=2, D=128,
                                BS=16, MB=4):
+    B, Hq, Hk = case.get("B", B), case.get("Hq", Hq), case.get("Hk", Hk)
+    D, BS, MB = case.get("D", D), case.get("BS", BS), case.get("MB", MB)
     rng = np.random.default_rng(7)
     dtype = jnp.dtype(case.get("dtype", "float32"))
     S = case["q_seq"]
@@ -228,7 +286,9 @@ def build_paged_attention_case(case: Dict, *, B=2, Hq=4, Hk=2, D=128,
     # scrambled, per-row-disjoint block tables (block 0 = null page)
     perm = rng.permutation(np.arange(1, NB)).reshape(B, MB)
     block_tables = jnp.asarray(perm, jnp.int32)
-    ctx = np.asarray([MB * BS - 7, 2 * BS + 3][:B], np.int32)
+    # ragged contexts: even rows nearly full, odd rows short
+    ctx = np.asarray([MB * BS - 7 - 3 * (b // 2) if b % 2 == 0
+                      else 2 * BS + 3 + b // 2 for b in range(B)], np.int32)
     ctx = np.maximum(ctx, S)
     positions = jnp.asarray(
         ctx[:, None] - S + np.arange(S)[None, :], jnp.int32)
@@ -247,19 +307,15 @@ def build_paged_attention_case(case: Dict, *, B=2, Hq=4, Hk=2, D=128,
             jnp.asarray(ctx), positions), kwargs, request
 
 
-def run_paged_attention_parity(spec_name: str, case: Dict) -> None:
+def run_paged_attention_parity(spec_name: str, case: Dict,
+                               native: bool = False) -> float:
     spec = registry.get_kernel(spec_name)
-    assert spec.reference is not None, f"{spec_name} has no XLA reference"
     args, kwargs, request = build_paged_attention_case(case)
-    with interpret_mode():
-        out = spec.impl(request, *args, **kwargs)
-    ref = spec.reference(request, *args, **kwargs)
-    tol = 2e-2 if case.get("dtype") == "bfloat16" else 2e-3
-    np.testing.assert_allclose(
-        np.asarray(out, np.float32), np.asarray(ref, np.float32),
-        atol=tol, rtol=tol,
-        err_msg=f"{spec_name} diverged from its XLA reference on "
-                f"{case['name']}")
+    out, ref = _execute(spec, request, args, kwargs, native)
+    tol = _tol(str(args[0].dtype), native,
+               2e-2 if args[0].dtype == jnp.bfloat16 else 2e-3)
+    return _compare(out, ref, tol, native,
+                    f"{spec_name} on {case['name']}")
 
 
 # ---------------------------------------------------------------------------
@@ -274,26 +330,28 @@ def linear_ce_cases() -> List[Dict]:
     ]
 
 
-def run_linear_ce_parity(spec_name: str, case: Dict) -> None:
+def run_linear_ce_parity(spec_name: str, case: Dict,
+                         native: bool = False) -> float:
     spec = registry.get_kernel(spec_name)
     rng = np.random.default_rng(0)
     t, h, v = case["t"], case["h"], case["v"]
-    hid = jnp.asarray(rng.normal(size=(t, h)), jnp.float32)
-    w = jnp.asarray(rng.normal(size=(h, v)) * 0.05, jnp.float32)
+    dtype = jnp.dtype(case.get("dtype", "float32"))
+    hid = jnp.asarray(rng.normal(size=(t, h)), jnp.float32).astype(dtype)
+    w = jnp.asarray(rng.normal(size=(h, v)) * 0.05,
+                    jnp.float32).astype(dtype)
     labels = jnp.asarray(
         rng.integers(case.get("label_lo", 0), case.get("label_hi", v), t),
         jnp.int32)
     request = {"kind": "linear_ce", "t": t, "h": h, "v": v,
                "bwd_mode": "pallas"}
-    with interpret_mode():
-        lse, pick = spec.impl(request, hid, w, labels)
-    ref_lse, ref_pick = spec.reference(request, hid, w, labels)
-    np.testing.assert_allclose(np.asarray(lse), np.asarray(ref_lse),
-                               rtol=1e-5, atol=1e-5,
-                               err_msg=f"{spec_name} lse on {case['name']}")
-    np.testing.assert_allclose(np.asarray(pick), np.asarray(ref_pick),
-                               rtol=1e-5, atol=1e-5,
-                               err_msg=f"{spec_name} pick on {case['name']}")
+    (lse, pick), (ref_lse, ref_pick) = _execute(
+        spec, request, (hid, w, labels), {}, native)
+    tol = _tol(str(dtype), native, 1e-5)
+    return max(
+        _compare(lse, ref_lse, tol, native,
+                 f"{spec_name} lse on {case['name']}"),
+        _compare(pick, ref_pick, tol, native,
+                 f"{spec_name} pick on {case['name']}"))
 
 
 # ---------------------------------------------------------------------------
@@ -310,27 +368,55 @@ def gmm_cases() -> List[Dict]:
     ]
 
 
-def run_gmm_parity(spec_name: str, case: Dict) -> None:
-    spec = registry.get_kernel(spec_name)
-    rng = np.random.default_rng(1)
+def _gmm_operands(case: Dict, seed: int):
+    rng = np.random.default_rng(seed)
     m, k, n = case["m"], case["k"], case["n"]
+    dtype = jnp.dtype(case.get("dtype", "float32"))
     sizes = jnp.asarray(case["sizes"], jnp.int32)
-    lhs = jnp.asarray(rng.normal(size=(m, k)) * 0.1, jnp.float32)
+    lhs = jnp.asarray(rng.normal(size=(m, k)) * case.get("lhs_scale", 0.1),
+                      jnp.float32).astype(dtype)
     rhs = jnp.asarray(rng.normal(size=(len(case["sizes"]), k, n)) * 0.1,
-                      jnp.float32)
-    request = {"kind": "gmm", "m": m, "k": k, "n": n,
+                      jnp.float32).astype(dtype)
+    return lhs, rhs, sizes
+
+
+def run_gmm_parity(spec_name: str, case: Dict, native: bool = False,
+                   grads: bool = False):
+    """Forward parity; with ``grads`` also the custom VJP — ``dlhs`` is a
+    second gmm and ``drhs`` the transposed kernel (``tgmm``), which has no
+    registry rung of its own — returning ``(fwd, dlhs, drhs)`` errors."""
+    spec = registry.get_kernel(spec_name)
+    lhs, rhs, sizes = _gmm_operands(case, seed=1)
+    request = {"kind": "gmm", "m": case["m"], "k": case["k"],
+               "n": case["n"],
                "block_aligned": bool(case.get("block_aligned")),
-               "block_rows": 128, "dtype": "float32"}
+               "block_rows": 128, "dtype": str(lhs.dtype)}
     if spec_name == "gmm.xla_blocked" and not request["block_aligned"]:
-        return      # that rung's contract requires block-aligned groups
-    with interpret_mode():
-        out = spec.impl(request, lhs, rhs, sizes)
-    ref = spec.reference(request, lhs, rhs, sizes) if spec.reference \
-        else registry.get_kernel("gmm.pallas").reference(
-            request, lhs, rhs, sizes)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-4, atol=2e-4,
-                               err_msg=f"{spec_name} on {case['name']}")
+        return None     # that rung's contract requires block-aligned groups
+    if spec.reference is None:
+        spec = dataclasses.replace(
+            spec, reference=registry.get_kernel("gmm.pallas").reference)
+    tol = _tol(str(lhs.dtype), native, 2e-4)
+    what = f"{spec_name} on {case['name']}"
+    out, ref = _execute(spec, request, (lhs, rhs, sizes), {}, native)
+    err = _compare(out, ref, tol, native, what)
+    if not grads:
+        return err
+
+    cot = jnp.asarray(np.random.default_rng(4).normal(
+        size=(case["m"], case["n"])), jnp.float32)
+
+    def as_loss(fn):
+        return lambda req, lhs, rhs, sizes: jax.grad(
+            lambda a, b: jnp.sum(fn(req, a, b, sizes).astype(jnp.float32)
+                                 * cot), argnums=(0, 1))(lhs, rhs)
+
+    gspec = dataclasses.replace(spec, impl=as_loss(spec.impl),
+                                reference=as_loss(spec.reference))
+    (dl, dr), (rl, rr) = _execute(gspec, request, (lhs, rhs, sizes), {},
+                                  native)
+    return (err, _compare(dl, rl, tol, native, what + " dlhs"),
+            _compare(dr, rr, tol, native, what + " drhs (tgmm)"))
 
 
 # ---------------------------------------------------------------------------
@@ -354,25 +440,25 @@ def qdot_cases() -> List[Dict]:
     ]
 
 
-def run_qdot_parity(spec_name: str, case: Dict) -> None:
+def run_qdot_parity(spec_name: str, case: Dict,
+                    native: bool = False) -> float:
     from automodel_tpu.ops.quant import _operand_scales
 
     spec = registry.get_kernel(spec_name)
     rng = np.random.default_rng(2)
     m, k, n = case["m"], case["k"], case["n"]
-    a = jnp.asarray(rng.normal(size=(m, k)), jnp.float32)
-    b = jnp.asarray(rng.normal(size=(k, n)) * 0.1, jnp.float32)
+    dtype = jnp.dtype(case.get("dtype", "float32"))
+    a = jnp.asarray(rng.normal(size=(m, k)), jnp.float32).astype(dtype)
+    b = jnp.asarray(rng.normal(size=(k, n)) * 0.1,
+                    jnp.float32).astype(dtype)
     sa, sb = _operand_scales(a, b, jnp.dtype(case["a_dtype"]),
                              jnp.dtype(case["b_dtype"]), case["rowwise"])
     request = {"kind": "qdot", "m": m, "k": k, "n": n,
                "a_dtype": case["a_dtype"], "b_dtype": case["b_dtype"],
                "rowwise": case["rowwise"]}
-    with interpret_mode():
-        out = spec.impl(request, a, b, sa, sb)
-    ref = spec.reference(request, a, b, sa, sb)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=1e-5, atol=1e-5,
-                               err_msg=f"{spec_name} on {case['name']}")
+    out, ref = _execute(spec, request, (a, b, sa, sb), {}, native)
+    return _compare(out, ref, _tol(case["a_dtype"], native, 1e-5),
+                    native, f"{spec_name} on {case['name']}")
 
 
 # ---------------------------------------------------------------------------
@@ -391,29 +477,101 @@ def gmm_quant_cases() -> List[Dict]:
     ]
 
 
-def run_gmm_quant_parity(spec_name: str, case: Dict) -> None:
+def run_gmm_quant_parity(spec_name: str, case: Dict,
+                         native: bool = False) -> Optional[float]:
     from automodel_tpu.ops.gmm_quant_kernel import lhs_scales, rhs_scales
     from automodel_tpu.ops.quant import _gemm_dtypes, quant_cast
 
     spec = registry.get_kernel(spec_name)
-    rng = np.random.default_rng(3)
-    m, k, n = case["m"], case["k"], case["n"]
-    sizes = jnp.asarray(case["sizes"], jnp.int32)
-    lhs = jnp.asarray(rng.normal(size=(m, k)) * 0.5, jnp.float32)
-    rhs = jnp.asarray(rng.normal(size=(len(case["sizes"]), k, n)) * 0.1,
-                      jnp.float32)
+    lhs, rhs, sizes = _gmm_operands(
+        {**case, "dtype": "float32", "lhs_scale": 0.5}, seed=3)
     a_q, b_q = _gemm_dtypes(case["dtype"], None)
     lhs_q = quant_cast(lhs, lhs_scales(lhs, sizes, a_q, case["recipe"]), a_q)
     rhs_q = quant_cast(rhs, rhs_scales(rhs, b_q, case["recipe"]), b_q)
-    request = {"kind": "gmm_quant", "m": m, "k": k, "n": n,
+    request = {"kind": "gmm_quant", "m": case["m"], "k": case["k"],
+               "n": case["n"],
                "a_dtype": str(jnp.dtype(a_q)), "b_dtype": str(jnp.dtype(b_q)),
                "block_aligned": bool(case.get("block_aligned")),
                "block_rows": 128}
     if spec_name == "gmm_quant.xla_blocked" and not request["block_aligned"]:
-        return      # that rung's contract requires block-aligned groups
-    with interpret_mode():
-        out = spec.impl(request, lhs_q, rhs_q, sizes)
-    ref = spec.reference(request, lhs_q, rhs_q, sizes)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-4, atol=2e-4,
-                               err_msg=f"{spec_name} on {case['name']}")
+        return None     # that rung's contract requires block-aligned groups
+    out, ref = _execute(spec, request, (lhs_q, rhs_q, sizes), {}, native)
+    return _compare(out, ref, _tol(case["dtype"], native, 2e-4),
+                    native, f"{spec_name} on {case['name']}")
+
+
+# ---------------------------------------------------------------------------
+# The chip matrix: the same case schema at published widths, one or two
+# shapes per Pallas rung (``tpu_tests/`` runs these with ``native=True``)
+# ---------------------------------------------------------------------------
+def _ragged_sizes(m: int, groups: int) -> tuple:
+    """Ragged but deterministic group sizes summing under ``m`` (a dropped
+    tail and one empty group, like the small ragged case)."""
+    base = m // groups
+    sizes = [base + (17 if g % 2 else -17) for g in range(groups)]
+    sizes[1] = 0
+    return tuple(sizes)
+
+
+def chip_cases() -> Dict[str, List[Dict]]:
+    """rung family -> cases.  Widths: Llama-3.2-1B (the trainer the smoke
+    runs; head_dim 64), Llama-3.2-3B (the server; head_dim 128, G=3),
+    Llama-3.1-8B dense projections for the quantized matmul, Mixtral-8x7B
+    and Moonlight-16B-A3B experts for the grouped matmuls — whole-K tiles
+    at K=14336 are the VMEM-heaviest shape any of them sees."""
+    l3b = dict(B=8, Hq=24, Hk=8, D=128, BS=16, MB=64)
+    mixtral_up = dict(m=4096, k=4096, n=14336, sizes=_ragged_sizes(4096, 8))
+    mixtral_down = dict(m=4096, k=14336, n=4096, sizes=_ragged_sizes(4096, 8))
+    moonlight_up = dict(m=4096, k=2048, n=1408, sizes=_ragged_sizes(4096, 64))
+    return {
+        "attention.splash": [
+            dict(name="llama3_2_1b_packed_2k", B=2, S=2048, Hq=32, Hk=8,
+                 D=64, dtype="bfloat16", causal=True, segments=True),
+            dict(name="llama3_2_3b_2k", B=1, S=2048, Hq=24, Hk=8, D=128,
+                 dtype="bfloat16", causal=True),
+            # the 512-edge diagonal default (S >= 8192); two heads keep the
+            # dense reference's [S, S] logits inside one chip
+            dict(name="long_16k_diag512", B=1, S=16384, Hq=2, Hk=1, D=64,
+                 dtype="bfloat16", causal=True),
+        ],
+        "attention.paged_decode": [
+            dict(name="llama3_2_3b_decode", q_seq=1, dtype="bfloat16",
+                 **l3b),
+            dict(name="llama3_2_3b_decode_int8_kv", q_seq=1,
+                 dtype="bfloat16", quantized=True, **l3b),
+            dict(name="llama3_2_3b_verify_w5", q_seq=5, dtype="bfloat16",
+                 **l3b),
+            dict(name="llama3_2_3b_verify_w5_int8_kv", q_seq=5,
+                 dtype="bfloat16", quantized=True, **l3b),
+            dict(name="llama3_2_3b_prefill_chunk32", q_seq=32,
+                 dtype="bfloat16", **l3b),
+        ],
+        "linear_ce.pallas": [
+            dict(name="llama3_2_1b_vocab128256", t=4096, h=2048, v=128256,
+                 dtype="bfloat16"),
+        ],
+        "gmm.pallas": [
+            dict(name="mixtral_8x7b_up", dtype="bfloat16", **mixtral_up),
+            dict(name="mixtral_8x7b_down", dtype="bfloat16", **mixtral_down),
+            dict(name="moonlight_16b_up", dtype="bfloat16", **moonlight_up),
+        ],
+        "qdot.pallas": [
+            dict(name="llama3_1_8b_up_int8_tensorwise", m=4096, k=4096,
+                 n=14336, dtype="bfloat16", a_dtype="int8", b_dtype="int8",
+                 rowwise=False),
+            dict(name="llama3_1_8b_down_int8_rowwise", m=4096, k=14336,
+                 n=4096, dtype="bfloat16", a_dtype="int8", b_dtype="int8",
+                 rowwise=True),
+            dict(name="llama3_1_8b_up_fp8_tensorwise", m=4096, k=4096,
+                 n=14336, dtype="bfloat16", a_dtype="float8_e4m3fn",
+                 b_dtype="float8_e4m3fn", rowwise=False),
+        ],
+        "gmm_quant.pallas": [
+            dict(name="mixtral_8x7b_up_int8_tensorwise", dtype="int8",
+                 recipe="tensorwise", **mixtral_up),
+            dict(name="mixtral_8x7b_down_int8_rowwise", dtype="int8",
+                 recipe="rowwise", **mixtral_down),
+            dict(name="moonlight_16b_up_fp8_tensorwise", dtype="float8",
+                 recipe="tensorwise", **moonlight_up),
+        ],
+    }

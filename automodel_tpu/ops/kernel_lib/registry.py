@@ -11,7 +11,9 @@ Contract:
 
 * ``probe(request) -> bool`` — pure availability/capability check against a
   plain-dict request (static shapes, dtype, feature flags, sharding
-  context).  Probes must not raise for "unavailable" — return False.
+  context).  "Unavailable" (wrong backend, unaligned shape) returns False;
+  a backend that cannot initialise is not "unavailable" — it raises
+  (:func:`on_tpu`), so a broken libtpu never reads as "no kernel here".
 * ``impl(request, *args, **kwargs)`` — the kernel entry.  Impls look their
   collaborators up at CALL time (module globals), so tests can monkeypatch
   a kernel module and the registry follows.
@@ -21,13 +23,17 @@ Contract:
   (``kernel_lib/parity.py``).
 
 Kernel modules register their rungs at import; :func:`ensure_default_kernels`
-imports every in-tree kernel module (tolerating ImportError on old JAX by
-stubbing the rung so the chain stays walkable) and is idempotent.
+imports every in-tree kernel module (a module that fails to import is a
+bug on the one installation there is, and raises) and is idempotent.
+:func:`resolved_rungs` reports which rungs call sites actually resolved —
+what the chip smoke asserts against.
 """
 
 from __future__ import annotations
 
+import collections
 import dataclasses
+import importlib
 import threading
 from typing import Any, Callable, Dict, List, Mapping, Optional
 
@@ -51,8 +57,17 @@ class KernelSpec:
 
 
 _REGISTRY: Dict[str, KernelSpec] = {}
+_RESOLVED: "collections.Counter[str]" = collections.Counter()
 _LOCK = threading.Lock()
 _defaults_loaded = False
+
+
+def on_tpu() -> bool:
+    """True when JAX's default backend is the TPU — the backend half of
+    every Pallas rung's probe.  Backend initialisation errors propagate."""
+    import jax
+
+    return jax.default_backend() == "tpu"
 
 
 def register_kernel(name: str, *, probe: Probe, impl: Callable,
@@ -64,23 +79,6 @@ def register_kernel(name: str, *, probe: Probe, impl: Callable,
     with _LOCK:
         _REGISTRY[name] = spec
     return spec
-
-
-def register_stub(name: str, fallback: Optional[str] = None,
-                  reason: str = "unavailable") -> KernelSpec:
-    """A never-available rung standing in for a kernel module that failed
-    to import (old JAX): keeps the fallback chain walkable."""
-
-    def _probe(request) -> bool:
-        return False
-
-    def _impl(request, *args, **kwargs):
-        raise RuntimeError(f"kernel {name!r} is unavailable: {reason}")
-
-    with _LOCK:
-        if name in _REGISTRY:       # a real registration beat us to it
-            return _REGISTRY[name]
-    return register_kernel(name, probe=_probe, impl=_impl, fallback=fallback)
 
 
 def get_kernel(name: str) -> KernelSpec:
@@ -120,6 +118,8 @@ def resolve(name: str, request: Mapping[str, Any]) -> KernelSpec:
         spec = get_kernel(cur)
         seen.append(cur)
         if spec.probe(request):
+            with _LOCK:
+                _RESOLVED[spec.name] += 1
             return spec
         cur = spec.fallback
         if cur in seen:
@@ -127,6 +127,13 @@ def resolve(name: str, request: Mapping[str, Any]) -> KernelSpec:
     raise RuntimeError(
         f"no kernel in the {name!r} chain accepted the request "
         f"{dict(request)!r}; probed: {seen}")
+
+
+def resolved_rungs() -> Dict[str, int]:
+    """How often each rung won a :func:`resolve` in this process (trace
+    time, so one count per traced call site, not per executed step)."""
+    with _LOCK:
+        return dict(_RESOLVED)
 
 
 def dispatch(name: str, request: Mapping[str, Any], *args, **kwargs):
@@ -137,55 +144,36 @@ def dispatch(name: str, request: Mapping[str, Any], *args, **kwargs):
 # ---------------------------------------------------------------------------
 # Default in-tree kernels
 # ---------------------------------------------------------------------------
-# (module, rung it registers, that rung's fallback — for the ImportError stub)
+# (module, the rung it must register)
 _DEFAULT_KERNEL_MODULES = (
-    ("automodel_tpu.ops.ring_attention", "attention.ring",
-     "attention.splash"),
-    ("automodel_tpu.ops.splash_attention", "attention.splash",
-     "attention.flash"),
-    ("automodel_tpu.ops.flash_attention", "attention.flash",
-     "attention.sdpa"),
-    ("automodel_tpu.ops.attention", "attention.sdpa", None),
-    ("automodel_tpu.ops.paged_attention_kernel", "attention.paged_decode",
-     "attention.paged_gather"),
-    ("automodel_tpu.ops.paged_attention", "attention.paged_gather", None),
-    ("automodel_tpu.ops.linear_ce_kernel", "linear_ce.pallas",
-     "linear_ce.chunked"),
-    ("automodel_tpu.loss.linear_ce", "linear_ce.chunked", None),
-    ("automodel_tpu.ops.gmm_kernel", "gmm.pallas", "gmm.xla_blocked"),
-    ("automodel_tpu.ops.qdot_kernel", "qdot.pallas", "qdot.xla"),
-    ("automodel_tpu.ops.quant", "qdot.xla", None),
-    ("automodel_tpu.ops.gmm_quant_kernel", "gmm_quant.pallas",
-     "gmm_quant.xla_blocked"),
+    ("automodel_tpu.ops.ring_attention", "attention.ring"),
+    ("automodel_tpu.ops.splash_attention", "attention.splash"),
+    ("automodel_tpu.ops.flash_attention", "attention.flash"),
+    ("automodel_tpu.ops.attention", "attention.sdpa"),
+    ("automodel_tpu.ops.paged_attention_kernel", "attention.paged_decode"),
+    ("automodel_tpu.ops.paged_attention", "attention.paged_gather"),
+    ("automodel_tpu.ops.linear_ce_kernel", "linear_ce.pallas"),
+    ("automodel_tpu.loss.linear_ce", "linear_ce.chunked"),
+    ("automodel_tpu.ops.gmm_kernel", "gmm.pallas"),
+    ("automodel_tpu.ops.qdot_kernel", "qdot.pallas"),
+    ("automodel_tpu.ops.quant", "qdot.xla"),
+    ("automodel_tpu.ops.gmm_quant_kernel", "gmm_quant.pallas"),
 )
 
 
 def ensure_default_kernels() -> None:
-    """Import every in-tree kernel module once so their registrations run;
-    a module that cannot import on this JAX gets a stub rung instead, so
-    resolution falls through it exactly like a failing probe."""
+    """Import every in-tree kernel module once so their registrations run.
+    An import failure propagates: a stubbed rung would let dispatch walk on
+    to the XLA rung with nothing but a warning."""
     global _defaults_loaded
     if _defaults_loaded:
         return
     _defaults_loaded = True     # set first: kernel modules import us back
-    import importlib
-
-    import logging
-
-    for mod, rung, fallback in _DEFAULT_KERNEL_MODULES:
-        try:
+    try:
+        for mod, rung in _DEFAULT_KERNEL_MODULES:
             importlib.import_module(mod)
-        except Exception as e:
-            # ImportError is the expected old-JAX shape, but upstream API
-            # drift can surface as AttributeError/TypeError at import —
-            # either way the chain must stay walkable past the dead rung
-            if not isinstance(e, ImportError):
-                logging.getLogger(__name__).warning(
-                    "kernel module %s failed to import (%s: %s); its rung "
-                    "%r is stubbed and dispatch falls through to %r",
-                    mod, type(e).__name__, e, rung, fallback)
-            register_stub(rung, fallback=fallback, reason=str(e))
-        else:
-            if rung not in _REGISTRY:   # module imported but didn't register
-                register_stub(rung, fallback=fallback,
-                              reason=f"{mod} registered no {rung!r}")
+            if rung not in _REGISTRY:
+                raise RuntimeError(f"{mod} registered no {rung!r} rung")
+    except BaseException:
+        _defaults_loaded = False    # the next caller sees the failure too
+        raise

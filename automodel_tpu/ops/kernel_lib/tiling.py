@@ -12,10 +12,9 @@ raw ``pl.BlockSpec`` / grid-spec / compiler-params construction outside
   with the hand-tuned values as the always-available defaults;
 * the VMEM-budgeted tile search (``fit_tile_pair``) and the legal-block
   divisor pick (``pick_block``) exist once instead of per kernel;
-* the TPUCompilerParams -> CompilerParams rename stays absorbed in
-  ``utils/jax_compat.py`` with the raised 64 MB ``vmem_limit_bytes``
-  default applied uniformly (Mosaic's 16 MB default is far under physical
-  VMEM and failed real tile choices — see ``linear_ce_kernel``'s history);
+* the raised 64 MB ``vmem_limit_bytes`` default is applied uniformly
+  (Mosaic's 16 MB default is far under physical VMEM and failed real tile
+  choices — see ``linear_ce_kernel``'s history);
 * the blockwise-attention math (online-softmax merge, tile validity /
   skip predicates) is shared between the ring kernel and any future
   blockwise consumer instead of re-derived.
@@ -46,13 +45,10 @@ _NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 def compiler_params(*, vmem_limit_bytes: int = DEFAULT_VMEM_LIMIT_BYTES,
                     **kwargs):
     """Pallas TPU compiler params with the framework-wide raised VMEM
-    ceiling.  Rides ``utils/jax_compat.pallas_tpu_compiler_params`` (the
-    L001-sanctioned home of the TPUCompilerParams -> CompilerParams rename
-    shim)."""
-    from automodel_tpu.utils.jax_compat import pallas_tpu_compiler_params
+    ceiling (L006: the one construction point)."""
+    from jax.experimental.pallas import tpu as pltpu
 
-    return pallas_tpu_compiler_params(
-        vmem_limit_bytes=vmem_limit_bytes, **kwargs)
+    return pltpu.CompilerParams(vmem_limit_bytes=vmem_limit_bytes, **kwargs)
 
 
 def block_spec(block_shape=None, index_map=None, *, memory_space=None):

@@ -57,11 +57,8 @@ _NEG_INF = -1e30
 # Mosaic's DEFAULT scoped-vmem budget is 16 MB, far under v5e's physical
 # 128 MB — tile choices near the default ceiling failed to compile at some
 # token counts (the pipeline's own buffering isn't in our estimate).  The
-# substrate default raises the kernel limit to 64 MB, giving the static
-# tile table real headroom; the params construction rides the
-# TPUCompilerParams -> CompilerParams rename shim via kernel_lib.tiling, so
-# this module (and everything importing it: loss/linear_ce.py, bench.py)
-# loads on both sides of it.
+# substrate default (kernel_lib.tiling.compiler_params) raises the kernel
+# limit to 64 MB, giving the static tile table real headroom.
 _COMPILER_PARAMS = tiling.compiler_params()
 
 
@@ -71,10 +68,7 @@ def linear_ce_kernel_available(n_tokens: int, hidden: int, vocab: int) -> bool:
         return False
     if _INTERPRET:
         return True
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return registry.on_tpu()
 
 
 def _tile_bytes(tm: int, tv: int, hidden: int,

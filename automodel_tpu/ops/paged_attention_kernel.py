@@ -66,10 +66,7 @@ def paged_decode_available(q_seq: int, head_dim: int) -> bool:
         return False
     if _INTERPRET:
         return True
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return registry.on_tpu()
 
 
 def _tile_bytes(kt: int, ge: int, bs: int, d: int, kv_itemsize: int,

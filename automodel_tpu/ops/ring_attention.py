@@ -238,9 +238,8 @@ def ring_attention(
     Hk = k.shape[2]
     G = Hq // Hk
     scale = D ** -0.5 if scale is None else scale
-    from automodel_tpu.utils.jax_compat import axis_size
 
-    cp = axis_size(axis_name)
+    cp = lax.axis_size(axis_name)
     my_idx = lax.axis_index(axis_name)
 
     qg = (q * scale).reshape(B, S, Hk, G, D)
@@ -309,7 +308,6 @@ def sharded_ring_attention(
     host-side; see ``ops/zigzag.py``).  ``batch_axes=None`` (default) uses
     the dp-family axes PRESENT in the mesh; an explicit tuple is used
     verbatim (typos fail loudly)."""
-    from automodel_tpu.utils.jax_compat import shard_map
     from jax.sharding import PartitionSpec as P
 
     from automodel_tpu.distributed.mesh import BATCH_AXES
@@ -328,14 +326,14 @@ def sharded_ring_attention(
         def wrapped(q, k, v):
             return fn(q, k, v, segment_ids=None)
 
-        return shard_map(
+        return jax.shard_map(
             wrapped, mesh=mesh, in_specs=(qspec, qspec, qspec),
             out_specs=qspec, check_vma=False)(q, k, v)
 
     def wrapped(q, k, v, seg):
         return fn(q, k, v, segment_ids=seg)
 
-    return shard_map(
+    return jax.shard_map(
         wrapped, mesh=mesh, in_specs=(qspec, qspec, qspec, sspec),
         out_specs=qspec, check_vma=False)(q, k, v, segment_ids)
 

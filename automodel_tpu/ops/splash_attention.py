@@ -60,12 +60,8 @@ _INTERPRET = False
 
 
 def splash_attention_available(q_seq: int, kv_seq: int, head_dim: int) -> bool:
-    try:
-        backend = jax.default_backend()
-    except Exception:
-        return False
     return (
-        backend == "tpu"
+        registry.on_tpu()
         and q_seq % _BLOCK == 0
         and kv_seq % _BLOCK == 0
         and head_dim >= 8
@@ -278,7 +274,6 @@ def sharded_splash_attention(
     whole (cp>1 routes to ring attention before reaching here).
     ``batch_axes=None`` (default) uses the dp-family axes PRESENT in the
     mesh; an explicit tuple is used verbatim (typos fail loudly)."""
-    from automodel_tpu.utils.jax_compat import shard_map
     from jax.sharding import PartitionSpec as P
 
     from automodel_tpu.distributed.mesh import BATCH_AXES
@@ -300,11 +295,11 @@ def sharded_splash_attention(
             local_window_size=local_window_size)
 
     if segment_ids is None:
-        return shard_map(
+        return jax.shard_map(
             lambda q, k, v: inner(q, k, v, None), mesh=mesh,
             in_specs=(qspec, qspec, qspec), out_specs=qspec,
             check_vma=False)(q, k, v)
-    return shard_map(
+    return jax.shard_map(
         inner, mesh=mesh,
         in_specs=(qspec, qspec, qspec, sspec), out_specs=qspec,
         check_vma=False)(q, k, v, segment_ids.astype(jnp.int32))

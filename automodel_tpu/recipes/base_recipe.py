@@ -96,20 +96,19 @@ class BaseRecipe:
 
     # -- shared setup hooks --------------------------------------------------
     def _setup_compile_cache(self, cfg: Optional[ConfigNode]) -> None:
-        """Wire the persistent XLA compile cache from the ``compile:`` YAML
-        section (the torch.compile-config analogue;
-        ``utils/compile_utils.py``).  First-compile of a 1B train step is
-        20-40s per process; with a shared cache dir the second run loads it
-        in under a second — the first dispatch's wall time is logged by the
-        recipes so cache hits are visible in the run log."""
-        if cfg is None or cfg.get("compile") is None:
-            return
+        """Place the persistent XLA compile cache by the one rule in
+        ``utils/compile_utils.py`` (``JAX_COMPILATION_CACHE_DIR`` if set,
+        else the fixed in-checkout directory); a ``compile:`` YAML section
+        can only disable it or tune the persist threshold.  The first
+        dispatch's wall time is logged by the recipes so cache hits are
+        visible in the run log."""
         from automodel_tpu.utils.compile_utils import (
             apply_compile_config,
             build_compile_config,
         )
 
-        apply_compile_config(build_compile_config(cfg.get("compile")))
+        apply_compile_config(build_compile_config(
+            cfg.get("compile") if cfg is not None else None))
 
     def _setup_kernel_autotune(self, cfg: Optional[ConfigNode], *,
                                model=None, seq_len=None,

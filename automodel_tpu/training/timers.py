@@ -285,7 +285,7 @@ class _Timer:
 
 def _device_barrier() -> None:
     # local_devices: jax.devices()[0] is unaddressable on processes > 0.
-    # device_get (not block_until_ready) so remote-tunnel runtimes truly sync.
+    # device_get: the fetched value cannot exist before the device is done.
     jax.device_get(  # lint: disable=L004 (this IS the barrier: a timer sync point, only reachable at log_level>=2 measurement runs)
         jax.device_put(np.zeros(()), jax.local_devices()[0]))
 

@@ -129,15 +129,13 @@ def _make_pp_shift(mesh, boundary_spec, pp: int):
     """
     from jax import lax as _lax
 
-    from automodel_tpu.utils.jax_compat import shard_map
-
     perm = [(i, i + 1) for i in range(pp - 1)]
 
     def _shift(y_local):
         return _lax.ppermute(y_local, AXIS_PP, perm)
 
-    return shard_map(_shift, mesh, in_specs=boundary_spec,
-                     out_specs=boundary_spec)
+    return jax.shard_map(_shift, mesh=mesh, in_specs=boundary_spec,
+                         out_specs=boundary_spec, check_vma=False)
 
 
 def _build_pipeline_loss(model, loss_fn, plan: ParallelPlan,

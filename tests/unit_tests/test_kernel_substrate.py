@@ -79,7 +79,10 @@ def test_cpu_attention_request_anchors_on_sdpa():
                "dtype": "float32", "causal": True, "soft_cap": False,
                "window": False, "traced_window": False, "cp_active": False,
                "mesh": None, "cp_layout": None}
+    before = registry.resolved_rungs().get("attention.sdpa", 0)
     assert registry.resolve("attention.ring", request).name == "attention.sdpa"
+    # the resolution log names the winner (what chip_smoke.py asserts on)
+    assert registry.resolved_rungs()["attention.sdpa"] == before + 1
 
 
 def test_cp_active_resolves_to_ring_unconditionally():
@@ -88,17 +91,38 @@ def test_cp_active_resolves_to_ring_unconditionally():
     assert registry.resolve("attention.ring", request).name == "attention.ring"
 
 
-def test_stub_rungs_keep_chain_walkable():
-    try:
-        registry.register_stub("_t.stub", fallback="_t.real")
-        registry.register_kernel("_t.real", probe=lambda r: True,
-                                 impl=lambda r: "real")
-        assert registry.resolve("_t.stub", {}).name == "_t.real"
-        with pytest.raises(RuntimeError, match="unavailable"):
-            registry.get_kernel("_t.stub").impl({})
-    finally:
-        for name in ("_t.stub", "_t.real"):
-            registry._REGISTRY.pop(name, None)
+def test_kernel_module_import_failure_raises(monkeypatch):
+    """A kernel module that cannot import is a bug on the one installation
+    there is — never a stubbed rung that lets the XLA rung run instead."""
+    monkeypatch.setattr(registry, "_defaults_loaded", False)
+    monkeypatch.setattr(registry, "_DEFAULT_KERNEL_MODULES",
+                        (("automodel_tpu.ops._no_such_kernel", "_t.x"),))
+    with pytest.raises(ImportError):
+        registry.ensure_default_kernels()
+    # not latched: the next caller sees the failure too
+    assert registry._defaults_loaded is False
+    # importable, but the rung it owes never registered
+    monkeypatch.setattr(registry, "_DEFAULT_KERNEL_MODULES",
+                        (("automodel_tpu.ops.norms", "_t.x"),))
+    with pytest.raises(RuntimeError, match="registered no"):
+        registry.ensure_default_kernels()
+
+
+def test_probe_lets_backend_failure_out(monkeypatch):
+    """A backend that fails to initialise must not read as "no kernel"."""
+    def boom():
+        raise RuntimeError("libtpu failed to initialise")
+
+    monkeypatch.setattr(jax, "default_backend", boom)
+    for chain, request in (
+            ("gmm.pallas", {"m": 256, "k": 128, "n": 128}),
+            ("qdot.pallas", {"m": 256, "k": 128, "n": 128}),
+            ("linear_ce.pallas", {"t": 256, "h": 128, "v": 256}),
+            ("attention.paged_decode", {"q_seq": 1, "head_dim": 128}),
+            ("attention.splash", {"q_seq": 256, "kv_seq": 256,
+                                  "head_dim": 64})):
+        with pytest.raises(RuntimeError, match="libtpu"):
+            registry.resolve(chain, request)
 
 
 # ---------------------------------------------------------------------------

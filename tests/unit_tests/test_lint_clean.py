@@ -2,11 +2,11 @@
 synthetic snippets, and THE tier-1 gate — both pillars run over the whole
 package asserting zero unsuppressed findings.
 
-The gate is what turns every rule into a standing invariant: introducing a
-raw ``jax.experimental.shard_map`` import, an unregistered enum knob, a
-``time.time()`` inside a jit function, a stray hot-loop ``device_get`` or
-an undrilled fault point anywhere in ``automodel_tpu/``/``tools/`` fails
-HERE with a rule ID and path:line.
+The gate is what turns every rule into a standing invariant: introducing
+an unregistered enum knob, a ``time.time()`` inside a jit function, a stray
+hot-loop ``device_get``, an undrilled fault point or a raw Pallas
+``BlockSpec``/``CompilerParams`` off the kernel substrate anywhere in
+``automodel_tpu/``/``tools/`` fails HERE with a rule ID and path:line.
 """
 
 import json
@@ -31,49 +31,6 @@ def _lint(src, rel="automodel_tpu/ops/fake.py", select=None):
 
 def _rules(findings):
     return [f.rule for f in findings]
-
-
-# ---------------------------------------------------------------------------
-# L001 — version-moved JAX APIs
-# ---------------------------------------------------------------------------
-def test_l001_flags_moved_shard_map_imports_and_attrs():
-    hits = _lint("import jax.experimental.shard_map\n")
-    assert _rules(hits) == ["L001"]
-    hits = _lint("from jax.experimental.shard_map import shard_map\n")
-    assert _rules(hits) == ["L001"]
-    hits = _lint("from jax import shard_map\n")
-    assert _rules(hits) == ["L001"]
-    hits = _lint(
-        "import jax\ndef f():\n    return jax.experimental.shard_map."
-        "shard_map(lambda x: x)\n")
-    assert "L001" in _rules(hits)
-
-
-def test_l001_flags_axis_size_and_compiler_params():
-    assert _rules(_lint(
-        "from jax import lax\ndef f(ax):\n    return lax.axis_size(ax)\n"
-    )) == ["L001"]
-    assert _rules(_lint(
-        "from jax.experimental.pallas import tpu as pltpu\n"
-        "p = pltpu.TPUCompilerParams(dimension_semantics=())\n"
-    )) == ["L001"]
-    assert _rules(_lint(
-        "from jax.experimental.pallas import tpu as pltpu\n"
-        "p = pltpu.CompilerParams()\n")) == ["L001"]
-
-
-def test_l001_clean_cases():
-    # the shim itself is exempt
-    assert _lint("from jax.experimental.shard_map import shard_map\n",
-                 rel="automodel_tpu/utils/jax_compat.py") == []
-    # routing through the shim is the sanctioned spelling
-    assert _lint(
-        "from automodel_tpu.utils.jax_compat import axis_size, shard_map\n"
-        "def f(ax):\n    return axis_size(ax)\n") == []
-    # unrelated pallas imports stay legal
-    assert _lint(
-        "from jax.experimental.pallas.ops.tpu.flash_attention import "
-        "flash_attention\n") == []
 
 
 # ---------------------------------------------------------------------------
@@ -192,9 +149,9 @@ def test_l004_suppression_requires_justification():
 def test_suppression_parser():
     sup = parse_suppressions(
         "x = 1\n"
-        "y  # lint: disable=L001,L004 (reason here)\n"
+        "y  # lint: disable=L003,L004 (reason here)\n"
         "z  # lint: disable=L003\n")
-    assert sup == {2: {"L001", "L004"}}
+    assert sup == {2: {"L003", "L004"}}
 
 
 # ---------------------------------------------------------------------------
@@ -246,13 +203,14 @@ def test_l006_flags_raw_blockspec_and_gridspec_construction():
     assert _rules(hits) == ["L006"]
 
 
-def test_l006_flags_raw_compiler_params_shim_calls():
+def test_l006_flags_raw_compiler_params_construction():
     hits = _lint(
-        "from automodel_tpu.utils.jax_compat import "
-        "pallas_tpu_compiler_params\n"
-        "p = pallas_tpu_compiler_params(dimension_semantics=())\n")
+        "from jax.experimental.pallas import tpu as pltpu\n"
+        "p = pltpu.CompilerParams(dimension_semantics=())\n")
     assert _rules(hits) == ["L006"]
-    assert "tiling.compiler_params" in hits[0].message
+    assert "tiling.py" in hits[0].message
+    # an unrelated object's CompilerParams is not Pallas construction
+    assert _lint("import mosaic\np = mosaic.CompilerParams()\n") == []
 
 
 def test_l006_exempts_the_substrate_and_accepts_suppressions():
@@ -284,8 +242,8 @@ def test_select_restricts_rules():
 
 
 def test_finding_format_carries_rule_id_and_location():
-    f = Finding("L001", "automodel_tpu/ops/x.py", 12, "msg")
-    assert f.format() == "automodel_tpu/ops/x.py:12: L001 msg"
+    f = Finding("L004", "automodel_tpu/ops/x.py", 12, "msg")
+    assert f.format() == "automodel_tpu/ops/x.py:12: L004 msg"
 
 
 # ---------------------------------------------------------------------------
@@ -313,12 +271,12 @@ def test_cli_exits_zero_and_emits_json(tmp_path):
 
 def test_cli_fails_on_a_seeded_violation(tmp_path):
     bad = tmp_path / "bad.py"
-    bad.write_text("import jax.experimental.shard_map\n")
+    bad.write_text("import jax\nx = jax.lax.ppermute(1, 'cp', [(0, 1)])\n")
     proc = subprocess.run(
         [sys.executable, os.path.join(_REPO, "tools", "lint.py"), str(bad)],
         capture_output=True, text=True, cwd=_REPO)
     assert proc.returncode == 1
-    assert "L001" in proc.stdout and "bad.py:1" in proc.stdout
+    assert "L007" in proc.stdout and "bad.py:2" in proc.stdout
 
 
 # ---------------------------------------------------------------------------

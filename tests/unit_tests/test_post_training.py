@@ -131,7 +131,10 @@ def test_logprobs_match_dense_log_softmax_oracle(model, params, sequences):
 
 def test_logprobs_padded_rows_exact(model, params, sequences):
     """Right-padding cannot change any valid position's value (causal
-    attention + ignored pad labels) — EXACT, not approximate."""
+    attention + ignored pad labels).  The two pad widths are two compiled
+    programs, and XLA promises no bitwise equality across shapes (the
+    reduction blocking follows the shape), so "same value" is held to
+    4 float32 ulp — a leaked pad token would move values by far more."""
     seqs, plens = sequences
     tight = make_sequence_batch(seqs, plens)            # S = longest
     wide = make_sequence_batch(seqs, plens, pad_to=24)  # extra pads
@@ -139,7 +142,8 @@ def test_logprobs_padded_rows_exact(model, params, sequences):
     lp_t = np.asarray(fn(params, tight))
     lp_w = np.asarray(fn(params, wide))
     S = lp_t.shape[1]
-    np.testing.assert_array_equal(lp_t, lp_w[:, :S])
+    assert lp_t.dtype == lp_w.dtype == np.float32
+    np.testing.assert_array_max_ulp(lp_t, lp_w[:, :S], maxulp=4)
     assert np.all(lp_w[:, S:] == 0.0)                   # pads contribute 0
 
 

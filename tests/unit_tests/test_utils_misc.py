@@ -30,23 +30,3 @@ def test_first_rank_first_single_process():
 
     with first_rank_first() as is_leader:
         assert is_leader  # single process is always the leader
-
-
-def test_compile_config_applies_cache_dir(tmp_path, monkeypatch):
-    import jax
-
-    from automodel_tpu.utils.compile_utils import (
-        apply_compile_config,
-        build_compile_config,
-    )
-
-    cfg = build_compile_config(
-        None, enabled=True, cache_dir=str(tmp_path), mode="max-autotune")
-    assert cfg.mode == "max-autotune"  # torch knob accepted, ignored
-    apply_compile_config(cfg)
-    assert jax.config.jax_compilation_cache_dir == str(tmp_path)
-
-    # disabled config must not touch the setting
-    apply_compile_config(build_compile_config(None, enabled=False,
-                                              cache_dir="/nope"))
-    assert jax.config.jax_compilation_cache_dir == str(tmp_path)

@@ -84,9 +84,9 @@ def main(argv=None) -> int:
                       help="delete the cache file")
     mode.add_argument("--sweep", action="store_true",
                       help="time candidates and persist winners")
-    parser.add_argument("--cache", help="cache file (default: alongside the "
-                        "configured XLA compile cache, else "
-                        "~/.cache/automodel_tpu/)")
+    parser.add_argument("--cache", help="cache file (default: beside the "
+                        "XLA compile cache — JAX_COMPILATION_CACHE_DIR if "
+                        "exported, else the in-checkout .jax_cache/)")
     parser.add_argument("--config", help="with --sweep: recipe YAML whose "
                         "model/sequence shapes to pre-warm")
     parser.add_argument("--kernel", help="with --sweep: one kernel key "
@@ -112,6 +112,9 @@ def main(argv=None) -> int:
         return 0
 
     # --sweep
+    from automodel_tpu.utils.compile_utils import setup_compile_cache
+
+    setup_compile_cache()
     requests = []
     if args.kernel:
         if not args.shape:

@@ -67,7 +67,9 @@ def main(argv=None) -> int:
         CheckpointEvalWatcher,
         rows_from_eval_config,
     )
+    from automodel_tpu.utils.compile_utils import setup_compile_cache
 
+    setup_compile_cache()
     cfg = load_yaml_config(args.config)
     model = cfg.get("model").instantiate()
     ckpt_cfg = build_checkpoint_config(cfg.get("checkpoint"))
