@@ -432,14 +432,15 @@ class LlamaForCausalLM:
         """Train/prefill/decode attention + cache update on rotated q/k."""
         S = q.shape[1]
         scale = self._attn_softmax_scale
-        if kv_cache is not None and hasattr(kv_cache, "layer_view"):
+        if kv_cache is not None and hasattr(kv_cache, "at_layer"):
             # Serving path: a block-paged cache view (duck-typed so models
             # never import the serving layer — see
-            # ``serving/kv_cache.PagedKVView``).  Write this step's k/v
-            # into the per-layer pools at the view's slot mapping, then
-            # attend the paged history through the
+            # ``serving/kv_cache.PagedKVView``) standing at this layer of
+            # the stacked pools.  Write this step's k/v into the layer's
+            # slots, then attend the paged history through the
             # ``attention.paged_decode`` kernel chain; chunked prefill
             # (S > 1) attends earlier chunks via the same block tables.
+            # What comes back as the new cache is the stacked pools.
             with jax.named_scope("kv_write"):
                 new_pools = kv_cache.write(k, v)
             with jax.named_scope("attn_core"):
@@ -650,19 +651,27 @@ class LlamaForCausalLM:
         layer_idx = jnp.arange(cfg.num_hidden_layers, dtype=jnp.int32)
 
         decoding = kv_cache is not None
-        # Paged serving cache: only the [L, ...] pools ride the layer scan's
-        # xs; the addressing arrays (block tables, slot mapping, context
-        # lengths) are layer-invariant and close over the scan body.  The
-        # returned "kv_cache" is then the stacked updated pools dict.
+        # Paged serving cache: the stacked [L, ...] pools ride the layer
+        # scan as CARRY beside the hidden state, and each layer writes and
+        # reads them in place at its own index.  As xs/ys a scan would
+        # slice one layer of pool out and stack one back into a fresh
+        # buffer per layer (and XLA would copy the donated pools whole to
+        # keep them readable meanwhile).  The addressing arrays (block
+        # tables, slot mapping, context lengths) are layer-invariant and
+        # close over the scan body.  The returned "kv_cache" is the carried
+        # pools dict.  The dense dict cache keeps its xs/ys.
         paged_view = kv_cache if (decoding
-                                  and hasattr(kv_cache, "layer_view")) \
+                                  and hasattr(kv_cache, "at_layer")) \
             else None
-        cache_xs = kv_cache.pools if paged_view is not None else kv_cache
+        cache_xs = None if paged_view is not None else kv_cache
 
-        def one_layer(h, xs):
+        def one_layer(carry, xs):
             layer_params, ad, idx, cache = xs
             if paged_view is not None:
-                cache = paged_view.layer_view(cache)
+                h, pools = carry
+                cache = paged_view.at_layer(pools, idx)
+            else:
+                h = carry
             rng = (jax.random.fold_in(dropout_rng, idx)
                    if dropout_rng is not None else None)
             # Grouped multi-LoRA routing only exists on models whose
@@ -677,6 +686,8 @@ class LlamaForCausalLM:
                 kv_cache=cache, cache_index=cache_index,
                 rope_scale=rope_scale, **extra,
             )
+            if paged_view is not None:
+                return (h, new_cache), (None, aux)
             return h, (new_cache, aux)
 
         L = cfg.num_hidden_layers
@@ -710,9 +721,14 @@ class LlamaForCausalLM:
         if block > 1:
             xs = jax.tree.map(
                 lambda a: a.reshape(L // block, block, *a.shape[1:]), xs)
+        init = hidden if paged_view is None else (hidden, paged_view.pools)
         with jax.named_scope("layers"):
-            hidden, (new_cache, aux_losses) = lax.scan(
-                body, hidden, xs, unroll=self.scan_unroll)
+            carry, (new_cache, aux_losses) = lax.scan(
+                body, init, xs, unroll=self.scan_unroll)
+        if paged_view is None:
+            hidden = carry
+        else:
+            hidden, new_cache = carry
         if block > 1 and (new_cache is not None or aux_losses is not None):
             # ys come back [L/block, block, ...] -> flatten to [L, ...]
             new_cache, aux_losses = jax.tree.map(

@@ -97,19 +97,22 @@ def _tol(dtype: str, native: bool, interpret_tol: float) -> float:
     return NATIVE_TOL[dtype]
 
 
-def _execute(spec, request, args, kwargs, native: bool):
-    """(out, ref) of one rung on one built case."""
+def _execute(spec, request, args, kwargs, native: bool, ref_args=None):
+    """(out, ref) of one rung on one built case.  ``ref_args``: operands
+    for the reference where they differ from the rung's (the paged family
+    hands it the one layer the rung must address)."""
     assert spec.reference is not None, f"{spec.name} has no XLA reference"
+    ref_args = args if ref_args is None else ref_args
     if not native:
         with interpret_mode():
             out = spec.impl(request, *args, **kwargs)
-        return out, spec.reference(request, *args, **kwargs)
+        return out, spec.reference(request, *ref_args, **kwargs)
     on = interpret_flags_on()
     assert not on, f"native parity with _INTERPRET on in {on}"
     out = jax.jit(lambda *a: spec.impl(request, *a, **kwargs))(*args)
     with jax.default_matmul_precision("highest"):
         ref = jax.jit(
-            lambda *a: spec.reference(request, *a, **kwargs))(*args)
+            lambda *a: spec.reference(request, *a, **kwargs))(*ref_args)
     return out, ref
 
 
@@ -237,7 +240,10 @@ def paged_attention_cases() -> List[Dict]:
     """Decode (q=1), speculative-verify (q=spec_k+1) and chunked-prefill
     (q>1) traffic over scrambled block tables with ragged per-row context
     lengths; the int8 cases exercise the quantized-KV dequant inside each
-    rung.  Shape keys: ``B Hq Hk D BS MB``."""
+    rung.  Every case stacks ``L`` layers of pool with contents of their
+    own and attends ``layer`` (default: the middle of three), so a rung
+    that addresses another layer fails; two cases stand at the first and
+    the last.  Shape keys: ``B Hq Hk D BS MB L layer``."""
     return [
         dict(name="decode_gqa", q_seq=1, dtype="float32"),
         dict(name="decode_bf16", q_seq=1, dtype="bfloat16"),
@@ -254,13 +260,17 @@ def paged_attention_cases() -> List[Dict]:
         dict(name="chunked_prefill", q_seq=8, dtype="float32"),
         dict(name="chunked_prefill_int8_kv", q_seq=8, dtype="float32",
              quantized=True),
+        dict(name="decode_first_layer", q_seq=1, dtype="float32", layer=0),
+        dict(name="chunked_prefill_last_layer_int8_kv", q_seq=8,
+             dtype="float32", quantized=True, layer=2),
     ]
 
 
 def build_paged_attention_case(case: Dict, *, B=2, Hq=4, Hk=2, D=128,
-                               BS=16, MB=4):
+                               BS=16, MB=4, L=3, layer=1):
     B, Hq, Hk = case.get("B", B), case.get("Hq", Hq), case.get("Hk", Hk)
     D, BS, MB = case.get("D", D), case.get("BS", BS), case.get("MB", MB)
+    L, layer = case.get("L", L), case.get("layer", layer)
     rng = np.random.default_rng(7)
     dtype = jnp.dtype(case.get("dtype", "float32"))
     S = case["q_seq"]
@@ -270,18 +280,18 @@ def build_paged_attention_case(case: Dict, *, B=2, Hq=4, Hk=2, D=128,
         dtype)
     if quantized:
         k_pool = jnp.asarray(
-            rng.integers(-127, 128, (NB, BS, Hk, D)), jnp.int8)
+            rng.integers(-127, 128, (L, NB, BS, Hk, D), np.int8))
         v_pool = jnp.asarray(
-            rng.integers(-127, 128, (NB, BS, Hk, D)), jnp.int8)
+            rng.integers(-127, 128, (L, NB, BS, Hk, D), np.int8))
         k_scale = jnp.asarray(
-            rng.uniform(0.005, 0.02, (NB, BS, Hk)), jnp.float32)
+            rng.uniform(0.005, 0.02, (L, NB, BS, Hk)), jnp.float32)
         v_scale = jnp.asarray(
-            rng.uniform(0.005, 0.02, (NB, BS, Hk)), jnp.float32)
+            rng.uniform(0.005, 0.02, (L, NB, BS, Hk)), jnp.float32)
     else:
-        k_pool = jnp.asarray(rng.normal(size=(NB, BS, Hk, D)),
-                             jnp.float32).astype(dtype)
-        v_pool = jnp.asarray(rng.normal(size=(NB, BS, Hk, D)),
-                             jnp.float32).astype(dtype)
+        k_pool = jnp.asarray(
+            rng.standard_normal((L, NB, BS, Hk, D), np.float32)).astype(dtype)
+        v_pool = jnp.asarray(
+            rng.standard_normal((L, NB, BS, Hk, D), np.float32)).astype(dtype)
         k_scale = v_scale = None
     # scrambled, per-row-disjoint block tables (block 0 = null page)
     perm = rng.permutation(np.arange(1, NB)).reshape(B, MB)
@@ -303,15 +313,23 @@ def build_paged_attention_case(case: Dict, *, B=2, Hq=4, Hk=2, D=128,
         q, k_pool, quantized=quantized,
         soft_cap="logits_soft_cap" in kwargs,
         window="local_window_size" in kwargs)
-    return (q, k_pool, v_pool, k_scale, v_scale, block_tables,
-            jnp.asarray(ctx), positions), kwargs, request
+    return (q, k_pool, v_pool, k_scale, v_scale, jnp.int32(layer),
+            block_tables, jnp.asarray(ctx), positions), kwargs, request
 
 
 def run_paged_attention_parity(spec_name: str, case: Dict,
                                native: bool = False) -> float:
+    """The rung over the stacked pools at the case's layer against the
+    reference over THAT layer's pools alone (``L = 1, layer = 0``): both
+    speak the one contract, and neither can agree with the other by
+    reading the same wrong layer."""
     spec = registry.get_kernel(spec_name)
     args, kwargs, request = build_paged_attention_case(case)
-    out, ref = _execute(spec, request, args, kwargs, native)
+    q, *pools, layer, tables, ctx, positions = args
+    own = slice(int(layer), int(layer) + 1)
+    ref_args = (q, *(None if p is None else p[own] for p in pools),
+                jnp.int32(0), tables, ctx, positions)
+    out, ref = _execute(spec, request, args, kwargs, native, ref_args)
     tol = _tol(str(args[0].dtype), native,
                2e-2 if args[0].dtype == jnp.bfloat16 else 2e-3)
     return _compare(out, ref, tol, native,

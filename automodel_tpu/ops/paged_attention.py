@@ -1,11 +1,12 @@
 """Paged attention over a block-paged KV cache — the serving decode path.
 
 The serving engine (``automodel_tpu/serving``) keeps every request's KV
-history in fixed-size *blocks* of a static ``[num_blocks, block_size, Hk,
-D]`` pool; a per-request *block table* names which pool blocks hold its
-positions ``0..context_len-1`` (position ``p`` lives in slot ``p %
-block_size`` of block ``table[p // block_size]``).  Attention over that
-layout is its own kernel family on the PR-7 substrate:
+history in fixed-size *blocks* of a static pool, ``[num_blocks,
+block_size, Hk, D]`` per layer and stacked over the layers; a per-request
+*block table* names which pool blocks hold its positions
+``0..context_len-1`` (position ``p`` lives in slot ``p % block_size`` of
+block ``table[p // block_size]``, in every layer alike).  Attention over
+that layout is its own kernel family on the PR-7 substrate:
 
 * ``attention.paged_decode`` — Pallas gather-by-block-table online-softmax
   decode (``ops/paged_attention_kernel.py``): the block table rides scalar
@@ -27,9 +28,15 @@ Both rungs speak one request/operand contract (:func:`paged_attention`):
 * ``q [B, S, Hq, D]`` — per-row query tokens at CONSECUTIVE positions
   ``positions[b, t]`` (pad columns repeat the last valid position and are
   discarded by the caller);
-* ``k_pool / v_pool [NB, BS, Hk, D]`` — position-major pools, optionally
-  int8 with per-slot-per-head scale planes ``[NB, BS, Hk]`` (the
-  quantized KV cache, see ``serving/kv_cache.py``);
+* ``k_pool / v_pool [L, NB, BS, Hk, D]`` — the position-major pools of
+  ALL layers, stacked, optionally int8 with per-slot-per-head scale planes
+  ``[L, NB, BS, Hk]`` (the quantized KV cache, see
+  ``serving/kv_cache.py``), and ``layer`` — an int32 scalar, traced or
+  not, naming the layer to attend.  A rung addresses the stacked pool AT
+  the layer (page ``layer * NB + block``) and never takes ``pool[layer]``
+  first: inside the engine's layer scan the pools are the loop's carry,
+  and a slice of them would be a copy of one layer per layer per step.  A
+  caller with one layer's pools passes ``L = 1, layer = 0``;
 * ``block_tables [B, MB]`` int32, ``context_lens [B]`` int32 (valid
   positions INCLUDING tokens written this step).  Rows must satisfy
   ``context_lens >= 1`` and ``positions >= 0`` so every query has at least
@@ -57,34 +64,40 @@ def dequantize_pool(pool: jnp.ndarray, scale: Optional[jnp.ndarray],
     return pool.astype(jnp.float32) * scale[..., None].astype(jnp.float32)
 
 
-def gathered_cache(pool: jnp.ndarray, scale: Optional[jnp.ndarray],
+def gathered_cache(pool: jnp.ndarray, scale: Optional[jnp.ndarray], layer,
                    block_tables: jnp.ndarray, dtype=jnp.float32):
-    """Linearize a row's pool blocks by position: ``[B, MB*BS, Hk, D]``.
+    """Linearize a row's blocks of layer ``layer`` of the stacked pool by
+    position: ``[B, MB*BS, Hk, D]``.
 
     Because block tables are position-major (position ``p`` -> slot ``p %
     BS`` of ``table[p // BS]``), gathering blocks in table order IS the
-    dense per-row cache reconstruction.
+    dense per-row cache reconstruction.  The gather runs over the pool's
+    ``[L*NB, ...]`` pages at ``layer * NB + table``, so no layer of the
+    pool is ever materialised.
     """
-    g = pool[block_tables]                       # [B, MB, BS, Hk, D]
-    gs = scale[block_tables] if scale is not None else None
+    L, NB = pool.shape[:2]
+    pages = jnp.asarray(layer, jnp.int32) * NB + block_tables
+    g = pool.reshape(L * NB, *pool.shape[2:])[pages]   # [B, MB, BS, Hk, D]
+    gs = None if scale is None else scale.reshape(
+        L * NB, *scale.shape[2:])[pages]
     B, MB, BS = g.shape[:3]
     g = dequantize_pool(g, gs, dtype).reshape(B, MB * BS, *g.shape[3:])
     return g
 
 
-def _paged_gather_impl(request, q, k_pool, v_pool, k_scale, v_scale,
+def _paged_gather_impl(request, q, k_pool, v_pool, k_scale, v_scale, layer,
                        block_tables, context_lens, positions, *,
                        scale=None, logits_soft_cap=None,
                        local_window_size=None):
     """XLA anchor: gather-by-table + masked SDPA, any query length."""
     B, S, Hq, D = q.shape
-    Hk = k_pool.shape[2]
+    Hk = k_pool.shape[3]
     assert Hq % Hk == 0, f"query heads {Hq} not a multiple of kv heads {Hk}"
     G = Hq // Hk
     scale = D ** -0.5 if scale is None else scale
 
-    keys = gathered_cache(k_pool, k_scale, block_tables)    # [B, K, Hk, D]
-    vals = gathered_cache(v_pool, v_scale, block_tables)
+    keys = gathered_cache(k_pool, k_scale, layer, block_tables)  # [B,K,Hk,D]
+    vals = gathered_cache(v_pool, v_scale, layer, block_tables)
     K = keys.shape[1]
 
     qg = q.reshape(B, S, Hk, G, D)
@@ -107,7 +120,7 @@ def _paged_gather_impl(request, q, k_pool, v_pool, k_scale, v_scale,
     return out.reshape(B, S, Hq, D).astype(q.dtype)
 
 
-def paged_reference(request, q, k_pool, v_pool, k_scale, v_scale,
+def paged_reference(request, q, k_pool, v_pool, k_scale, v_scale, layer,
                     block_tables, context_lens, positions, *,
                     scale=None, logits_soft_cap=None,
                     local_window_size=None):
@@ -119,8 +132,8 @@ def paged_reference(request, q, k_pool, v_pool, k_scale, v_scale,
     the same numbers."""
     from automodel_tpu.ops.attention import dot_product_attention
 
-    keys = gathered_cache(k_pool, k_scale, block_tables)
-    vals = gathered_cache(v_pool, v_scale, block_tables)
+    keys = gathered_cache(k_pool, k_scale, layer, block_tables)
+    vals = gathered_cache(v_pool, v_scale, layer, block_tables)
     K = keys.shape[1]
 
     def row(qb, kb, vb, ctx, pos0):
@@ -144,14 +157,14 @@ def build_paged_request(q, k_pool, *, quantized: bool,
     return {
         "kind": "paged_attention",
         "q_seq": q.shape[1], "head_dim": q.shape[3],
-        "num_q_heads": q.shape[2], "num_kv_heads": k_pool.shape[2],
-        "num_blocks": k_pool.shape[0], "block_size": k_pool.shape[1],
+        "num_q_heads": q.shape[2], "num_kv_heads": k_pool.shape[3],
+        "num_blocks": k_pool.shape[1], "block_size": k_pool.shape[2],
         "dtype": str(q.dtype), "quantized": bool(quantized),
         "soft_cap": bool(soft_cap), "window": bool(window),
     }
 
 
-def paged_attention(q, k_pool, v_pool, *, block_tables, context_lens,
+def paged_attention(q, k_pool, v_pool, *, layer, block_tables, context_lens,
                     positions, k_scale=None, v_scale=None, scale=None,
                     logits_soft_cap=None, local_window_size=None):
     """The serving path's attention entry point: build one request and
@@ -163,7 +176,7 @@ def paged_attention(q, k_pool, v_pool, *, block_tables, context_lens,
         window=local_window_size is not None)
     spec = registry.resolve("attention.paged_decode", request)
     return spec.impl(
-        request, q, k_pool, v_pool, k_scale, v_scale, block_tables,
+        request, q, k_pool, v_pool, k_scale, v_scale, layer, block_tables,
         context_lens, positions, scale=scale,
         logits_soft_cap=logits_soft_cap,
         local_window_size=local_window_size)

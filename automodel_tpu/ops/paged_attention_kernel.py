@@ -107,9 +107,9 @@ def _head_tile(hk: int, g: int, s: int, bs: int, d: int, kv_itemsize: int,
     return int(choice[0])
 
 
-def _decode_kernel(bt_ref, cl_ref, p0_ref, q_ref, k_ref, v_ref, ks_ref,
-                   vs_ref, o_ref, m_ref, l_ref, acc_ref, *, bs, kt, g, s_q,
-                   scale, soft_cap, window, quantized):
+def _decode_kernel(bt_ref, cl_ref, p0_ref, ly_ref, q_ref, k_ref, v_ref,
+                   ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref, *, bs, kt, g,
+                   s_q, scale, soft_cap, window, quantized):
     from jax.experimental import pallas as pl
 
     b, j = pl.program_id(0), pl.program_id(2)
@@ -174,12 +174,17 @@ def _decode_kernel(bt_ref, cl_ref, p0_ref, q_ref, k_ref, v_ref, ks_ref,
             o_ref.dtype)
 
 
-def paged_decode_pallas(q, k_pool, v_pool, k_scale, v_scale, block_tables,
-                        context_lens, positions=None, *, scale=None,
-                        logits_soft_cap=None, local_window_size=None):
+def paged_decode_pallas(q, k_pool, v_pool, k_scale, v_scale, layer,
+                        block_tables, context_lens, positions=None, *,
+                        scale=None, logits_soft_cap=None,
+                        local_window_size=None):
     """``q [B, S, Hq, D]`` (small S — decode 1, verify spec_k+1, chunked
-    prefill) over position-major pools ``[NB, BS, Hk, D]`` (+ optional
-    int8 scale planes ``[NB, BS, Hk]``) -> ``[B, S, Hq, D]``.
+    prefill) over layer ``layer`` (int32 scalar, traced or not) of the
+    stacked position-major pools ``[L, NB, BS, Hk, D]`` (+ optional int8
+    scale planes ``[L, NB, BS, Hk]``) -> ``[B, S, Hq, D]``.  The layer
+    index rides scalar prefetch beside the block tables and leads every
+    page's index map, so the kernel's operand is the whole stacked pool
+    and only the pages a row owns in that layer are ever read.
 
     ``positions [B, S]``: each query token's absolute position.  The
     kernel prefetches only column 0 and derives the rest as ``pos0 + s``
@@ -190,7 +195,7 @@ def paged_decode_pallas(q, k_pool, v_pool, k_scale, v_scale, block_tables,
     from jax.experimental import pallas as pl
 
     B, S, Hq, D = q.shape
-    NB, BS, Hk, _ = k_pool.shape
+    _, _, BS, Hk, _ = k_pool.shape
     MB = block_tables.shape[1]
     assert S <= _MAX_CHUNKED_Q, "paged_decode is the small-q rung"
     G = Hq // Hk
@@ -208,20 +213,26 @@ def paged_decode_pallas(q, k_pool, v_pool, k_scale, v_scale, block_tables,
     # [B, S, Hq, D] -> [B, S, Hk, G, D] -> [B, Hk, S, G, D] -> fold (S, G)
     q4 = q.reshape(B, S, Hk, G, D).transpose(0, 2, 1, 3, 4).reshape(
         B, Hk, GE, D)
-    if not quantized:
+    if quantized:
+        # The scale planes' minor dims (BS, Hk) are far under a lane tile,
+        # so XLA keeps the planes NB-minor on the device while a Mosaic
+        # operand is row-major: handing over the stacked plane would relay
+        # out all L layers of it on every call.  One layer's slice costs
+        # 1/L of that (a plane is 1/32 of its pool at D=128).
+        k_scale, v_scale = (jax.lax.dynamic_slice_in_dim(x, layer, 1)
+                            for x in (k_scale, v_scale))
+    else:
         # uniform kernel signature: zero-page dummies the specs still index
-        k_scale = jnp.ones((1, BS, Hk), jnp.float32)
-        v_scale = jnp.ones((1, BS, Hk), jnp.float32)
+        k_scale = jnp.ones((1, 1, BS, Hk), jnp.float32)
+        v_scale = jnp.ones((1, 1, BS, Hk), jnp.float32)
 
-    def page_index(b, h, j, bt, cl, p0):
-        return (bt[b, j], 0, h, 0)
+    def page_index(b, h, j, bt, cl, p0, ly):
+        return (ly[0], bt[b, j], 0, h, 0)
 
-    def scale_index(b, h, j, bt, cl, p0):
-        if quantized:
-            return (bt[b, j], 0, h)
-        return (0, 0, h)
+    def scale_index(b, h, j, bt, cl, p0, ly):
+        return (0, bt[b, j] if quantized else 0, 0, h)
 
-    def q_index(b, h, j, bt, cl, p0):
+    def q_index(b, h, j, bt, cl, p0, ly):
         return (b, h, 0, 0)
 
     out = pl.pallas_call(
@@ -230,14 +241,15 @@ def paged_decode_pallas(q, k_pool, v_pool, k_scale, v_scale, block_tables,
             soft_cap=logits_soft_cap, window=local_window_size,
             quantized=quantized),
         grid_spec=tiling.prefetch_grid_spec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4,
             grid=(B, Hk // kt, MB),
             in_specs=[
                 tiling.block_spec((1, kt, GE, D), q_index),
-                tiling.block_spec((1, BS, kt, D), page_index),
-                tiling.block_spec((1, BS, kt, D), page_index),
-                tiling.block_spec((1, BS, kt), scale_index),
-                tiling.block_spec((1, BS, kt), scale_index),
+                # the layer axis is squeezed: the body sees one page
+                tiling.block_spec((None, 1, BS, kt, D), page_index),
+                tiling.block_spec((None, 1, BS, kt, D), page_index),
+                tiling.block_spec((None, 1, BS, kt), scale_index),
+                tiling.block_spec((None, 1, BS, kt), scale_index),
             ],
             out_specs=tiling.block_spec((1, kt, GE, D), q_index),
             scratch_shapes=[
@@ -250,7 +262,8 @@ def paged_decode_pallas(q, k_pool, v_pool, k_scale, v_scale, block_tables,
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_INTERPRET,
     )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
-      pos0, q4, k_pool, v_pool, k_scale, v_scale)
+      pos0, jnp.asarray(layer, jnp.int32).reshape(1), q4, k_pool, v_pool,
+      k_scale, v_scale)
     # unfold (S, G) and restore [B, S, Hq, D]
     return out.reshape(B, Hk, S, G, D).transpose(0, 2, 1, 3, 4).reshape(
         B, S, Hq, D)
@@ -269,7 +282,7 @@ def _paged_decode_probe(request) -> bool:
     return paged_decode_available(request["q_seq"], request["head_dim"])
 
 
-def _paged_decode_impl(request, q, k_pool, v_pool, k_scale, v_scale,
+def _paged_decode_impl(request, q, k_pool, v_pool, k_scale, v_scale, layer,
                        block_tables, context_lens, positions, *,
                        scale=None, logits_soft_cap=None,
                        local_window_size=None):
@@ -281,8 +294,9 @@ def _paged_decode_impl(request, q, k_pool, v_pool, k_scale, v_scale,
     # repointed at ``paged_decode`` (ROADMAP Design 10); then it goes.
     with jax.named_scope("paged_decode"), jax.named_scope("closed_call"):
         return paged_decode_pallas(
-            q, k_pool, v_pool, k_scale, v_scale, block_tables, context_lens,
-            positions, scale=scale, logits_soft_cap=logits_soft_cap,
+            q, k_pool, v_pool, k_scale, v_scale, layer, block_tables,
+            context_lens, positions, scale=scale,
+            logits_soft_cap=logits_soft_cap,
             local_window_size=local_window_size)
 
 
@@ -320,12 +334,12 @@ def _sweep_run(req, choice) -> float:
     dtype = jnp.dtype(req.get("dtype", "bfloat16"))
     q = jax.random.normal(key, (b, s, hq, d), jnp.float32).astype(dtype)
     if quant:
-        kp = jax.random.randint(key, (nb, bs, hk, d), -127, 128, jnp.int8)
+        kp = jax.random.randint(key, (1, nb, bs, hk, d), -127, 128, jnp.int8)
         vp = kp
-        ks = jnp.full((nb, bs, hk), 0.01, jnp.float32)
+        ks = jnp.full((1, nb, bs, hk), 0.01, jnp.float32)
         vs = ks
     else:
-        kp = jax.random.normal(key, (nb, bs, hk, d), jnp.float32).astype(
+        kp = jax.random.normal(key, (1, nb, bs, hk, d), jnp.float32).astype(
             dtype)
         vp = kp
         ks = vs = None
@@ -334,7 +348,8 @@ def _sweep_run(req, choice) -> float:
     pos = ctx[:, None] - s + jnp.arange(s, dtype=jnp.int32)[None, :]
 
     fn = jax.jit(functools.partial(paged_decode_pallas, scale=None))
-    return autotune.time_call(fn, q, kp, vp, ks, vs, tables, ctx, pos)
+    return autotune.time_call(fn, q, kp, vp, ks, vs, jnp.int32(0), tables,
+                              ctx, pos)
 
 
 registry.register_kernel(

@@ -14,9 +14,13 @@ that with three static-shape ingredients:
   aborts, expiries and rejections only change the *contents* of the
   buffers (the tier-1 suite holds ``assert_compiles_once`` across a
   multi-request run);
-* **the paged KV cache** (``serving/kv_cache.py``) — pools donated through
-  the step so cache updates are in-place, block tables assembled host-side
-  from the scheduler's plan;
+* **the paged KV cache** (``serving/kv_cache.py``) — the stacked pools are
+  donated to the step, ride the model's layer scan as its carry, and are
+  scatter-written and read in place at each layer's index: no instruction
+  of the step program copies, slices or reallocates a K or V pool or a
+  layer of one (``tests/unit_tests/test_program_spans.py`` compiles the
+  step for a v5e and looks).  Block tables are assembled host-side from the
+  scheduler's plan;
 * **the scheduler** (``serving/scheduler.py``) — WAITING → PREFILL →
   DECODE → FINISHED per request, chunked prefill sharing step slots with
   decode, in-flight admission when blocks free up, and recompute
@@ -250,11 +254,12 @@ def _paged_step(model, block_size: int, quantized: bool, cow_enabled: bool,
     """ONE traced program per step width: run any pending copy-on-write
     block forks, write this step's tokens into the paged cache, attend,
     and greedy-pick EVERY column's next token.  Returns ``(greedy [B, W],
-    last_logits [B, V], pools)`` — pools donated, so the cache updates in
-    place.  Plain decode reads its one token at its last valid column of
-    ``greedy``; the speculative verify reads the argmax at each draft
-    position from the same array — the per-column argmax IS the verify,
-    so acceptance costs no extra device work and no extra fetch.
+    last_logits [B, V], pools)`` — the pools are donated and come back as
+    the layer scan's carry (``models/llama.py::forward_embeds``), so the
+    cache updates in place.  Plain decode reads its one token at its last
+    valid column of ``greedy``; the speculative verify reads the argmax at
+    each draft position from the same array — the per-column argmax IS the
+    verify, so acceptance costs no extra device work and no extra fetch.
 
     ``cow_src``/``cow_dst`` are fixed ``[B]`` block-id pairs: rows with a
     prefix-cache fork copy their shared last block into a private one

@@ -4,9 +4,10 @@ the pytree view the model's attention core consumes.
 The dense decode cache (``model.init_kv_cache``) reserves ``[B, S_max]``
 rows per request — at serving batch sizes that is almost entirely dead HBM
 (most requests are far shorter than the max).  The paged cache instead
-keeps ONE static pool of fixed-size blocks per layer,
+keeps ONE static pool of fixed-size blocks per layer, stacked over the
+layers into one buffer the step donates and updates in place,
 
-    ``k/v: [num_blocks, block_size, Hk, D]``  (position-major),
+    ``k/v: [L, num_blocks, block_size, Hk, D]``  (position-major),
 
 and a per-request *block table* mapping position ``p`` to slot ``p %
 block_size`` of block ``table[p // block_size]``.  Blocks are recycled
@@ -21,7 +22,7 @@ block-table entries point at it, so scatter/gather shapes stay static and
 garbage is never read (context-length masks exclude it).
 
 ``serving.kv_cache_dtype: int8`` stores the pools quantized with per-slot
-per-kv-head scale planes ``[num_blocks, block_size, Hk]`` — the scale
+per-kv-head scale planes ``[L, num_blocks, block_size, Hk]`` — the scale
 rides the same block layout as the data, so one block table addresses
 both.  Quantize/rescale reuses PR-10's machinery (``ops/quant.quant_cast``
 at write, broadcast rescale at read — in-VMEM inside the Pallas decode
@@ -414,13 +415,18 @@ def pool_bytes(pools: Dict[str, jnp.ndarray]) -> int:
 @dataclasses.dataclass
 class PagedKVView:
     """The paged cache as one model forward sees it — a pytree whose array
-    leaves are the pools and the per-step addressing arrays, with the
-    layout facts (block size, quantization) as static aux data.
+    leaves are the STACKED pools ``[L, NB, BS, Hk, D]``, the per-step
+    addressing arrays and the layer the view stands at, with the layout
+    facts (block size, quantization) as static aux data.
 
-    ``forward_embeds`` splits the view: the ``[L, ...]`` pools ride the
-    layer scan's ``xs`` while the addressing arrays are closed over (they
-    are shared by every layer); :meth:`layer_view` rewraps the per-layer
-    pool slice inside the scan body.
+    ``forward_embeds`` carries the pools through its layer scan (the
+    loop's CARRY, never its ``xs``/``ys``: a scan slices its ``xs`` and
+    stacks its ``ys`` into a new buffer, one layer of pool out and one in
+    per layer) and closes over the addressing arrays, which every layer
+    shares; inside the body :meth:`at_layer` rewraps the carried pools
+    with the layer's index.  :meth:`write` and :meth:`attend` then address
+    the stacked pools AT that layer, so the donated buffers are updated
+    and read in place.
     """
 
     pools: Dict[str, jnp.ndarray]
@@ -428,34 +434,41 @@ class PagedKVView:
     slot_mapping: jnp.ndarray     # [B, S] int32 flat slot per written token
     context_lens: jnp.ndarray     # [B] int32, INCLUDING this step's writes
     positions: jnp.ndarray        # [B, S] int32 absolute query positions
+    layer: Any = None             # int32 scalar (traced in the layer scan)
     block_size: int = 16
     quantized: bool = False
 
     def tree_flatten(self):
         children = (self.pools, self.block_tables, self.slot_mapping,
-                    self.context_lens, self.positions)
+                    self.context_lens, self.positions, self.layer)
         return children, (self.block_size, self.quantized)
 
     @classmethod
     def tree_unflatten(cls, aux, children):
         return cls(*children, block_size=aux[0], quantized=aux[1])
 
-    def layer_view(self, layer_pools: Dict[str, jnp.ndarray]) -> "PagedKVView":
-        return PagedKVView(
-            layer_pools, self.block_tables, self.slot_mapping,
-            self.context_lens, self.positions,
-            block_size=self.block_size, quantized=self.quantized)
+    def at_layer(self, pools: Dict[str, jnp.ndarray], layer) -> "PagedKVView":
+        """The view over ``pools`` (the stacked pools as the layer scan
+        carries them) standing at ``layer``."""
+        return dataclasses.replace(self, pools=pools, layer=layer)
 
     # -- the model-facing seam (llama._attention_core's paged branch) ------
     def write(self, k: jnp.ndarray, v: jnp.ndarray) -> Dict[str, jnp.ndarray]:
-        """Scatter this step's ``[B, S, Hk, D]`` k/v into the (per-layer)
-        pools at ``slot_mapping`` (pad tokens land in null page 0) and
-        return the updated pools dict.  int8 pools quantize per written
-        slot per kv head (PR-10's ``quant_cast``), storing the scale in
-        the matching scale plane."""
+        """Scatter this step's ``[B, S, Hk, D]`` k/v into the view's layer
+        of the stacked pools — flat slot ``layer * NB * BS + slot_mapping``
+        (pad tokens land in the layer's null page 0) — and return the
+        stacked pools dict.  int8 pools quantize per written slot per kv
+        head (PR-10's ``quant_cast``), storing the scale in the matching
+        scale plane — indexed ``[layer, block, slot]`` as it stands: a
+        plane's ``[.., BS, Hk]`` tiles are far under a lane tile, so the
+        TPU keeps it NB-minor, and a reshape to rows would have every
+        layer relay out the whole plane."""
         B, S, Hk, D = k.shape
-        slots = self.slot_mapping.reshape(-1)
         pools = dict(self.pools)
+        NB, BS = pools["k"].shape[1:3]
+        layer = jnp.asarray(self.layer, jnp.int32)
+        slot = self.slot_mapping.reshape(-1)
+        slots = layer * (NB * BS) + slot
         for name, x in (("k", k), ("v", v)):
             pool = pools[name]
             flat = x.reshape(B * S, Hk, D)
@@ -465,9 +478,8 @@ class PagedKVView:
                 amax = jnp.max(jnp.abs(flat.astype(jnp.float32)), axis=-1)
                 sc = jnp.maximum(amax, 1e-12) / INT8_MAX      # [B*S, Hk]
                 flat = quant_cast(flat, sc[..., None], jnp.int8)
-                spool = pools[name + "_scale"]
-                pools[name + "_scale"] = spool.reshape(-1, Hk).at[
-                    slots].set(sc).reshape(spool.shape)
+                pools[name + "_scale"] = pools[name + "_scale"].at[
+                    layer, slot // BS, slot % BS].set(sc)
             else:
                 flat = flat.astype(pool.dtype)
             pools[name] = pool.reshape(-1, Hk, D).at[slots].set(
@@ -477,13 +489,15 @@ class PagedKVView:
     def attend(self, q: jnp.ndarray, pools: Dict[str, jnp.ndarray], *,
                scale=None, logits_soft_cap=None, local_window_size=None
                ) -> jnp.ndarray:
-        """Paged attention of ``q [B, S, Hq, D]`` over the (freshly
-        written) pools, through the ``attention.paged_decode`` chain."""
+        """Paged attention of ``q [B, S, Hq, D]`` over the view's layer of
+        the (freshly written) stacked pools, through the
+        ``attention.paged_decode`` chain."""
         from automodel_tpu.ops.paged_attention import paged_attention
 
         return paged_attention(
             q, pools["k"], pools["v"],
             k_scale=pools.get("k_scale"), v_scale=pools.get("v_scale"),
+            layer=self.layer,
             block_tables=self.block_tables, context_lens=self.context_lens,
             positions=self.positions, scale=scale,
             logits_soft_cap=logits_soft_cap,

@@ -556,6 +556,55 @@ def test_paged_decode_kernel_parity(case):
     parity.run_paged_attention_parity("attention.paged_decode", case)
 
 
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+def test_paged_view_write_touches_its_layer_and_slots_only(quantized):
+    """A write at ``layer = 1`` of three lands in that layer's slots of
+    ``slot_mapping`` and nowhere else: the other layers, and every slot of
+    layer 1 that was not written, stay bit-identical — the scale planes of
+    an int8 pool included."""
+    from automodel_tpu.serving.kv_cache import PagedKVView
+
+    L, NB, BS, Hk, D, B, S = 3, 5, 4, 2, 8, 2, 3
+    rng = np.random.default_rng(11)
+    if quantized:
+        pools = {n: jnp.asarray(rng.integers(-127, 128, (L, NB, BS, Hk, D),
+                                             np.int8)) for n in "kv"}
+        pools.update({n + "_scale": jnp.asarray(
+            rng.uniform(0.005, 0.02, (L, NB, BS, Hk)), jnp.float32)
+            for n in "kv"})
+    else:
+        pools = {n: jnp.asarray(rng.standard_normal(
+            (L, NB, BS, Hk, D), np.float32), jnp.bfloat16) for n in "kv"}
+    # row 0 crosses a block boundary; row 1 ends in a pad slot (null page)
+    slots = np.asarray([[1 * BS + 3, 3 * BS + 0, 3 * BS + 1],
+                        [2 * BS + 2, 2 * BS + 3, 0]], np.int32)
+    k, v = (jnp.asarray(rng.standard_normal((B, S, Hk, D), np.float32),
+                        jnp.bfloat16) for _ in range(2))
+    z = jnp.zeros((B, S), jnp.int32)
+    view = PagedKVView(pools, jnp.zeros((B, 2), jnp.int32),
+                       jnp.asarray(slots), jnp.ones((B,), jnp.int32), z,
+                       block_size=BS, quantized=quantized)
+    out = jax.jit(lambda view, k, v, layer: view.at_layer(
+        view.pools, layer).write(k, v))(view, k, v, jnp.int32(1))
+
+    assert sorted(out) == sorted(pools)
+    written = np.zeros((L, NB * BS), bool)
+    written[1, slots.reshape(-1)] = True
+    for name, before in pools.items():
+        after = np.asarray(out[name])
+        before = np.asarray(before)
+        assert after.shape == before.shape and after.dtype == before.dtype
+        flat = lambda a: a.reshape(L, NB * BS, *a.shape[3:])
+        np.testing.assert_array_equal(flat(after)[~written],
+                                      flat(before)[~written], err_msg=name)
+    got_k = np.asarray(out["k"]).reshape(L, NB * BS, Hk, D)[1, slots]
+    if quantized:
+        got_k = got_k * np.asarray(out["k_scale"]).reshape(
+            L, NB * BS, Hk)[1, slots][..., None]
+    np.testing.assert_allclose(got_k, np.asarray(k, np.float32),
+                               atol=0.04 if quantized else 0)
+
+
 def test_paged_chain_and_cpu_fallback(model_and_params):
     """Chain shape + the CPU probe contract: off-TPU, the engine's traffic
     resolves to the gather anchor; in interpret mode the Pallas rung

@@ -5,6 +5,7 @@ vs an XLA reference) and sliding-window splash attention."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 
 def test_linear_ce_kernel_matches_xla_reference():
@@ -68,6 +69,52 @@ def test_sliding_window_splash_matches_sdpa():
                                atol=3e-2, rtol=3e-2)
 
 
+@pytest.mark.parametrize("width", [1, 32])
+def test_paged_decode_at_the_serving_cells_shapes(width, record_property):
+    """The Pallas rung over stacked pools as large as the benchmark's
+    serving cells hold (16 layers x 2,816 blocks x 16 x 16 heads x 128,
+    bf16: 2.95 GB each for K and V), 64 rows, at both step widths, against
+    ``paged_reference``.  Every layer holds other values, so a page read
+    from another layer shows."""
+    from automodel_tpu.ops.kernel_lib import parity
+    from automodel_tpu.ops.paged_attention import paged_reference
+    from automodel_tpu.ops.paged_attention_kernel import paged_decode_pallas
+
+    L, NB, BS, Hk, D, B, MB = 16, 2816, 16, 16, 128, 64, 128
+    layer = 11
+    rng = np.random.default_rng(27 + width)
+    ctx = rng.integers(width, 704, B)
+    ctx[:2] = MB * BS, MB * BS - 1            # two rows at the full length
+    need = -(-ctx // BS)
+    assert need.sum() < NB
+    free = rng.permutation(np.arange(1, NB))
+    tables = np.zeros((B, MB), np.int32)      # pad entries: the null page
+    for b in range(B):
+        tables[b, :need[b]], free = free[:need[b]], free[need[b]:]
+    positions = ctx[:, None] - width + np.arange(width)[None, :]
+
+    @jax.jit
+    def make(key):
+        kq, kk, kv = jax.random.split(key, 3)
+        per_layer = 1.0 + 0.25 * jnp.arange(L, dtype=jnp.float32)
+        pool = lambda k: (jax.random.normal(k, (1, NB, BS, Hk, D))
+                          * per_layer[:, None, None, None, None]
+                          ).astype(jnp.bfloat16)
+        return (jax.random.normal(kq, (B, width, Hk, D), jnp.bfloat16),
+                pool(kk), pool(kv))
+
+    q, k_pool, v_pool = make(jax.random.key(width))
+    args = (q, k_pool, v_pool, None, None, jnp.int32(layer),
+            jnp.asarray(tables), jnp.asarray(ctx, jnp.int32),
+            jnp.asarray(positions, jnp.int32))
+    out = jax.jit(paged_decode_pallas)(*args)
+    with jax.default_matmul_precision("highest"):
+        ref = jax.jit(lambda *a: paged_reference({}, *a))(*args)
+    record_property("max_err", parity._compare(
+        out, ref, parity.NATIVE_TOL["bfloat16"], True,
+        f"paged_decode at the cell's shapes, width {width}"))
+
+
 def test_kernel_scopes_and_instruction_names():
     """The scopes only a Pallas rung reaches, as the chip's compiler sees
     them: ``linear_ce`` inside the custom-vjp's forward and backward,
@@ -103,12 +150,12 @@ def test_kernel_scopes_and_instruction_names():
                for scope in ce.values()), ce
 
     B, Hq, D, BS, MB, NB = 8, 4, 128, 16, 8, 64
-    pool = jnp.zeros((NB, BS, Hq, D), jnp.bfloat16)
+    pool = jnp.zeros((2, NB, BS, Hq, D), jnp.bfloat16)
     paged = kernels(jax.jit(
-        lambda q, kp, vp, tables, lens:
+        lambda q, kp, vp, layer, tables, lens:
         paged_attention_kernel._paged_decode_impl(
-            {}, q, kp, vp, None, None, tables, lens, None)).lower(
-        jnp.zeros((B, 1, Hq, D), jnp.bfloat16), pool, pool,
+            {}, q, kp, vp, None, None, layer, tables, lens, None)).lower(
+        jnp.zeros((B, 1, Hq, D), jnp.bfloat16), pool, pool, jnp.int32(1),
         jnp.zeros((B, MB), jnp.int32),
         jnp.ones((B,), jnp.int32)).compile().as_text())
     (name, scope), = paged.items()
