@@ -40,10 +40,34 @@ leaf name so standard AdamW configs cannot silently decay it.
 Scope notes: rope is yarn (``ops/rotary.rope_parameters``) with the
 DeepSeek interleaved channel layout (``rope_interleave: true`` —
 de-interleaved before the standard half-split rotation, which preserves
-q.k inner products exactly).  Decode uses a full expanded-kv cache
-(v padded to ``qk_head_dim``); the latent-kv cache — MLA's inference
-memory trick — is a known optimization, not wired.  Rank-r LoRA bypass
-is not wired for the MLA projections and fails loudly.
+q.k inner products exactly).  Rank-r LoRA bypass is not wired for the MLA
+projections and fails loudly.
+
+Serving (``serving/engine.py``) runs the LATENT cache: the model says
+through :meth:`DeepseekV3ForCausalLM.paged_cache_planes` that it caches one
+plane of ``kv_lora_rank + qk_rope_head_dim`` values per token and layer
+(the normalised ``c_kv`` beside the rotated rope key: 1,152 bytes in
+bfloat16 at DeepSeek-V3's and Kimi-K2's widths, against 49,152 expanded),
+writes it through ``PagedKVView.write_latent`` and attends in the ABSORBED
+form — ``q_nope W_uk^T`` against the latent, the context through ``W_uv``
+afterwards — through the ``attention.mla_paged_decode`` chain
+(``ops/mla_paged_attention.py``), decode steps and prefill chunks alike:
+one kernel, and no re-expansion of the history per chunk.  The plane rides
+BOTH layer scans as their carry under one layer index that runs across
+the two stacks.  ``generate()``'s dense dict cache still holds expanded
+per-head keys (v padded to ``qk_head_dim``): it is the parity oracle and
+goes when ROADMAP Design 2 removes the dict caches.
+
+``held_experts: [first, count]`` makes this model one expert-parallel
+share: the router stays ``n_routed_experts`` wide and picks
+``num_experts_per_tok``, the combine weights are normalised over ALL the
+chosen, the parameter tree holds ``[count, H, Im]`` expert stacks and a
+layer computes ``sum over chosen & held`` plus the shared expert.  What
+the absent experts would add is left out (on one chip the layer runs
+without its exchange).  In the serving step the experts run through
+``ops/moe.decode_expert_ffn`` (dropless, work in proportion to the
+assignments, experts nobody chose never read), and the step returns
+``expert_tokens [n_moe_layers, held]``.
 """
 
 from __future__ import annotations
@@ -60,13 +84,16 @@ from automodel_tpu.distributed.shardings import constrain
 from automodel_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 from automodel_tpu.ops.attention import attention
 from automodel_tpu.ops.moe import (
+    decode_expert_ffn,
     expert_ffn,
     group_and_capacity,
     group_tokens,
+    held_experts_local,
     mask_padded_tokens,
     noaux_topk_routing,
 )
 from automodel_tpu.ops.norms import rms_norm
+from automodel_tpu.ops.quant import maybe_qdot
 from automodel_tpu.ops.remat import resolve_remat_policy
 from automodel_tpu.ops.rotary import apply_rope
 
@@ -96,6 +123,9 @@ class DeepseekV3Config(LlamaConfig):
     moe_group_size: int = 512
     # Expert dispatch path ("sorted" | "onehot"; None = the sorted default).
     moe_dispatch: Optional[str] = None
+    # [first, count]: the routed experts THIS model holds, as one share of
+    # an expert-parallel layer; None = all of n_routed_experts.
+    held_experts: Optional[Tuple[int, int]] = None
 
     def __post_init__(self):
         # HF DeepseekV3Config defines head_dim = qk_rope_head_dim (the rope
@@ -104,7 +134,9 @@ class DeepseekV3Config(LlamaConfig):
         if self.head_dim is None:
             self.head_dim = self.qk_rope_head_dim
         super().__post_init__()
-        self.model_type = "deepseek_v3"
+        # ``kimi_k2`` (Kimi-K2's published model_type) is this family
+        if self.model_type != "kimi_k2":
+            self.model_type = "deepseek_v3"
         from automodel_tpu.ops.moe import (
             normalize_moe_dispatch,
             validate_moe_dispatch,
@@ -118,10 +150,24 @@ class DeepseekV3Config(LlamaConfig):
                 f"range for {self.num_hidden_layers} layers")
         if self.n_routed_experts % self.n_group:
             raise ValueError("n_routed_experts must divide into n_group")
+        if self.held_experts is not None:
+            held = tuple(int(v) for v in self.held_experts)
+            if len(held) != 2 or held[0] < 0 or held[1] < 1 \
+                    or held[0] + held[1] > self.n_routed_experts:
+                raise ValueError(
+                    f"held_experts={self.held_experts!r} must be [first, "
+                    f"count] within n_routed_experts="
+                    f"{self.n_routed_experts}")
+            self.held_experts = held
 
     @property
     def qk_head_dim(self) -> int:
         return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def n_held_experts(self) -> int:
+        return (self.n_routed_experts if self.held_experts is None
+                else self.held_experts[1])
 
 class DeepseekV3ForCausalLM(LlamaForCausalLM):
     """``model_type: deepseek_v3`` — MLA attention x no-aux MoE."""
@@ -219,6 +265,7 @@ class DeepseekV3ForCausalLM(LlamaForCausalLM):
             }
         if n_moe:
             E, Im = cfg.n_routed_experts, cfg.moe_intermediate_size
+            Eh = cfg.n_held_experts
             Is = Im * cfg.n_shared_experts
             params["layers"] = {
                 **layer_norms(n_moe),
@@ -230,11 +277,11 @@ class DeepseekV3ForCausalLM(LlamaForCausalLM):
                             (n_moe, E), jnp.float32),
                     },
                     "experts": {
-                        "gate_proj": {"kernel": dense(next(keys), (E, H, Im),
+                        "gate_proj": {"kernel": dense(next(keys), (Eh, H, Im),
                                                       n_moe)},
-                        "up_proj": {"kernel": dense(next(keys), (E, H, Im),
+                        "up_proj": {"kernel": dense(next(keys), (Eh, H, Im),
                                                     n_moe)},
-                        "down_proj": {"kernel": dense(next(keys), (E, Im, H),
+                        "down_proj": {"kernel": dense(next(keys), (Eh, Im, H),
                                                       n_moe)},
                     },
                     "shared_experts": {
@@ -323,39 +370,72 @@ class DeepseekV3ForCausalLM(LlamaForCausalLM):
         return jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1) \
             if self.config.rope_interleave else x
 
+    def paged_cache_planes(self) -> Dict[str, Tuple[int, ...]]:
+        """What the serving engine's paged pools hold per token and layer:
+        ONE latent plane (``serving/kv_cache.init_paged_pools``)."""
+        cfg = self.config
+        return {"kv": (cfg.kv_lora_rank + cfg.qk_rope_head_dim,)}
+
     def _mla_attention(self, x, p, position_ids, segment_ids, attention_mask,
                       inv_freq, rope_scale, kv_cache=None, cache_index=None):
         cfg = self.config
         B, S, H = x.shape
         Hq = cfg.num_attention_heads
         dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+        R = cfg.kv_lora_rank
         cd = self.compute_dtype
 
-        def proj(h, w):
-            return h @ w["kernel"].astype(cd)
+        def proj(h, name):
+            # quantized compute (``fp8.enabled``) reaches the projections
+            # through the one rule the dense families use
+            return maybe_qdot(h, p[name]["kernel"].astype(cd), self.quant,
+                              "self_attn." + name)
 
         if cfg.q_lora_rank is None:
-            q = proj(x, p["q_proj"])
+            q = proj(x, "q_proj")
         else:
-            q_lat = rms_norm(proj(x, p["q_a_proj"]),
+            q_lat = rms_norm(proj(x, "q_a_proj"),
                              p["q_a_layernorm"]["weight"], cfg.rms_norm_eps)
-            q = proj(q_lat, p["q_b_proj"])
+            q = proj(q_lat, "q_b_proj")
         q = q.reshape(B, S, Hq, dn + dr)
         q_nope, q_rope = q[..., :dn], q[..., dn:]
 
-        ckv = proj(x, p["kv_a_proj_with_mqa"])
-        k_lat, k_rope = ckv[..., :cfg.kv_lora_rank], ckv[..., cfg.kv_lora_rank:]
+        ckv = proj(x, "kv_a_proj_with_mqa")
+        k_lat, k_rope = ckv[..., :R], ckv[..., R:]
         k_lat = rms_norm(k_lat, p["kv_a_layernorm"]["weight"],
                          cfg.rms_norm_eps)
-        kv = proj(k_lat, p["kv_b_proj"]).reshape(B, S, Hq, dn + dv)
-        k_nope, v = kv[..., :dn], kv[..., dn:]
-
         q_rope = self._deinterleave(q_rope)
         k_rope = self._deinterleave(k_rope)[:, :, None, :]     # single head
         q_rope, k_rope = apply_rope(q_rope, k_rope, position_ids, inv_freq,
                                     attention_scaling=rope_scale)
-        k_rope = jnp.broadcast_to(k_rope, (B, S, Hq, dr))
 
+        if kv_cache is not None and hasattr(kv_cache, "at_layer"):
+            # Serving: the latent paged cache, attended in the absorbed
+            # form.  With W_kvb = [W_uk_i | W_uv_i] per head, the score
+            # q_nope_i . (c_kv W_uk_i) is (q_nope_i W_uk_i^T) . c_kv, and
+            # sum_j p_ij (c_kv_j W_uv_i) is (sum_j p_ij c_kv_j) W_uv_i: the
+            # cache holds c_kv and the rotated rope key and nothing per
+            # head.  ``mla_decode`` is innermost round the kernel (the
+            # rung's own scope), the rest names what surrounds it.
+            w_kvb = p["kv_b_proj"]["kernel"].astype(cd).reshape(
+                R, Hq, dn + dv)
+            with jax.named_scope("mla_latent_write"):
+                pools = kv_cache.write_latent(
+                    jnp.concatenate([k_lat, k_rope[:, :, 0, :]], axis=-1))
+            with jax.named_scope("mla_absorb_q"):
+                q_abs = jnp.einsum("bshd,rhd->bshr", q_nope,
+                                   w_kvb[..., :dn])
+                q_cat = jnp.concatenate([q_abs, q_rope], axis=-1)
+            with jax.named_scope("attn_core"):
+                ctx = kv_cache.attend_latent(
+                    q_cat, pools, value_dim=R, scale=self._attn_scale)
+            with jax.named_scope("mla_out"):
+                out = jnp.einsum("bshr,rhd->bshd", ctx, w_kvb[..., dn:])
+                return proj(out.reshape(B, S, Hq * dv), "o_proj"), pools
+
+        kv = proj(k_lat, "kv_b_proj").reshape(B, S, Hq, dn + dv)
+        k_nope, v = kv[..., :dn], kv[..., dn:]
+        k_rope = jnp.broadcast_to(k_rope, (B, S, Hq, dr))
         qh = jnp.concatenate([q_nope, q_rope], axis=-1)        # [B,S,Hq,dn+dr]
         kh = jnp.concatenate([k_nope, k_rope], axis=-1)
         # one head dim for the kernels: pad v to qk_head_dim (HF FA2 does
@@ -364,9 +444,8 @@ class DeepseekV3ForCausalLM(LlamaForCausalLM):
             if dv != dn + dr else v
         new_cache = None
         if kv_cache is not None:
-            # decode v1: cache the EXPANDED per-head k / padded v (the
-            # latent-cache decode — storing only [kv_lora + rope] per token
-            # — is the known MLA inference optimization, not wired yet).
+            # generate()'s dense dict cache: the EXPANDED per-head k /
+            # padded v (the parity oracle of the latent serving path above).
             from automodel_tpu.ops.attention import cached_attention
 
             k_cache = lax.dynamic_update_slice(
@@ -391,26 +470,40 @@ class DeepseekV3ForCausalLM(LlamaForCausalLM):
                             attention_mask=attention_mask,
                             scale=self._attn_scale)
         out = out[..., :dv]
-        return proj(out.reshape(B, S, Hq * dv), p["o_proj"]), new_cache
+        return proj(out.reshape(B, S, Hq * dv), "o_proj"), new_cache
 
-    def _dense_mlp(self, x, p):
+    def _dense_mlp(self, x, p, name="mlp"):
         cd = self.compute_dtype
-        gate = x @ p["gate_proj"]["kernel"].astype(cd)
-        up = x @ p["up_proj"]["kernel"].astype(cd)
-        return (jax.nn.silu(gate) * up) @ p["down_proj"]["kernel"].astype(cd)
+
+        def mm(h, leaf):
+            return maybe_qdot(h, p[leaf]["kernel"].astype(cd), self.quant,
+                              f"{name}.{leaf}")
+
+        return mm(jax.nn.silu(mm(x, "gate_proj")) * mm(x, "up_proj"),
+                  "down_proj")
 
     def _route(self, xg, gate_p, k):
         """Router hook: V3 sigmoid + aux-free bias correction; the V2
         family overrides with softmax gating."""
         cfg = self.config
-        scores = jax.nn.sigmoid(
-            xg.astype(jnp.float32)
-            @ gate_p["kernel"].astype(jnp.float32))
+        # float32 in earnest: on a TPU a default-precision float32 product
+        # is one bfloat16 pass, and a selection among near-tied scores
+        # should not turn on that
+        scores = jax.nn.sigmoid(jnp.matmul(
+            xg.astype(jnp.float32), gate_p["kernel"].astype(jnp.float32),
+            precision=lax.Precision.HIGHEST))
         return noaux_topk_routing(
             scores, gate_p["e_score_correction_bias"], k,
             n_group=cfg.n_group, topk_group=cfg.topk_group,
             norm_topk=bool(cfg.norm_topk_prob),
             routed_scaling_factor=float(cfg.routed_scaling_factor))
+
+    def _held(self, weights, idx):
+        """Routing over all experts -> over the held share's local ids."""
+        cfg = self.config
+        if cfg.held_experts is None:
+            return weights, idx
+        return held_experts_local(weights, idx, *cfg.held_experts)
 
     def _moe_mlp(self, x, p):
         cfg = self.config
@@ -422,8 +515,9 @@ class DeepseekV3ForCausalLM(LlamaForCausalLM):
                                   cfg.moe_capacity_factor)
         xg, pad = group_tokens(x.reshape(T, H), M)
         xg = constrain(xg, ("act_tokens", None, None))
-        weights, idx = self._route(xg, p["gate"], k)
-        weights, idx, _ = mask_padded_tokens(weights, idx, pad, E)
+        weights, idx = self._held(*self._route(xg, p["gate"], k))
+        weights, idx, _ = mask_padded_tokens(weights, idx, pad,
+                                             cfg.n_held_experts)
         from automodel_tpu.ops.quant import quant_for
 
         routed = expert_ffn(
@@ -437,7 +531,33 @@ class DeepseekV3ForCausalLM(LlamaForCausalLM):
         routed = routed.reshape(-1, H)
         if pad:
             routed = routed[:T]
-        return routed.reshape(B, S, H) + self._dense_mlp(x, p["shared_experts"])
+        return routed.reshape(B, S, H) + self._dense_mlp(
+            x, p["shared_experts"], "mlp.shared_experts")
+
+    def _moe_mlp_serving(self, x, p, valid, experts, at):
+        """The serving step's expert layer: dropless and decode-shaped
+        (``ops/moe.decode_expert_ffn``); ``valid [B, S]`` keeps the step
+        buffer's pad columns out of the routing; ``experts`` are the expert
+        stacks of ALL expert layers and ``at`` this layer's place in them.
+        Returns the layer's output and the tokens each held expert got."""
+        cfg = self.config
+        B, S, H = x.shape
+        k = cfg.num_experts_per_tok
+        x2 = x.reshape(B * S, H)
+        with jax.named_scope("moe_router"):
+            weights, idx = self._held(*self._route(x2, p["gate"], k))
+            idx = jnp.where(valid.reshape(-1, 1), idx, cfg.n_held_experts)
+        with jax.named_scope("moe_experts"):
+            routed, counts = decode_expert_ffn(
+                x2, weights, idx,
+                experts["gate_proj"]["kernel"],
+                experts["up_proj"]["kernel"],
+                experts["down_proj"]["kernel"],
+                layer=at, compute_dtype=self.compute_dtype)
+        with jax.named_scope("moe_shared"):
+            shared = self._dense_mlp(x, p["shared_experts"],
+                                     "mlp.shared_experts")
+        return routed.reshape(B, S, H) + shared, counts
 
     def forward_embeds(
         self,
@@ -462,6 +582,11 @@ class DeepseekV3ForCausalLM(LlamaForCausalLM):
                 "use peft merge mode")
         B, S = hidden.shape[:2]
         decoding = kv_cache is not None
+        # The serving engine's paged view: its pools ride BOTH layer scans
+        # as carry (never xs/ys: see ``llama.forward_embeds``) and a layer
+        # stands at ONE index that runs across the two stacks.
+        paged = kv_cache if decoding and hasattr(kv_cache, "at_layer") \
+            else None
         if position_ids is None:
             start = 0 if cache_index is None else cache_index
             position_ids = start + jnp.broadcast_to(
@@ -469,54 +594,103 @@ class DeepseekV3ForCausalLM(LlamaForCausalLM):
         hidden = constrain(hidden.astype(self.compute_dtype),
                            ("act_batch", "act_seq", "act_embed"))
         inv_freq, rope_scale = self._rope_tables(position_ids)
+        valid = paged.valid_tokens() if paged is not None else None
 
-        def layer(h, p, moe: bool, cache):
-            resid = h
-            x = rms_norm(h, p["input_layernorm"]["weight"], cfg.rms_norm_eps)
-            attn, new_cache = self._mla_attention(
-                x, p["self_attn"], position_ids, segment_ids, attention_mask,
-                inv_freq, rope_scale, kv_cache=cache, cache_index=cache_index)
-            h = resid + attn
-            resid = h
-            x = rms_norm(h, p["post_attention_layernorm"]["weight"],
-                         cfg.rms_norm_eps)
-            out = self._moe_mlp(x, p["mlp"]) if moe \
-                else self._dense_mlp(x, p["mlp"])
-            return constrain(resid + out, ("act_batch", "act_seq",
-                                           "act_embed")), new_cache
+        def layer(h, p, moe: bool, cache, experts=None, at=None):
+            with jax.named_scope("attn"):
+                resid = h
+                x = rms_norm(h, p["input_layernorm"]["weight"],
+                             cfg.rms_norm_eps)
+                attn, new_cache = self._mla_attention(
+                    x, p["self_attn"], position_ids, segment_ids,
+                    attention_mask, inv_freq, rope_scale, kv_cache=cache,
+                    cache_index=cache_index)
+                h = resid + attn
+            with jax.named_scope("mlp"):
+                resid = h
+                x = rms_norm(h, p["post_attention_layernorm"]["weight"],
+                             cfg.rms_norm_eps)
+                counts = None
+                if not moe:
+                    with jax.named_scope("dense_mlp"):
+                        out = self._dense_mlp(x, p["mlp"])
+                elif paged is not None:
+                    out, counts = self._moe_mlp_serving(x, p["mlp"], valid,
+                                                        experts, at)
+                else:
+                    out = self._moe_mlp(x, p["mlp"])
+                out = constrain(resid + out, ("act_batch", "act_seq",
+                                              "act_embed"))
+            return out, new_cache, counts
 
         policy = resolve_remat_policy(self.remat_policy)
         new_kv = {} if decoding else None
+        pools = paged.pools if paged is not None else None
+        expert_tokens = None
+        first = 0
         for name, moe in (("dense_layers", False), ("layers", True)):
             if name not in params:
                 continue
+            stack = params[name]
+            n = jax.tree.leaves(stack)[0].shape[0]
+            experts = None
+            if paged is not None and moe:
+                # the expert stacks stay OUT of the scan's xs: the step
+                # slices one expert's matrices at (layer, expert) where it
+                # multiplies them (``decode_expert_ffn``)
+                experts = stack["mlp"]["experts"]
+                stack = dict(stack, mlp={k: v for k, v in stack["mlp"].items()
+                                         if k != "experts"})
 
-            def body(h, xs, moe=moe):
-                p, cache = xs
-                h, new_cache = layer(h, p, moe, cache)
+            def body(carry, xs, moe=moe, first=first, experts=experts):
+                p, idx, cache = xs
+                if paged is not None:
+                    h, pl_ = carry
+                    h, pl_, counts = layer(h, p, moe,
+                                           paged.at_layer(pl_, idx),
+                                           experts, idx - first)
+                    return (h, pl_), counts
+                h, new_cache, _ = layer(carry, p, moe, cache)
                 return h, new_cache
 
             if self.remat and not decoding:
                 body = jax.checkpoint(body, policy=policy, prevent_cse=False)
-            stack_cache = kv_cache.get(name) if decoding else None
-            hidden, stack_new = lax.scan(body, hidden,
-                                         (params[name], stack_cache))
-            if decoding:
-                new_kv[name] = stack_new
+            stack_cache = (kv_cache.get(name)
+                           if decoding and paged is None else None)
+            idx = first + jnp.arange(n, dtype=jnp.int32)
+            init = hidden if paged is None else (hidden, pools)
+            with jax.named_scope("layers"):
+                carry, ys = lax.scan(body, init, (stack, idx, stack_cache))
+            if paged is not None:
+                hidden, pools = carry
+                if moe:
+                    expert_tokens = ys
+            else:
+                hidden = carry
+                if decoding:
+                    new_kv[name] = ys
+            first += n
 
-        hidden = rms_norm(hidden, params["norm"]["weight"], cfg.rms_norm_eps)
-        lm_kernel = (params["embed_tokens"]["embedding"].T
-                     if cfg.tie_word_embeddings
-                     else params.get("lm_head", {}).get("kernel"))
-        if return_hidden:
-            out = {"hidden_states": hidden}
-            if lm_kernel is not None:
-                out["lm_head_kernel"] = lm_kernel
-        else:
-            logits = hidden @ lm_kernel.astype(self.compute_dtype)
-            out = {"logits": constrain(
-                logits, ("act_batch", "act_seq_nosp", "act_vocab"))}
-        if decoding:
+        with jax.named_scope("final_norm"):
+            hidden = rms_norm(hidden, params["norm"]["weight"],
+                              cfg.rms_norm_eps)
+        with jax.named_scope("lm_head"):
+            lm_kernel = (params["embed_tokens"]["embedding"].T
+                         if cfg.tie_word_embeddings
+                         else params.get("lm_head", {}).get("kernel"))
+            if return_hidden:
+                out = {"hidden_states": hidden}
+                if lm_kernel is not None:
+                    out["lm_head_kernel"] = lm_kernel
+            else:
+                logits = hidden @ lm_kernel.astype(self.compute_dtype)
+                out = {"logits": constrain(
+                    logits, ("act_batch", "act_seq_nosp", "act_vocab"))}
+        if paged is not None:
+            out["kv_cache"] = pools
+            if expert_tokens is not None:
+                out["expert_tokens"] = expert_tokens
+        elif decoding:
             out["kv_cache"] = new_kv
         return out
 

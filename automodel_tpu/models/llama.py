@@ -603,6 +603,14 @@ class LlamaForCausalLM:
             dropout_rng=dropout_rng, kv_cache=kv_cache,
             cache_index=cache_index, **extra)
 
+    def paged_cache_planes(self) -> Dict[str, Any]:
+        """What the serving engine's paged pools hold per token and layer,
+        plane by plane (``serving/kv_cache.init_paged_pools``): per-head
+        keys and values here; a latent-attention family says otherwise."""
+        cfg = self.config
+        per_head = (cfg.num_key_value_heads, cfg.head_dim)
+        return {"k": per_head, "v": per_head}
+
     def init_kv_cache(self, batch: int, max_len: int,
                       dtype: Optional[Any] = None) -> Dict[str, jnp.ndarray]:
         """Static-shape decode cache: ``{"k"|"v": [L, B, max_len, Hk, D]}``."""

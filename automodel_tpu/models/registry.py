@@ -154,6 +154,13 @@ def _ensure_builtin() -> None:
                                DeepseekV3ForCausalLM,
                                hf_io.deepseek_v3_key_map,
                                ["DeepseekV3ForCausalLM"]))
+    # Kimi-K2's published config.json says ``model_type: kimi_k2``; its
+    # modeling code is DeepSeek-V3's (MLA, sigmoid noaux_tc router, one
+    # shared expert) at other sizes.
+    register_model(ModelFamily("kimi_k2", DeepseekV3Config,
+                               DeepseekV3ForCausalLM,
+                               hf_io.deepseek_v3_key_map,
+                               ["DeepseekV3ForCausalLM"]))
     from automodel_tpu.models.deepseek_v2 import (
         DeepseekV2Config,
         DeepseekV2ForCausalLM,

@@ -39,6 +39,7 @@ from automodel_tpu.ops.kernel_lib import registry
 CPU_EXECUTABLE = {
     "attention.splash", "attention.ring", "attention.sdpa",
     "attention.paged_decode", "attention.paged_gather",
+    "attention.mla_paged_decode", "attention.mla_paged_gather",
     "linear_ce.pallas", "linear_ce.chunked",
     "gmm.pallas", "gmm.xla_blocked", "gmm.ragged",
     "qdot.pallas", "qdot.xla",
@@ -51,6 +52,7 @@ _INTERPRET_MODULES = (
     "automodel_tpu.ops.gmm_kernel",
     "automodel_tpu.ops.qdot_kernel",
     "automodel_tpu.ops.paged_attention_kernel",
+    "automodel_tpu.ops.mla_paged_attention_kernel",
 )
 
 
@@ -337,6 +339,103 @@ def run_paged_attention_parity(spec_name: str, case: Dict,
 
 
 # ---------------------------------------------------------------------------
+# MLA paged attention family (the latent serving cache)
+# ---------------------------------------------------------------------------
+def mla_paged_attention_cases() -> List[Dict]:
+    """Decode (q=1) and chunked prefill (q>1) over ONE stacked latent plane
+    ``[L, NB, BS, R]`` with scrambled block tables and ragged contexts;
+    ``valid`` < ``q_seq`` makes the trailing columns padding (repeating the
+    last valid position, as the engine assembles a decode row of a mixed
+    step); ``q_rows`` shrinks the kernel's query tile so that a small case
+    has several (one of them all padding).  Shape keys: ``B Hq R V BS MB L
+    layer``; ``ctx``: explicit context lengths."""
+    return [
+        dict(name="decode", q_seq=1, dtype="float32"),
+        dict(name="decode_bf16", q_seq=1, dtype="bfloat16"),
+        dict(name="decode_first_layer", q_seq=1, dtype="float32", layer=0),
+        dict(name="chunked_prefill", q_seq=8, dtype="float32"),
+        dict(name="chunked_prefill_tiles", q_seq=8, dtype="float32",
+             q_rows=16, layer=2),
+        dict(name="mixed_step_padding", q_seq=8, dtype="float32",
+             q_rows=16, valid=(1, 5)),
+        dict(name="decode_two_chunks", q_seq=1, dtype="float32", MB=12,
+             chunk=64),
+        dict(name="chunked_prefill_bf16_two_chunks", q_seq=8,
+             dtype="bfloat16", MB=12, chunk=64, q_rows=16),
+    ]
+
+
+def build_mla_paged_attention_case(case: Dict, *, B=2, Hq=4, R=256, V=128,
+                                   BS=16, MB=4, L=3, layer=1):
+    B, Hq, R, V = (case.get(k, d) for k, d in
+                   (("B", B), ("Hq", Hq), ("R", R), ("V", V)))
+    BS, MB = case.get("BS", BS), case.get("MB", MB)
+    L, layer = case.get("L", L), case.get("layer", layer)
+    rng = np.random.default_rng(11)
+    dtype = jnp.dtype(case.get("dtype", "float32"))
+    S = case["q_seq"]
+    NB = B * MB + 1
+    valid = np.asarray(case.get("valid") or [S] * B, np.int32)
+    if "ctx_range" in case:
+        # the chip cases: contexts spread geometrically over the range, each
+        # row owning only the blocks its context needs (a pool that held
+        # ``B * MB`` blocks of 16k-token rows would not fit the chip)
+        ctx = np.maximum(np.geomspace(*case["ctx_range"], B).astype(np.int32),
+                         valid)
+        need = -(-ctx // BS)
+        NB = int(need.sum()) + 1
+        ids = rng.permutation(np.arange(1, NB))
+        perm = np.zeros((B, MB), np.int64)
+        for b, (n, at) in enumerate(zip(need, np.cumsum(need) - need)):
+            perm[b, :n] = ids[at:at + n]
+    else:       # ragged: even rows nearly full, odd rows short
+        ctx = np.asarray([MB * BS - 7 - 3 * (b // 2) if b % 2 == 0
+                          else 2 * BS + 3 + b // 2 for b in range(B)],
+                         np.int32)
+        ctx = np.maximum(ctx, valid)
+        perm = rng.permutation(np.arange(1, NB)).reshape(B, MB)
+    positions = (ctx - valid)[:, None] + np.minimum(
+        np.arange(S)[None, :], valid[:, None] - 1)
+    key = jax.random.key(11)
+    q = jax.random.normal(key, (B, S, Hq, R), jnp.float32).astype(dtype)
+    pool = jax.random.normal(jax.random.fold_in(key, 1), (L, NB, BS, R),
+                             dtype)
+    from automodel_tpu.ops.mla_paged_attention import build_mla_request
+
+    kwargs = {"value_dim": V, "scale": float(R) ** -0.5}
+    return ((q, pool, jnp.int32(layer), jnp.asarray(perm, jnp.int32),
+             jnp.asarray(ctx), jnp.asarray(positions, jnp.int32)), kwargs,
+            build_mla_request(q, pool, V), valid)
+
+
+def run_mla_paged_attention_parity(spec_name: str, case: Dict,
+                                   native: bool = False) -> float:
+    """The rung over the stacked plane at the case's layer against the
+    reference over THAT layer alone; only the valid columns are compared
+    (a pad column's output is the caller's to discard)."""
+    from automodel_tpu.ops import mla_paged_attention_kernel as mk
+
+    spec = registry.get_kernel(spec_name)
+    args, kwargs, request, valid = build_mla_paged_attention_case(case)
+    q, pool, layer, tables, ctx, positions = args
+    ref_args = (q, pool[int(layer):int(layer) + 1], jnp.int32(0), tables,
+                ctx, positions)
+    saved = mk._Q_ROWS, mk._CHUNK
+    mk._Q_ROWS = case.get("q_rows", saved[0])
+    mk._CHUNK = case.get("chunk", saved[1])
+    try:
+        out, ref = _execute(spec, request, args, kwargs, native, ref_args)
+    finally:
+        mk._Q_ROWS, mk._CHUNK = saved
+    keep = (np.arange(q.shape[1])[None, :] < valid[:, None])[..., None, None]
+    tol = _tol(str(q.dtype), native,
+               2e-2 if q.dtype == jnp.bfloat16 else 2e-3)
+    return _compare(np.where(keep, np.asarray(out, np.float32), 0.0),
+                    np.where(keep, np.asarray(ref, np.float32), 0.0),
+                    tol, native, f"{spec_name} on {case['name']}")
+
+
+# ---------------------------------------------------------------------------
 # linear_ce family
 # ---------------------------------------------------------------------------
 def linear_ce_cases() -> List[Dict]:
@@ -538,6 +637,7 @@ def chip_cases() -> Dict[str, List[Dict]]:
     and Moonlight-16B-A3B experts for the grouped matmuls — whole-K tiles
     at K=14336 are the VMEM-heaviest shape any of them sees."""
     l3b = dict(B=8, Hq=24, Hk=8, D=128, BS=16, MB=64)
+    kimi = dict(Hq=64, R=640, V=512, BS=16, MB=1056, L=7, layer=5)
     mixtral_up = dict(m=4096, k=4096, n=14336, sizes=_ragged_sizes(4096, 8))
     mixtral_down = dict(m=4096, k=14336, n=4096, sizes=_ragged_sizes(4096, 8))
     moonlight_up = dict(m=4096, k=2048, n=1408, sizes=_ragged_sizes(4096, 64))
@@ -563,6 +663,18 @@ def chip_cases() -> Dict[str, List[Dict]]:
                  dtype="bfloat16", quantized=True, **l3b),
             dict(name="llama3_2_3b_prefill_chunk32", q_seq=32,
                  dtype="bfloat16", **l3b),
+        ],
+        # Kimi-K2's latent plane as the serving cell holds it: 7 layers,
+        # 576 values a token stored 640 wide, 64 heads, value 512, tables
+        # of 1,056 blocks (16,896 positions), contexts 200 to 16k
+        "attention.mla_paged_decode": [
+            dict(name="kimi_k2_decode_64rows", q_seq=1, dtype="bfloat16",
+                 ctx_range=(200, 16384), B=64, **kimi),
+            dict(name="kimi_k2_prefill_chunk64", q_seq=64, dtype="bfloat16",
+                 ctx_range=(300, 16384), B=8, **kimi),
+            dict(name="kimi_k2_mixed_step_w64", q_seq=64, dtype="bfloat16",
+                 ctx_range=(300, 16384), B=8,
+                 valid=(1, 64, 1, 1, 37, 1, 64, 1), **kimi),
         ],
         "linear_ce.pallas": [
             dict(name="llama3_2_1b_vocab128256", t=4096, h=2048, v=128256,

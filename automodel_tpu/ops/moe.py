@@ -437,6 +437,90 @@ def sorted_expert_ffn(
     return constrain(out.reshape(G, M, H), ("act_tokens", None, None))
 
 
+def held_experts_local(weights: jnp.ndarray, idx: jnp.ndarray, first: int,
+                       count: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Routing over ALL experts -> routing over the ``count`` experts held
+    from index ``first``: an assignment to an expert held elsewhere goes to
+    the sentinel id ``count`` with weight zero (it joins no dispatch, like a
+    padded token's), a held one keeps its weight — already normalised over
+    everything the token chose — under its local index."""
+    local = idx - first
+    held = (local >= 0) & (local < count)
+    return (jnp.where(held, weights, jnp.zeros((), weights.dtype)),
+            jnp.where(held, local, count))
+
+
+DECODE_CHUNK = 256      # rows of one expert's segment multiplied at a time
+
+
+def decode_expert_ffn(
+    x: jnp.ndarray,           # [T, H] tokens
+    weights: jnp.ndarray,     # [T, k] combine weights
+    idx: jnp.ndarray,         # [T, k] expert assignment (E = sentinel: none)
+    w_gate: jnp.ndarray,      # [L, E, H, I]: the stacks of ALL layers
+    w_up: jnp.ndarray,        # [L, E, H, I]
+    w_down: jnp.ndarray,      # [L, E, I, H]
+    *,
+    layer,                    # int32 scalar: which layer's experts
+    compute_dtype: jnp.dtype = jnp.bfloat16,
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The serving step's expert FFN: dropless, no capacity tiles, work in
+    proportion to the assignments there ARE.  A decode step routes a few
+    dozen tokens, so most experts see one or two and some none; what an
+    expert costs is the read of its weights.  The assignments are sorted by
+    expert; each expert then takes its own segment in ``DECODE_CHUNK``-row pieces
+    (one piece under a ``cond`` when the step's tokens fit one, else a loop
+    whose trip count is the segment's: an expert nobody chose runs nothing
+    and its weights are never read), gathers the tokens, runs its SwiGLU
+    and scatter-adds the weighted result.  Forward only (a ``while`` loop
+    has no transpose): training keeps :func:`expert_ffn`.
+
+    ``layer`` may be traced (a layer scan's index): the weights are the
+    stacks of ALL layers and an expert's matrices are sliced at ``(layer,
+    expert)`` right where they are multiplied.  Handed one
+    layer's ``[E, H, I]`` slice, the loops below would take it as an
+    operand, and the scan would first copy the layer's whole stack out of
+    its ``xs`` to make it one (a gigabyte a layer at Kimi-K2's widths).
+
+    Returns ``(out [T, H], tokens_per_expert [E] int32)``."""
+    T, H = x.shape
+    E = w_gate.shape[1]
+    k = idx.shape[-1]
+    N = T * k
+    cd = compute_dtype
+    chunk = min(DECODE_CHUNK, -(-T // 16) * 16)  # a decode step: one small piece
+    eid = idx.reshape(N)
+    order = jnp.argsort(eid)            # stable: the sentinel sorts last
+    sizes = jnp.bincount(eid, length=E + 1)[:E].astype(jnp.int32)
+    offs = jnp.cumsum(sizes) - sizes
+    tok_sorted = (order // k).astype(jnp.int32)
+    w_sorted = jnp.take(weights.reshape(N), order).astype(jnp.float32)
+    lane = jnp.arange(chunk, dtype=jnp.int32)
+    layer = jnp.asarray(layer, jnp.int32)
+
+    def piece(e, c, out):
+        rank = c * chunk + lane
+        at = jnp.minimum(offs[e] + rank, N - 1)
+        tok = jnp.take(tok_sorted, at)
+        w = jnp.where(rank < sizes[e], jnp.take(w_sorted, at), 0.0)
+        xs = jnp.take(x, tok, axis=0).astype(cd)
+        wg, wu, wd = (lax.dynamic_slice(
+            m, (layer, e, 0, 0), (1, 1, *m.shape[2:]))[0, 0].astype(cd)
+            for m in (w_gate, w_up, w_down))
+        y = (jax.nn.silu(xs @ wg) * (xs @ wu)) @ wd
+        return out.at[tok].add(y.astype(jnp.float32) * w[:, None])
+
+    def expert(e, out):
+        if T <= chunk:
+            return lax.cond(sizes[e] > 0, lambda o: piece(e, 0, o),
+                            lambda o: o, out)
+        return lax.fori_loop(0, (sizes[e] + chunk - 1) // chunk,
+                             lambda c, o: piece(e, c, o), out)
+
+    out = lax.fori_loop(0, E, expert, jnp.zeros((T, H), jnp.float32))
+    return out.astype(cd), sizes
+
+
 def noaux_topk_routing(
     scores: jnp.ndarray,      # [..., E] f32 sigmoid scores
     bias: jnp.ndarray,        # [E] e_score_correction_bias (selection only)

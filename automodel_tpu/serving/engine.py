@@ -254,8 +254,9 @@ def _paged_step(model, block_size: int, quantized: bool, cow_enabled: bool,
     """ONE traced program per step width: run any pending copy-on-write
     block forks, write this step's tokens into the paged cache, attend,
     and greedy-pick EVERY column's next token.  Returns ``(greedy [B, W],
-    last_logits [B, V], pools)`` — the pools are donated and come back as
-    the layer scan's carry (``models/llama.py::forward_embeds``), so the
+    last_logits [B, V], pools)`` and, from a model with routed expert
+    layers, ``expert_tokens [n_moe_layers, held]``.  The pools are donated
+    and come back as the layer scan's carry (``models/llama.py::forward_embeds``), so the
     cache updates in place.  Plain decode reads its one token at its last
     valid column of ``greedy``; the speculative verify reads the argmax at
     each draft position from the same array — the per-column argmax IS the
@@ -296,6 +297,9 @@ def _paged_step(model, block_size: int, quantized: bool, cow_enabled: bool,
         last = jnp.take_along_axis(
             logits, last_col[:, None, None], axis=1)[:, 0]    # [B, V]
         greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [B, W]
+    if "expert_tokens" in out:
+        # [n_moe_layers, held] int32: tokens each held expert got this step
+        return greedy, last, out["kv_cache"], out["expert_tokens"]
     return greedy, last, out["kv_cache"]
 
 
@@ -330,10 +334,11 @@ class DecodeEngine:
         cache_dtype = jnp.int8 if self.quantized else model.compute_dtype
         num_blocks = self.config.resolved_num_blocks()
         self.max_blocks_per_seq = self.config.blocks_per_seq
+        # the planes of the cache are the MODEL's to say: per-head k and v,
+        # or MLA's one latent plane (refused with int8, loudly, there)
         self._pool_spec = dict(
             num_layers=mcfg.num_hidden_layers,
-            num_kv_heads=mcfg.num_key_value_heads,
-            head_dim=mcfg.head_dim, num_blocks=num_blocks,
+            planes=model.paged_cache_planes(), num_blocks=num_blocks,
             block_size=self.config.kv_block_size, cache_dtype=cache_dtype,
             quantized=self.quantized)
         self.pools = init_paged_pools(**self._pool_spec)
@@ -402,6 +407,11 @@ class DecodeEngine:
         self.mixed_steps = 0
         self.aborts = 0
         self.tokens_generated = 0
+        # routed-expert counters, summed over the steps of a model whose
+        # step returns ``expert_tokens`` (None for a model without routed
+        # layers in its serving step)
+        self.expert_assignments_sum = None
+        self.experts_hit_sum = None
         self.watchdog_recoveries = 0
         # clock stamp of the FIRST of the current run of no-progress steps
         # (None while the engine is productive or idle)
@@ -773,15 +783,15 @@ class DecodeEngine:
                     rows=len(active), positions=positions, slots=n_slots,
                     prefill_rows=prefill_rows,
                     sampled=sum(1 for w in active if w.samples_next)):
-                greedy, last_logits, self.pools = self.step_fn(width)(
-                    self.params, self.pools, ids, pos, slots, tables, ctx,
-                    last, cow_src, cow_dst, *extra)
+                greedy, last_logits, self.pools, *routed = self.step_fn(
+                    width)(self.params, self.pools, ids, pos, slots, tables,
+                           ctx, last, cow_src, cow_dst, *extra)
             # the engine's one host sync: the [B, W] per-column argmax
             # drives the host-side request state machine — plain decode
             # reads one column, the speculative verify reads k+1, SAME
             # fetch either way
             with timers.record("serve_fetch"):
-                greedy = np.asarray(jax.device_get(greedy))  # lint: disable=L004 (continuous batching IS a per-step host decision loop: one [B, W]-int fetch per step — the speculative verify rides it too — and the logits stay on device unless do_sample)
+                greedy, *routed = (np.asarray(a) for a in jax.device_get((greedy, *routed)))  # lint: disable=L004 (continuous batching IS a per-step host decision loop: one [B, W]-int fetch per step — the speculative verify rides it too — and the logits stay on device unless do_sample)
         except InjectedFault:
             self._watchdog_recover("injected stall (serve_watchdog_stall)")
             return []
@@ -792,6 +802,14 @@ class DecodeEngine:
             # real bug stays loud
             self._watchdog_recover(f"device step failed: {e!r}")
             raise
+        if routed:
+            # a span's stats are fixed when it opens, so this is an event
+            assignments, hit = int(routed[0].sum()), int((routed[0] > 0).sum())
+            timers.event("serve_experts", step=self.steps_run,
+                         assignments=assignments, hit=hit)
+            self.expert_assignments_sum = (self.expert_assignments_sum
+                                           or 0) + assignments
+            self.experts_hit_sum = (self.experts_hit_sum or 0) + hit
         with timers.record("serve_finish"):
             # slot -> this row's greedy/sampled CHAIN: column t-1 is the
             # plain next token, columns t..t+d-1 are the argmax at the d
@@ -995,6 +1013,9 @@ class DecodeEngine:
             "decode_steps": self.decode_steps,
             "mixed_steps": self.mixed_steps,
             "tokens_generated": self.tokens_generated,
+            # None unless the model's step has routed expert layers
+            "expert_assignments_sum": self.expert_assignments_sum,
+            "experts_hit_sum": self.experts_hit_sum,
             "preemptions": self.scheduler.preemptions,
             "admissions": self.scheduler.admissions,
             "aborts": self.aborts,

@@ -17,6 +17,13 @@ step touches is static-shape: pools, ``[B, MB]`` block tables, ``[B, S]``
 slot mappings — allocation is pure host bookkeeping
 (:class:`BlockAllocator`), never a trace event.
 
+A model with a LATENT cache (MLA: DeepSeek-V3, Kimi-K2) says so through
+``model.paged_cache_planes()`` and gets ONE plane ``kv: [L, num_blocks,
+block_size, kv_lora_rank + qk_rope_head_dim]`` whose leading values are key
+and value at once (:meth:`PagedKVView.write_latent` /
+:meth:`PagedKVView.attend_latent`); allocator, block tables, scheduler and
+:func:`cow_copy_blocks` see block ids only and do not change.
+
 Block 0 is the reserved **null page**: pad tokens write into it and pad
 block-table entries point at it, so scatter/gather shapes stay static and
 garbage is never read (context-length masks exclude it).
@@ -391,19 +398,43 @@ def cow_copy_blocks(pools: Dict[str, jnp.ndarray], src: jnp.ndarray,
             for name, pool in pools.items()}
 
 
-def init_paged_pools(*, num_layers: int, num_kv_heads: int, head_dim: int,
-                     num_blocks: int, block_size: int, cache_dtype,
-                     quantized: bool) -> Dict[str, jnp.ndarray]:
-    """The static per-layer-stacked pools: ``{"k"|"v": [L, NB, BS, Hk, D]}``
-    plus ``{"k_scale"|"v_scale": [L, NB, BS, Hk]}`` when quantized."""
-    shape = (num_layers, num_blocks, block_size, num_kv_heads, head_dim)
+def latent_plane_width(r: int) -> int:
+    """A latent plane's stored width: ``r`` rounded up to the 128-lane tile.
+    The TPU pads the minor dimension of an HBM array to the tile anyway (a
+    576-wide bf16 plane IS ``memref<..x640xbf16>`` to Mosaic, which then
+    refuses a 576-wide page as a DMA source), so the padding costs no byte
+    that was not already there; the pad columns are written as zeros and
+    meet zeros in the query."""
+    return -(-int(r) // 128) * 128
+
+
+def init_paged_pools(*, num_layers: int, num_blocks: int, block_size: int,
+                     cache_dtype, quantized: bool,
+                     planes: Dict[str, Tuple[int, ...]],
+                     ) -> Dict[str, jnp.ndarray]:
+    """The static per-layer-stacked pools, one per plane of the model's
+    cache: ``{name: [L, NB, BS, *per-slot shape]}``.  ``planes`` is what
+    the model says it caches per token (``model.paged_cache_planes()``):
+    ``{"k"|"v": (Hk, D)}`` for a per-head cache, ``{"kv": (R,)}`` for a
+    latent cache (MLA: ONE plane whose leading values are key AND value).
+    A quantized per-head cache adds ``{"k_scale"|"v_scale": [L, NB, BS,
+    Hk]}``; a latent plane has no per-head scale to carry and is refused."""
     dtype = jnp.int8 if quantized else jnp.dtype(cache_dtype)
-    pools = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
-    if quantized:
-        # two distinct buffers: the step donates the pools, and XLA
-        # rejects donating one buffer twice
-        pools["k_scale"] = jnp.zeros(shape[:-1], jnp.float32)
-        pools["v_scale"] = jnp.zeros(shape[:-1], jnp.float32)
+    pools = {}
+    for name, per_slot in planes.items():
+        if len(per_slot) == 1:
+            per_slot = (latent_plane_width(per_slot[0]),)
+        shape = (num_layers, num_blocks, block_size, *per_slot)
+        pools[name] = jnp.zeros(shape, dtype)
+        if quantized:
+            if len(per_slot) != 2:
+                raise NotImplementedError(
+                    f"serving.kv_cache_dtype: int8 is not wired for the "
+                    f"latent cache plane {name!r} {tuple(per_slot)}: it has "
+                    "no per-head scale plane; serve it in the compute dtype")
+            # distinct buffers: the step donates the pools, and XLA
+            # rejects donating one buffer twice
+            pools[name + "_scale"] = jnp.zeros(shape[:-1], jnp.float32)
     return pools
 
 
@@ -502,6 +533,51 @@ class PagedKVView:
             positions=self.positions, scale=scale,
             logits_soft_cap=logits_soft_cap,
             local_window_size=local_window_size)
+
+    def valid_tokens(self) -> jnp.ndarray:
+        """``[B, S]`` bool: the step buffer's columns that hold a token.
+        The engine writes a row's tokens at consecutive positions and pads
+        by repeating the last one, so a row holds ``last - first + 1``; an
+        idle row's table is all null page (block 0 is never a request's)
+        and it holds none."""
+        pos = self.positions
+        held = pos[:, -1:] - pos[:, :1] + 1
+        busy = self.block_tables[:, :1] != 0
+        return (jnp.arange(pos.shape[1], dtype=pos.dtype)[None, :] < held) & busy
+
+    # -- the latent seam (deepseek_v3._mla_attention's paged branch) --------
+    def write_latent(self, latent: jnp.ndarray) -> Dict[str, jnp.ndarray]:
+        """Scatter this step's ``[B, S, R]`` latent rows (MLA: the normalised
+        ``c_kv`` and the rotated rope key, side by side) into the view's
+        layer of the ONE stacked plane ``kv [L, NB, BS, R]``, at flat slot
+        ``layer * NB * BS + slot_mapping``; returns the stacked pools.  The
+        plane is :func:`latent_plane_width` wide: the rows are zero-padded
+        to it."""
+        pools = dict(self.pools)
+        pool = pools["kv"]
+        NB, BS, R = pool.shape[1:]
+        latent = jnp.pad(latent, ((0, 0), (0, 0),
+                                  (0, R - latent.shape[-1])))
+        slots = (jnp.asarray(self.layer, jnp.int32) * (NB * BS)
+                 + self.slot_mapping.reshape(-1))
+        pools["kv"] = pool.reshape(-1, R).at[slots].set(
+            latent.reshape(-1, R).astype(pool.dtype)).reshape(pool.shape)
+        return pools
+
+    def attend_latent(self, q: jnp.ndarray, pools: Dict[str, jnp.ndarray],
+                      *, value_dim: int, scale: float) -> jnp.ndarray:
+        """MQA over the latent: ``q [B, S, Hq, R]`` (absorbed queries beside
+        their rope part) against the view's layer of ``kv``, whose first
+        ``value_dim`` values are also the value -> ``[B, S, Hq, value_dim]``
+        through the ``attention.mla_paged_decode`` chain."""
+        from automodel_tpu.ops.mla_paged_attention import mla_paged_attention
+
+        q = jnp.pad(q, ((0, 0),) * 3
+                    + ((0, pools["kv"].shape[-1] - q.shape[-1]),))
+        return mla_paged_attention(
+            q, pools["kv"], layer=self.layer,
+            block_tables=self.block_tables, context_lens=self.context_lens,
+            positions=self.positions, value_dim=value_dim, scale=scale)
 
 
 def slot_for(block_table: List[int], position: int, block_size: int) -> int:

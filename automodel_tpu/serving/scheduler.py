@@ -233,8 +233,19 @@ class Request:
         return self.prompt + self.out_tokens
 
     @property
+    def seq_len(self) -> int:
+        return len(self.prompt) + len(self.out_tokens)
+
+    @property
     def pending(self) -> List[int]:
-        return self.seq[self.num_computed:]
+        """``seq[num_computed:]`` without building ``seq``: the scheduler
+        asks this of every active row every step, and a decode row's
+        pending token is its last output, not a copy of its whole context
+        (64 rows of 5k tokens were 4 ms a step of list building)."""
+        n, p = self.num_computed, len(self.prompt)
+        if n >= p:
+            return self.out_tokens[n - p:]
+        return self.prompt[n:] + self.out_tokens
 
     @property
     def finished(self) -> bool:
@@ -932,7 +943,7 @@ class Scheduler:
             if req.slot is None:
                 continue       # preempted by an earlier row's allocation
             t = min(len(req.pending), width)
-            samples_next = req.num_computed + t == len(req.seq)
+            samples_next = req.num_computed + t == req.seq_len
             draft = (self._propose_draft(req, width - t)
                      if speculate and samples_next else [])
             if not self._ensure_blocks(req, req.num_computed + t

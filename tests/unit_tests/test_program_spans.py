@@ -450,3 +450,77 @@ def test_paged_step_updates_its_pools_in_place(
                      f"are copied whole once a step: {whole} (PERF.md s7)")
     assert not whole, moves
     assert temp < pool_bytes(pools) / 4, (temp, pool_bytes(pools))
+
+
+# -- the latent (MLA) step: one plane, two layer stacks, expert stacks --------
+# Kimi-K2's serving step carries ONE latent plane through both layer scans
+# and slices an expert's matrices at (layer, expert) where it multiplies
+# them.  The same question as above, put to the compiled program: nothing
+# the size of the plane, of a layer of it or of a layer's expert stack is
+# copied, sliced out or relaid out; one Mosaic call per layer stack, named
+# ``mla_decode``.
+def _latent_model():
+    from automodel_tpu.models.deepseek_v3 import (
+        DeepseekV3Config,
+        DeepseekV3ForCausalLM,
+    )
+
+    cfg = DeepseekV3Config(
+        vocab_size=256, hidden_size=256, intermediate_size=512,
+        num_hidden_layers=4, num_attention_heads=16, num_key_value_heads=16,
+        q_lora_rank=128, kv_lora_rank=512, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, n_routed_experts=32,
+        num_experts_per_tok=4, moe_intermediate_size=768,
+        first_k_dense_replace=1, held_experts=[8, 8],
+        tie_word_embeddings=False, max_position_embeddings=256,
+        moe_capacity_factor=None)
+    return DeepseekV3ForCausalLM(cfg, param_dtype=jnp.bfloat16,
+                                 compute_dtype=jnp.bfloat16, remat=False)
+
+
+@pytest.mark.parametrize("width", [1, 8], ids=["w1", "w8"])
+def test_latent_step_updates_its_plane_in_place(one_chip, monkeypatch, width):
+    from automodel_tpu.ops.kernel_lib import registry
+    from automodel_tpu.serving.kv_cache import pool_bytes
+
+    monkeypatch.setattr(registry, "on_tpu", lambda: True)
+    model = _latent_model()
+    params = model.abstract_params()
+    eng = DecodeEngine(
+        model, params,
+        ServingConfig(kv_block_size=16, max_num_seqs=8, max_model_len=128,
+                      prefill_chunk=8, num_kv_blocks=16))
+    assert sorted(eng.pools) == ["kv"] and eng.pools["kv"].shape[-1] == 640
+
+    def spec(a, shape=None):
+        return jax.ShapeDtypeStruct(shape or a.shape, a.dtype,
+                                    sharding=one_chip)
+
+    pools = {name: spec(p, (p.shape[0], _POOL_BLOCKS, *p.shape[2:]))
+             for name, p in eng.pools.items()}
+    B, MB = 8, eng.max_blocks_per_seq
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+    compiled = eng.step_fn(width).lower(
+        jax.tree.map(spec, params), pools,
+        i32(B, width), i32(B, width), i32(B, width), i32(B, MB), i32(B),
+        i32(B), i32(B), i32(B)).compile()
+    text = compiled.as_text()
+
+    kernels = _kernels(text)
+    assert len(kernels) == 2, kernels       # the dense stack's, the experts'
+    for name, scope in kernels.items():
+        assert re.match(r"^mla_decode(\.\d+)?$", name), kernels
+        assert "/attn/attn_core/mla_decode/" in scope, kernels
+    for scope_name in ("mla_latent_write", "mla_absorb_q", "mla_out",
+                       "moe_router", "moe_experts", "moe_shared",
+                       "dense_mlp"):
+        assert f"/{scope_name}/" in text, scope_name
+
+    assert not _pool_sized_moves(text, pools), _pool_sized_moves(text, pools)
+    # the expert stacks [3, 8, 256, 768] stay where they are: the step reads
+    # one expert's matrices out of the stacks of all layers; handed to the
+    # expert loops as one layer's slice, the stack was copied out per layer
+    stacks = {"e": jax.ShapeDtypeStruct((3, 8 * 256 * 768), jnp.bfloat16)}
+    assert not _pool_sized_moves(text, stacks), _pool_sized_moves(text, stacks)
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < pool_bytes(pools) / 4

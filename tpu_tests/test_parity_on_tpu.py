@@ -13,6 +13,7 @@ from automodel_tpu.ops.kernel_lib import parity, registry
 _RUNNERS = {
     "attention.splash": parity.run_attention_parity,
     "attention.paged_decode": parity.run_paged_attention_parity,
+    "attention.mla_paged_decode": parity.run_mla_paged_attention_parity,
     "linear_ce.pallas": parity.run_linear_ce_parity,
     # grads=True: dlhs is a second gmm, drhs the transposed kernel (tgmm)
     "gmm.pallas": functools.partial(parity.run_gmm_parity, grads=True),
@@ -41,6 +42,10 @@ def test_probes_accept_published_widths_on_the_chip():
                                   "head_dim": 64}),
             ("attention.paged_decode", {"q_seq": 1, "head_dim": 128}),
             ("attention.paged_decode", {"q_seq": 5, "head_dim": 128}),
+            ("attention.mla_paged_decode",
+             {"q_seq": 1, "latent_dim": 640, "value_dim": 512}),
+            ("attention.mla_paged_decode",
+             {"q_seq": 64, "latent_dim": 640, "value_dim": 512}),
             ("linear_ce.pallas", {"t": 16384, "h": 2048, "v": 128256}),
             ("gmm.pallas", {"m": 4096, "k": 4096, "n": 14336}),
             ("qdot.pallas", {"m": 4096, "k": 14336, "n": 4096}),
