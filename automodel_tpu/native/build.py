@@ -27,7 +27,8 @@ _lib: Optional[ctypes.CDLL] = None
 _tried = False
 # how this process got the library: "built" (compiled here from
 # src/packing.cpp), "cached" (an existing _build/*.so of the same source
-# hash), "python" (no compiler: the Python packer/collater is the path)
+# hash), "python" (no compiler: the numpy row layout and collater are the
+# path)
 _source: Optional[str] = None
 
 
@@ -46,11 +47,11 @@ def _so_path(cc: str) -> str:
 
 def _bind(dll: ctypes.CDLL) -> ctypes.CDLL:
     i32p = ctypes.POINTER(ctypes.c_int32)
-    dll.am_pack_greedy.restype = ctypes.c_int64
-    dll.am_pack_greedy.argtypes = [
-        i32p, ctypes.c_int64, i32p, i32p,
+    dll.am_pack_rows.restype = ctypes.c_int32
+    dll.am_pack_rows.argtypes = [
+        i32p, i32p, ctypes.c_int64, i32p, i32p,
         ctypes.c_int64, ctypes.c_int32, ctypes.c_int32,
-        i32p, i32p, i32p, i32p, i32p,
+        i32p, i32p, i32p, i32p,
     ]
     dll.am_collate_pad.restype = ctypes.c_int32
     dll.am_collate_pad.argtypes = [
@@ -72,7 +73,7 @@ def lib() -> Optional[ctypes.CDLL]:
     if cc is None:
         _source = "python"
         logger.warning("native core disabled (no C++ compiler on PATH): "
-                       "the Python packer/collater runs instead")
+                       "the numpy row layout and collater run instead")
         return None
     so = _so_path(cc)
     _source = "cached"
@@ -112,37 +113,37 @@ def _i32ptr(arr):
     return arr.ctypes.data_as(ctypes.POINTER(ctypes.c_int32))
 
 
-def pack_greedy(lengths, ids, labels, pack_size: int, pad_id: int,
-                ignore_index: int):
-    """numpy front-end for am_pack_greedy; returns a dict of [n_packs, size]
-    int32 arrays plus per-pack sample ``counts``, or None when the native
-    core is unavailable."""
+def pack_rows(lengths, counts, ids, labels, pack_size: int, pad_id: int,
+              ignore_index: int):
+    """numpy front-end for am_pack_rows: documents already in row order
+    (``lengths`` per document, ``counts`` documents per row, ``ids`` and
+    ``labels`` concatenated) laid out as a dict of [n_rows, pack_size] int32
+    arrays, or None when the native core is unavailable."""
     import numpy as np
 
     dll = lib()
     if dll is None:
         return None
     lengths = np.ascontiguousarray(lengths, np.int32)
+    counts = np.ascontiguousarray(counts, np.int32)
     ids = np.ascontiguousarray(ids, np.int32)
     labels = np.ascontiguousarray(labels, np.int32)
-    null = ctypes.POINTER(ctypes.c_int32)()
-    n = dll.am_pack_greedy(_i32ptr(lengths), len(lengths), _i32ptr(ids),
-                           _i32ptr(labels), pack_size, pad_id, ignore_index,
-                           null, null, null, null, null)
-    if n < 0:
+    if (counts.sum() != len(lengths) or counts.min(initial=0) < 0
+            or not lengths.sum() == len(ids) == len(labels)):
         raise ValueError(
-            f"sample longer than packed_sequence_size={pack_size}")
-    out = {k: np.empty((n, pack_size), np.int32)
+            f"{len(lengths)} documents of {lengths.sum()} tokens do not "
+            f"match counts summing to {counts.sum()}, {len(ids)} ids and "
+            f"{len(labels)} labels")
+    out = {k: np.empty((len(counts), pack_size), np.int32)
            for k in ("input_ids", "labels", "position_ids", "segment_ids")}
-    counts = np.empty((n,), np.int32)
-    n2 = dll.am_pack_greedy(
-        _i32ptr(lengths), len(lengths), _i32ptr(ids), _i32ptr(labels),
-        pack_size, pad_id, ignore_index,
+    rc = dll.am_pack_rows(
+        _i32ptr(lengths), _i32ptr(counts), len(counts), _i32ptr(ids),
+        _i32ptr(labels), pack_size, pad_id, ignore_index,
         _i32ptr(out["input_ids"]), _i32ptr(out["labels"]),
-        _i32ptr(out["position_ids"]), _i32ptr(out["segment_ids"]),
-        _i32ptr(counts))
-    assert n2 == n
-    out["counts"] = counts
+        _i32ptr(out["position_ids"]), _i32ptr(out["segment_ids"]))
+    if rc != 0:
+        raise ValueError(
+            f"a row's documents exceed packed_sequence_size={pack_size}")
     return out
 
 

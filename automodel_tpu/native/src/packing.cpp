@@ -1,10 +1,13 @@
-// Native data-plane core: greedy sequence packing + ragged-batch collation.
+// Native data-plane core: packed-row layout + ragged-batch collation.
 //
 // Role: the hot host-side loops of the input pipeline (the reference keeps
-// its data plane on torch's C++ via torchdata/tokenizers; here the packing
-// and padding inner loops are plain C++ behind ctypes, with the Python
-// implementations in automodel_tpu/datasets/ as the semantic reference and
-// fallback).  Single-threaded on purpose: dataloading shares one host core
+// its data plane on torch's C++ via torchdata/tokenizers; here the inner
+// loops that copy tokens into rows and pad ragged batches are plain C++
+// behind ctypes, with the numpy implementations in automodel_tpu/datasets/
+// as the semantic reference and the path where there is no compiler).
+// Nothing here decides anything: which documents share a packed row is
+// decided in Python (packed_sequence.py:best_fit_rows), once, for both
+// layouts.  Single-threaded on purpose: dataloading shares one host core
 // with the dispatch loop, so memory-bandwidth-efficient tight loops beat
 // thread fan-out here.
 //
@@ -16,82 +19,59 @@
 
 extern "C" {
 
-// Greedy no-split packing (semantics of
-// automodel_tpu/datasets/llm/packed_sequence.py:pack with
-// split_across_pack=false): samples are laid out consecutively; a sample
-// that would overflow the current pack starts the next one.  Emits
-// input_ids / labels / position_ids (restarting per sample) / segment_ids
-// (1-based per sample, dense per pack; 0 = padding) and per-pack sample
-// counts.
+// Row layout of whole-document packing (split_across_pack=false).  WHICH
+// documents share a row is decided in one place,
+// automodel_tpu/datasets/llm/packed_sequence.py:best_fit_rows (best fit,
+// longest first, inside a window of the dataset); the caller hands the
+// documents over already in row order with the number each row holds, and
+// this writes the rows: input_ids / labels / position_ids (restarting per
+// document) / segment_ids (1-based per document, dense per row; 0 =
+// padding), each row padded to pack_size.
 //
-// Pass out_* = nullptr to only count packs (first of two calls).
+//   lengths[sum(counts)] : token count of each document, in row order
+//   counts[n_rows]       : documents in each row
+//   ids, labels          : the documents' tokens, concatenated in row order
+//   pack_size            : slots per row
+//   pad_id               : fill for input_ids (labels pad with ignore_index)
+//   out_*                : [n_rows, pack_size]
 //
-//   lengths[n_samples]  : token count of each sample
-//   ids, labels         : concatenated sample tokens (sum(lengths))
-//   pack_size           : tokens per pack
-//   pad_id              : fill for input_ids (labels pad with ignore_index)
-//   out_counts          : samples placed into each pack (len n_packs);
-//                         zero-length samples are skipped entirely
-//
-// Returns the number of packs, or -1 if any sample exceeds pack_size.
-int64_t am_pack_greedy(
-    const int32_t* lengths, int64_t n_samples,
+// Returns 0, or -1 if a row's documents exceed pack_size.
+int32_t am_pack_rows(
+    const int32_t* lengths, const int32_t* counts, int64_t n_rows,
     const int32_t* ids, const int32_t* labels,
     int64_t pack_size, int32_t pad_id, int32_t ignore_index,
     int32_t* out_ids, int32_t* out_labels,
-    int32_t* out_pos, int32_t* out_seg, int32_t* out_counts) {
-  int64_t n_packs = 0;
-  int64_t fill = 0;         // tokens used in the current pack
+    int32_t* out_pos, int32_t* out_seg) {
+  int64_t doc = 0;          // next document
   int64_t src = 0;          // read offset into ids/labels
-  int32_t seg = 0;          // segments emitted in the current pack
-  const bool write = out_ids != nullptr;
-
-  auto pad_tail = [&](int64_t pack_idx, int64_t from) {
-    if (!write) return;
-    int32_t* ids_row = out_ids + pack_idx * pack_size;
-    int32_t* lab_row = out_labels + pack_idx * pack_size;
-    int32_t* pos_row = out_pos + pack_idx * pack_size;
-    int32_t* seg_row = out_seg + pack_idx * pack_size;
-    for (int64_t i = from; i < pack_size; ++i) {
+  for (int64_t r = 0; r < n_rows; ++r) {
+    int32_t* ids_row = out_ids + r * pack_size;
+    int32_t* lab_row = out_labels + r * pack_size;
+    int32_t* pos_row = out_pos + r * pack_size;
+    int32_t* seg_row = out_seg + r * pack_size;
+    int64_t fill = 0;       // slots used in this row
+    for (int32_t seg = 1; seg <= counts[r]; ++seg, ++doc) {
+      const int64_t len = lengths[doc];
+      if (len < 0 || fill + len > pack_size) return -1;
+      std::memcpy(ids_row + fill, ids + src, len * sizeof(int32_t));
+      std::memcpy(lab_row + fill, labels + src, len * sizeof(int32_t));
+      for (int64_t i = 0; i < len; ++i) {
+        pos_row[fill + i] = static_cast<int32_t>(i);
+        seg_row[fill + i] = seg;
+      }
+      src += len;
+      fill += len;
+    }
+    for (int64_t i = fill; i < pack_size; ++i) {
       ids_row[i] = pad_id;
       lab_row[i] = ignore_index;
-      // pad positions keep counting (python packer parity; they are
+      // pad positions keep counting (python layout parity; they are
       // attention-masked via segment 0 either way)
       pos_row[i] = static_cast<int32_t>(i);
       seg_row[i] = 0;
     }
-  };
-
-  for (int64_t s = 0; s < n_samples; ++s) {
-    const int64_t len = lengths[s];
-    if (len > pack_size) return -1;
-    if (len == 0) continue;            // contributes no tokens, no segment
-    if (fill + len > pack_size) {      // close the current pack
-      pad_tail(n_packs, fill);
-      if (write) out_counts[n_packs] = seg;
-      ++n_packs;
-      fill = 0;
-      seg = 0;
-    }
-    if (write) {
-      int64_t base = n_packs * pack_size + fill;
-      std::memcpy(out_ids + base, ids + src, len * sizeof(int32_t));
-      std::memcpy(out_labels + base, labels + src, len * sizeof(int32_t));
-      for (int64_t i = 0; i < len; ++i) {
-        out_pos[base + i] = static_cast<int32_t>(i);
-        out_seg[base + i] = seg + 1;
-      }
-    }
-    src += len;
-    fill += len;
-    ++seg;
   }
-  if (fill > 0) {
-    pad_tail(n_packs, fill);
-    if (write) out_counts[n_packs] = seg;
-    ++n_packs;
-  }
-  return n_packs;
+  return 0;
 }
 
 // Pad a ragged batch of int32 rows into a [n_rows, max_len] buffer.

@@ -119,6 +119,8 @@ def build_dataloader(cfg: ConfigNode, dataset, cfg_key: str = "dataloader",
             packed_sequence_size=int(packed_cfg.get("packed_sequence_size")),
             split_across_pack=bool(packed_cfg.get("split_across_pack", False)),
         ).pack()
+        logger.info("%s: pack_fill %.4f (%d rows hold %d tokens)", cfg_key,
+                    dataset.fill, dataset.rows, dataset.tokens)
 
     dl_cfg = cfg.get(cfg_key)
     kwargs: Dict[str, Any] = {}

@@ -10,7 +10,11 @@ from automodel_tpu.datasets.llm.nanogpt_dataset import (
     load_shard,
     write_shard,
 )
-from automodel_tpu.datasets.llm.packed_sequence import PackedSequence
+from automodel_tpu.datasets.llm.packed_sequence import (
+    WINDOW,
+    PackedSequence,
+    best_fit_rows,
+)
 from automodel_tpu.datasets.utils import (
     CROSS_ENTROPY_IGNORE_IDX,
     default_collater,
@@ -50,14 +54,205 @@ def test_packed_sequence_segment_ids():
     ]
     ps = PackedSequence(data, packed_sequence_size=8).pack()
     p0 = ps[0]
-    # first pack: samples 1+2 (3+2=5 tokens) + padding; sample 3 doesn't fit
-    np.testing.assert_array_equal(p0["segment_ids"][:5], [1, 1, 1, 2, 2])
-    assert (p0["segment_ids"][5:] == 0).all()
-    np.testing.assert_array_equal(p0["position_ids"][:5], [0, 1, 2, 0, 1])
-    assert (p0["labels"][5:] == CROSS_ENTROPY_IGNORE_IDX).all()
+    # longest first: sample 3 (4 tokens) opens a row, sample 1 (3) fits
+    # beside it and stands first, as in the dataset; sample 2 (2) no longer
+    # fits and opens the second row
+    np.testing.assert_array_equal(p0["input_ids"], [1, 2, 3, 6, 7, 8, 9, 0])
+    np.testing.assert_array_equal(p0["segment_ids"], [1, 1, 1, 2, 2, 2, 2, 0])
+    np.testing.assert_array_equal(p0["position_ids"], [0, 1, 2, 0, 1, 2, 3, 7])
+    np.testing.assert_array_equal(p0["labels"][:7],
+                                  [2, 3, -100, 7, 8, 9, -100])
+    assert p0["labels"][7] == CROSS_ENTROPY_IGNORE_IDX
     p1 = ps[1]
-    np.testing.assert_array_equal(p1["segment_ids"][:4], [1, 1, 1, 1])
+    np.testing.assert_array_equal(p1["segment_ids"][:3], [1, 1, 0])
+    assert (p1["labels"][2:] == CROSS_ENTROPY_IGNORE_IDX).all()
     assert len(ps) == 2
+    assert (ps.rows, ps.tokens, ps.fill) == (2, 9, 9 / 16)
+
+
+def test_best_fit_rows_by_hand():
+    # 5 opens row a (3 left), 4 opens row b (4 left), 3 fills a exactly
+    # (least room that holds it), 2 and 1 go to b; nothing of length 0
+    assert best_fit_rows([3, 2, 4, 5, 0, 1], 8) == [[0, 3], [1, 2, 5]]
+    # equal lengths go by index, equal rooms by the row opened first
+    assert best_fit_rows([2, 2, 2, 2, 1, 1], 3) == [[0, 4], [1, 5], [2], [3]]
+    assert best_fit_rows([], 8) == best_fit_rows([0, 0], 8) == []
+
+
+def _named_docs(lengths, loss_mask=False):
+    """Documents whose every token is unique in the dataset, so that a row
+    says which documents it holds; labels are the negated ids."""
+    out, base = [], 1
+    for n in lengths:
+        ids = np.arange(base, base + n, dtype=np.int32)
+        doc = {"input_ids": ids, "labels": -ids}
+        if loss_mask:
+            doc["loss_mask"] = (ids % 2).astype(np.int32)
+        out.append(doc)
+        base += n
+    return out
+
+
+def _rows_of_whole_documents(ps, docs, size):
+    """Hold ``ps`` to the batch format and return its rows as lists of
+    document indices: every document whole, contiguous, with its labels,
+    positions from 0 and a segment id of its own; ids dense from 1 per row;
+    padding after the last document and nowhere else."""
+    first = {int(d["input_ids"][0]): i for i, d in enumerate(docs)
+             if len(d["input_ids"])}
+    rows = []
+    for r in range(len(ps)):
+        item, lens = ps[r], ps.packed_dataset[r]["seq_lens"]
+        assert all(v.shape == (size,) and v.dtype == np.int32
+                   for v in item.values())
+        used, row = int(lens.sum()), []
+        for k, n in enumerate(lens):
+            o = int(lens[:k].sum())
+            i = first[int(item["input_ids"][o])]
+            d = docs[i]
+            assert n == len(d["input_ids"])
+            np.testing.assert_array_equal(item["input_ids"][o:o + n],
+                                          d["input_ids"])
+            np.testing.assert_array_equal(item["labels"][o:o + n],
+                                          d["labels"])
+            np.testing.assert_array_equal(item["position_ids"][o:o + n],
+                                          np.arange(n))
+            assert (item["segment_ids"][o:o + n] == k + 1).all()
+            if "loss_mask" in d:
+                np.testing.assert_array_equal(item["loss_mask"][o:o + n],
+                                              d["loss_mask"])
+            row.append(i)
+        assert used <= size and (item["segment_ids"][used:] == 0).all()
+        assert (item["input_ids"][used:] == 0).all()
+        assert (item["labels"][used:] == CROSS_ENTROPY_IGNORE_IDX).all()
+        rows.append(row)
+    return rows
+
+
+def _lognormal(rng, n, size, median=600.0, sigma=1.2, low=16):
+    return np.clip(np.rint(median * np.exp(sigma * rng.standard_normal(n))),
+                   low, size).astype(int)
+
+
+LENGTH_SETS = {
+    "uniform": lambda rng: rng.integers(1, 129, 700),
+    "lognormal_clipped_at_the_row": lambda rng: _lognormal(
+        rng, 600, 128, median=20.0, low=1),
+    "all_equal_to_the_row": lambda rng: np.full(300, 128),
+    "all_tiny": lambda rng: rng.integers(1, 4, 1000),
+    "one_document": lambda rng: np.array([77]),
+    "not_a_multiple_of_the_window": lambda rng: rng.integers(
+        0, 100, 2 * WINDOW + 37),
+}
+
+
+@pytest.fixture(params=["native", "python"])
+def layout(request, monkeypatch):
+    """Both ways the rows are laid out: the C++ core, and the numpy one
+    that runs where there is no compiler (or a ``loss_mask`` to carry)."""
+    from automodel_tpu import native
+
+    if request.param == "python":
+        monkeypatch.setattr(native, "available", lambda: False)
+    elif not native.available():
+        pytest.skip("no C++ toolchain")
+    return request.param
+
+
+@pytest.mark.parametrize("name", sorted(LENGTH_SETS))
+def test_packed_whole_every_document_once(name, layout):
+    size = 128
+    lengths = LENGTH_SETS[name](np.random.default_rng(len(name)))
+    docs = _named_docs(lengths)
+    ps = PackedSequence(docs, packed_sequence_size=size).pack()
+    rows = _rows_of_whole_documents(ps, docs, size)
+    placed = sorted(i for row in rows for i in row)
+    assert placed == [i for i, n in enumerate(lengths) if n > 0]
+    assert ps.rows == len(rows) and ps.tokens == int(lengths.sum())
+    assert ps.fill == ps.tokens / (ps.rows * size)
+    # (d) a row draws from one window, its documents stand in dataset
+    # order, and the rows come out by their earliest document
+    assert all(row == sorted(row) for row in rows)
+    assert all(row[0] // WINDOW == row[-1] // WINDOW for row in rows)
+    heads = [row[0] for row in rows]
+    assert heads == sorted(heads)
+    # the same dataset gives the same rows
+    again = PackedSequence(docs, packed_sequence_size=size).pack()
+    for a, b in zip(ps.packed_dataset, again.packed_dataset):
+        for k in a:
+            np.testing.assert_array_equal(a[k], b[k])
+
+
+def test_packed_whole_fills_heavy_tailed_rows(layout):
+    """4,096 lognormal documents (median 600, sigma 1.2, 16-4096) into rows
+    of 4096: closing a row at the first document that does not fit leaves a
+    fifth of the slots empty, best fit inside a window of 256 under 2 %."""
+    size = 4096
+    lengths = _lognormal(np.random.default_rng(2025), 4096, size)
+    in_order, room = 0, 0
+    for n in lengths:
+        if n > room:
+            in_order, room = in_order + 1, size
+        room -= n
+    assert 0.75 < lengths.sum() / (in_order * size) < 0.85
+    ps = PackedSequence(_named_docs(lengths), packed_sequence_size=size).pack()
+    assert ps.fill >= 0.98, ps.fill
+    assert ps.rows < 0.83 * in_order
+
+
+@pytest.mark.parametrize("max_packs", [1, 7, 10_000])
+def test_packed_whole_max_packs_and_loss_mask_follow_the_placement(
+        max_packs, layout):
+    size = 64
+    lengths = np.random.default_rng(3).integers(1, 65, WINDOW + 50)
+    docs = _named_docs(lengths)
+    full = PackedSequence(docs, packed_sequence_size=size).pack()
+    rows = _rows_of_whole_documents(full, docs, size)
+    capped = PackedSequence(docs, packed_sequence_size=size,
+                            max_packs=max_packs).pack()
+    assert _rows_of_whole_documents(capped, docs, size) == rows[:max_packs]
+    masked_docs = _named_docs(lengths, loss_mask=True)
+    masked = PackedSequence(masked_docs, packed_sequence_size=size,
+                            max_packs=max_packs).pack()
+    assert "loss_mask" in masked[0]
+    assert _rows_of_whole_documents(masked, masked_docs,
+                                    size) == rows[:max_packs]
+
+
+def test_packed_whole_takes_a_stream_window_by_window():
+    """``max_packs`` stops reading: a dataset that cannot be listed is
+    packed as far as asked."""
+    def stream():
+        n = 0
+        while True:
+            n += 1
+            yield {"input_ids": [n] * 5, "labels": [n] * 5}
+
+    class Endless:
+        __iter__ = staticmethod(stream)
+
+    ps = PackedSequence(Endless(), packed_sequence_size=16, max_packs=3).pack()
+    assert ps.rows == 3 and ps.tokens == 45
+    np.testing.assert_array_equal(ps[0]["segment_ids"][:15],
+                                  [1] * 5 + [2] * 5 + [3] * 5)
+
+
+def test_pack_says_how_full_the_rows_are(caplog):
+    import logging
+
+    from automodel_tpu.config.loader import ConfigNode
+    from automodel_tpu.recipes.llm.train_ft import build_dataloader
+
+    docs = _named_docs([5, 3, 8, 2])
+    cfg = ConfigNode({"packed_sequence": {"packed_sequence_size": 8}})
+    with caplog.at_level(logging.INFO):
+        loader = build_dataloader(cfg, docs)
+    assert "packs created: 3 (18 tokens, fill 0.7500)" in caplog.text
+    assert "dataloader: pack_fill 0.7500 (3 rows hold 18 tokens)" in caplog.text
+    assert len(loader.dataset) == 3
+    split = PackedSequence(docs, packed_sequence_size=8,
+                           split_across_pack=True).pack()
+    assert (split.rows, split.tokens, split.fill) == (3, 18, 0.75)
 
 
 def test_packed_sequence_split_across_pack():
@@ -91,9 +286,10 @@ def test_packed_split_continuation_distinct_segment():
         assert len(docs) == 1, seg_to_docs
 
 
-def test_packed_too_long_raises():
-    data = [{"input_ids": list(range(10)), "labels": list(range(10))}]
-    with pytest.raises(ValueError):
+def test_packed_too_long_raises(layout):
+    data = [{"input_ids": [1, 2], "labels": [1, 2]},
+            {"input_ids": list(range(10)), "labels": list(range(10))}]
+    with pytest.raises(ValueError, match=r"too long \(10 > 4\)"):
         PackedSequence(data, packed_sequence_size=4).pack()
 
 
