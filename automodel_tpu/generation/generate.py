@@ -6,8 +6,9 @@ by necessity: everything under jit, no data-dependent Python control flow.
 
 * **Left-padded batching**: prompts are aligned to the right edge so every
   row's last prompt token sits at the same position — the whole batch then
-  decodes in lockstep (one shared ``cache_index``), pad positions are
-  excluded via the kv padding mask, and rope positions are 0-based per row.
+  decodes in lockstep (one shared write offset in the cache), pad positions
+  are excluded via the kv padding mask, and rope positions are 0-based per
+  row.
 * **Prefill**: one forward over the padded prompt block writes the kv cache
   and the last-position logits give every row's first sampled token.
 * **Decode**: ``lax.scan`` over ``max_new_tokens`` single-token steps —
@@ -38,6 +39,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+
+from automodel_tpu.generation.dense_kv import DenseKVView
 
 
 @dataclasses.dataclass(frozen=True)
@@ -95,10 +98,12 @@ def _generate_jit(model, params, left_ids, prompt_lens, cfg: GenerationConfig,
     # rope positions are 0-based per row (pads clamp to 0; they are masked)
     prefill_pos = jnp.maximum(jnp.arange(S)[None, :] - shift[:, None], 0)
 
+    # The dense cache (``DenseKVView``): its state rides each forward's
+    # layer scan as carry; the view holds the write offset and the mask.
     cache = model.init_kv_cache(B, max_len)
     out = model(params, left_ids, position_ids=prefill_pos.astype(jnp.int32),
-                attention_mask=kv_mask, kv_cache=cache,
-                cache_index=jnp.int32(0), **prefill_kwargs)
+                kv_cache=DenseKVView.at(cache, 0, S, kv_mask),
+                **prefill_kwargs)
     cache = out["kv_cache"]
     next_tok = sample_logits(out["logits"][:, -1], cfg, key)
 
@@ -107,8 +112,7 @@ def _generate_jit(model, params, left_ids, prompt_lens, cfg: GenerationConfig,
         t, step_key = xs
         pos_ids = (prompt_lens + t)[:, None].astype(jnp.int32)
         out = model(params, tok[:, None], position_ids=pos_ids,
-                    attention_mask=kv_mask, kv_cache=cache,
-                    cache_index=S + t)
+                    kv_cache=DenseKVView.at(cache, S + t, 1, kv_mask))
         cache = out["kv_cache"]
         sampled = sample_logits(out["logits"][:, 0], cfg, step_key)
         emitted = jnp.where(done, cfg.pad_token_id, tok)

@@ -54,9 +54,11 @@ afterwards — through the ``attention.mla_paged_decode`` chain
 (``ops/mla_paged_attention.py``), decode steps and prefill chunks alike:
 one kernel, and no re-expansion of the history per chunk.  The plane rides
 BOTH layer scans as their carry under one layer index that runs across
-the two stacks.  ``generate()``'s dense dict cache still holds expanded
-per-head keys (v padded to ``qk_head_dim``): it is the parity oracle and
-goes when ROADMAP Design 2 removes the dict caches.
+the two stacks (``models/layer_scan.py``).  A cache of per-head planes
+(``generate()``'s ``DenseKVView``) is written and attended in the EXPANDED
+form through the same seam (v padded to ``qk_head_dim``): it is the parity
+oracle of the absorbed form, and which form runs is read off the planes
+the cache holds.
 
 ``held_experts: [first, count]`` makes this model one expert-parallel
 share: the router stays ``n_routed_experts`` wide and picks
@@ -81,6 +83,13 @@ import jax.numpy as jnp
 from jax import lax
 
 from automodel_tpu.distributed.shardings import constrain
+from automodel_tpu.models.layer_scan import (
+    SubStack,
+    default_position_ids,
+    dense_kv_state,
+    norm_and_head,
+    scan_layers,
+)
 from automodel_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 from automodel_tpu.ops.attention import attention
 from automodel_tpu.ops.moe import (
@@ -94,7 +103,6 @@ from automodel_tpu.ops.moe import (
 )
 from automodel_tpu.ops.norms import rms_norm
 from automodel_tpu.ops.quant import maybe_qdot
-from automodel_tpu.ops.remat import resolve_remat_policy
 from automodel_tpu.ops.rotary import apply_rope
 
 
@@ -377,7 +385,7 @@ class DeepseekV3ForCausalLM(LlamaForCausalLM):
         return {"kv": (cfg.kv_lora_rank + cfg.qk_rope_head_dim,)}
 
     def _mla_attention(self, x, p, position_ids, segment_ids, attention_mask,
-                      inv_freq, rope_scale, kv_cache=None, cache_index=None):
+                      inv_freq, rope_scale, kv_cache=None):
         cfg = self.config
         B, S, H = x.shape
         Hq = cfg.num_attention_heads
@@ -409,9 +417,10 @@ class DeepseekV3ForCausalLM(LlamaForCausalLM):
         q_rope, k_rope = apply_rope(q_rope, k_rope, position_ids, inv_freq,
                                     attention_scaling=rope_scale)
 
-        if kv_cache is not None and hasattr(kv_cache, "at_layer"):
-            # Serving: the latent paged cache, attended in the absorbed
-            # form.  With W_kvb = [W_uk_i | W_uv_i] per head, the score
+        if kv_cache is not None and "kv" in kv_cache.pools:
+            # The cache holds the latent plane this family declares
+            # (``paged_cache_planes``): attend in the absorbed form.  With
+            # W_kvb = [W_uk_i | W_uv_i] per head, the score
             # q_nope_i . (c_kv W_uk_i) is (q_nope_i W_uk_i^T) . c_kv, and
             # sum_j p_ij (c_kv_j W_uv_i) is (sum_j p_ij c_kv_j) W_uv_i: the
             # cache holds c_kv and the rotated rope key and nothing per
@@ -442,35 +451,19 @@ class DeepseekV3ForCausalLM(LlamaForCausalLM):
         # the same); softmax(qk) @ padded-v leaves the pad zero — slice it.
         vh = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, dn + dr - dv))) \
             if dv != dn + dr else v
-        new_cache = None
+        state = None
         if kv_cache is not None:
-            # generate()'s dense dict cache: the EXPANDED per-head k /
-            # padded v (the parity oracle of the latent serving path above).
-            from automodel_tpu.ops.attention import cached_attention
-
-            k_cache = lax.dynamic_update_slice(
-                kv_cache["k"], kh.astype(kv_cache["k"].dtype),
-                (0, cache_index, 0, 0))
-            v_cache = lax.dynamic_update_slice(
-                kv_cache["v"], vh.astype(kv_cache["v"].dtype),
-                (0, cache_index, 0, 0))
-            new_cache = {"k": k_cache, "v": v_cache}
-            if S > 1:       # prefill attends only its own keys
-                out = attention(qh, kh, vh, causal=True,
-                                attention_mask=(
-                                    None if attention_mask is None
-                                    else attention_mask[:, :S]),
-                                scale=self._attn_scale)
-            else:
-                out = cached_attention(
-                    qh, k_cache, v_cache, cache_index=cache_index, q_len=S,
-                    attention_mask=attention_mask, scale=self._attn_scale)
+            # A cache of per-head planes (``generate()``'s dense one) holds
+            # the EXPANDED k / padded v: the parity oracle of the absorbed
+            # form above.
+            state = kv_cache.write(kh, vh)
+            out = kv_cache.attend(qh, state, scale=self._attn_scale)
         else:
             out = attention(qh, kh, vh, causal=True, segment_ids=segment_ids,
                             attention_mask=attention_mask,
                             scale=self._attn_scale)
         out = out[..., :dv]
-        return proj(out.reshape(B, S, Hq * dv), "o_proj"), new_cache
+        return proj(out.reshape(B, S, Hq * dv), "o_proj"), state
 
     def _dense_mlp(self, x, p, name="mlp"):
         cd = self.compute_dtype
@@ -572,8 +565,7 @@ class DeepseekV3ForCausalLM(LlamaForCausalLM):
         adapter_dropout: float = 0.0,
         adapter_dropout_position: str = "post",
         dropout_rng: Optional[jax.Array] = None,
-        kv_cache: Optional[Dict[str, jnp.ndarray]] = None,
-        cache_index: Optional[jnp.ndarray] = None,
+        kv_cache: Optional[Any] = None,
     ) -> Dict[str, jnp.ndarray]:
         cfg = self.config
         if adapters is not None:
@@ -581,136 +573,88 @@ class DeepseekV3ForCausalLM(LlamaForCausalLM):
                 "rank-r LoRA bypass is not wired for the MLA projections; "
                 "use peft merge mode")
         B, S = hidden.shape[:2]
-        decoding = kv_cache is not None
-        # The serving engine's paged view: its pools ride BOTH layer scans
-        # as carry (never xs/ys: see ``llama.forward_embeds``) and a layer
-        # stands at ONE index that runs across the two stacks.
-        paged = kv_cache if decoding and hasattr(kv_cache, "at_layer") \
-            else None
+        # A cache that holds the latent plane is the serving engine's: its
+        # step is decode-shaped, so the experts take the dropless dispatch
+        # and the step buffer's pad columns stay out of the routing.
+        serving = kv_cache is not None and "kv" in kv_cache.pools
         if position_ids is None:
-            start = 0 if cache_index is None else cache_index
-            position_ids = start + jnp.broadcast_to(
-                jnp.arange(S, dtype=jnp.int32), (B, S))
+            position_ids = default_position_ids(kv_cache, B, S)
         hidden = constrain(hidden.astype(self.compute_dtype),
                            ("act_batch", "act_seq", "act_embed"))
         inv_freq, rope_scale = self._rope_tables(position_ids)
-        valid = paged.valid_tokens() if paged is not None else None
+        valid = kv_cache.valid_tokens() if serving else None
 
-        def layer(h, p, moe: bool, cache, experts=None, at=None):
-            with jax.named_scope("attn"):
-                resid = h
-                x = rms_norm(h, p["input_layernorm"]["weight"],
-                             cfg.rms_norm_eps)
-                attn, new_cache = self._mla_attention(
-                    x, p["self_attn"], position_ids, segment_ids,
-                    attention_mask, inv_freq, rope_scale, kv_cache=cache,
-                    cache_index=cache_index)
-                h = resid + attn
-            with jax.named_scope("mlp"):
-                resid = h
-                x = rms_norm(h, p["post_attention_layernorm"]["weight"],
-                             cfg.rms_norm_eps)
-                counts = None
-                if not moe:
-                    with jax.named_scope("dense_mlp"):
-                        out = self._dense_mlp(x, p["mlp"])
-                elif paged is not None:
-                    out, counts = self._moe_mlp_serving(x, p["mlp"], valid,
-                                                        experts, at)
-                else:
-                    out = self._moe_mlp(x, p["mlp"])
-                out = constrain(resid + out, ("act_batch", "act_seq",
-                                              "act_embed"))
-            return out, new_cache, counts
+        def make_layer(moe: bool, first: int, experts):
+            def layer(h, p, _, idx, cache):
+                at = idx - first        # this layer's place in its stack
+                with jax.named_scope("attn"):
+                    resid = h
+                    x = rms_norm(h, p["input_layernorm"]["weight"],
+                                 cfg.rms_norm_eps)
+                    attn, state = self._mla_attention(
+                        x, p["self_attn"], position_ids, segment_ids,
+                        attention_mask, inv_freq, rope_scale, kv_cache=cache)
+                    h = resid + attn
+                with jax.named_scope("mlp"):
+                    resid = h
+                    x = rms_norm(h, p["post_attention_layernorm"]["weight"],
+                                 cfg.rms_norm_eps)
+                    counts = None
+                    if not moe:
+                        with jax.named_scope("dense_mlp"):
+                            out = self._dense_mlp(x, p["mlp"])
+                    elif serving:
+                        out, counts = self._moe_mlp_serving(
+                            x, p["mlp"], valid, experts, at)
+                    else:
+                        out = self._moe_mlp(x, p["mlp"])
+                    out = constrain(resid + out, ("act_batch", "act_seq",
+                                                  "act_embed"))
+                return out, state, counts
+            return layer
 
-        policy = resolve_remat_policy(self.remat_policy)
-        new_kv = {} if decoding else None
-        pools = paged.pools if paged is not None else None
-        expert_tokens = None
-        first = 0
+        # two homogeneous sub-stacks (their FFN params differ) under ONE
+        # layer index
+        stacks, first = [], 0
         for name, moe in (("dense_layers", False), ("layers", True)):
             if name not in params:
                 continue
             stack = params[name]
-            n = jax.tree.leaves(stack)[0].shape[0]
             experts = None
-            if paged is not None and moe:
+            if serving and moe:
                 # the expert stacks stay OUT of the scan's xs: the step
                 # slices one expert's matrices at (layer, expert) where it
                 # multiplies them (``decode_expert_ffn``)
                 experts = stack["mlp"]["experts"]
                 stack = dict(stack, mlp={k: v for k, v in stack["mlp"].items()
                                          if k != "experts"})
+            stacks.append(SubStack(stack, make_layer(moe, first, experts)))
+            first += jax.tree.leaves(stack)[0].shape[0]
+        hidden, cache_state, ys = scan_layers(
+            hidden, stacks, kv_cache, remat=self.remat,
+            remat_policy=self.remat_policy)
 
-            def body(carry, xs, moe=moe, first=first, experts=experts):
-                p, idx, cache = xs
-                if paged is not None:
-                    h, pl_ = carry
-                    h, pl_, counts = layer(h, p, moe,
-                                           paged.at_layer(pl_, idx),
-                                           experts, idx - first)
-                    return (h, pl_), counts
-                h, new_cache, _ = layer(carry, p, moe, cache)
-                return h, new_cache
-
-            if self.remat and not decoding:
-                body = jax.checkpoint(body, policy=policy, prevent_cse=False)
-            stack_cache = (kv_cache.get(name)
-                           if decoding and paged is None else None)
-            idx = first + jnp.arange(n, dtype=jnp.int32)
-            init = hidden if paged is None else (hidden, pools)
-            with jax.named_scope("layers"):
-                carry, ys = lax.scan(body, init, (stack, idx, stack_cache))
-            if paged is not None:
-                hidden, pools = carry
-                if moe:
-                    expert_tokens = ys
-            else:
-                hidden = carry
-                if decoding:
-                    new_kv[name] = ys
-            first += n
-
-        with jax.named_scope("final_norm"):
-            hidden = rms_norm(hidden, params["norm"]["weight"],
-                              cfg.rms_norm_eps)
-        with jax.named_scope("lm_head"):
-            lm_kernel = (params["embed_tokens"]["embedding"].T
-                         if cfg.tie_word_embeddings
-                         else params.get("lm_head", {}).get("kernel"))
-            if return_hidden:
-                out = {"hidden_states": hidden}
-                if lm_kernel is not None:
-                    out["lm_head_kernel"] = lm_kernel
-            else:
-                logits = hidden @ lm_kernel.astype(self.compute_dtype)
-                out = {"logits": constrain(
-                    logits, ("act_batch", "act_seq_nosp", "act_vocab"))}
-        if paged is not None:
-            out["kv_cache"] = pools
-            if expert_tokens is not None:
-                out["expert_tokens"] = expert_tokens
-        elif decoding:
-            out["kv_cache"] = new_kv
+        out = norm_and_head(
+            hidden, params,
+            lambda h, p: rms_norm(h, p["weight"], cfg.rms_norm_eps),
+            tied=cfg.tie_word_embeddings, compute_dtype=self.compute_dtype,
+            return_hidden=return_hidden)
+        if kv_cache is not None:
+            out["kv_cache"] = cache_state
+        if serving and "layers" in params:
+            out["expert_tokens"] = ys[-1]
         return out
 
     def init_kv_cache(self, batch: int, max_len: int,
                       dtype: Optional[Any] = None) -> Dict[str, Any]:
-        """Static decode cache per layer sub-stack: expanded per-head keys
-        ``[n, B, max_len, Hq, qk_head_dim]`` and v PADDED to the same head
-        dim (see ``_mla_attention``)."""
+        """The state of ``generate()``'s dense cache: EXPANDED per-head keys
+        ``[L, B, max_len, Hq, qk_head_dim]`` and v padded to the same head
+        dim (see ``_mla_attention``), one stack across both sub-stacks."""
         cfg = self.config
-        dtype = dtype or self.compute_dtype
-        kd = cfg.first_k_dense_replace
-        out: Dict[str, Any] = {}
-        for name, n in (("dense_layers", kd),
-                        ("layers", cfg.num_hidden_layers - kd)):
-            if n:
-                shape = (n, batch, max_len, cfg.num_attention_heads,
-                         cfg.qk_head_dim)
-                out[name] = {"k": jnp.zeros(shape, dtype),
-                             "v": jnp.zeros(shape, dtype)}
-        return out
+        return dense_kv_state(
+            cfg.num_hidden_layers, batch, max_len,
+            (cfg.num_attention_heads, cfg.qk_head_dim),
+            dtype or self.compute_dtype)
 
     def flops_per_token(self) -> float:
         cfg = self.config

@@ -30,6 +30,13 @@ import jax.numpy as jnp
 from jax import lax
 
 from automodel_tpu.distributed.shardings import constrain
+from automodel_tpu.models.layer_scan import (
+    SubStack,
+    default_position_ids,
+    dense_kv_state,
+    norm_and_head,
+    scan_layers,
+)
 from automodel_tpu.ops.attention import attention
 from automodel_tpu.ops.norms import rms_norm
 from automodel_tpu.ops.quant import maybe_qdot
@@ -182,7 +189,7 @@ class Gemma3ForCausalLM:
 
     # -- forward -----------------------------------------------------------
     def _layer(self, hidden, p, position_ids, segment_ids, attention_mask,
-               inv_freq, is_full, kv_cache=None, cache_index=None):
+               inv_freq, is_full, kv_cache=None):
         cfg = self.config
         B, S, H = hidden.shape
         D, Hq, Hk = cfg.head_dim, cfg.num_attention_heads, cfg.num_key_value_heads
@@ -209,7 +216,6 @@ class Gemma3ForCausalLM:
                          offset=1.0)
         q, k = apply_rope(q, k, position_ids, inv_freq)
         scale = float(cfg.query_pre_attn_scalar) ** -0.5
-        scale_ = scale
         soft_cap = cfg.attn_logit_softcapping
         sliding = int(cfg.sliding_window)
 
@@ -224,32 +230,14 @@ class Gemma3ForCausalLM:
                 lambda *ops: fn(*ops, local_window_size=sliding, **kwargs),
                 *operands)
 
-        new_cache = None
+        state = None
         if kv_cache is not None:
-            from automodel_tpu.ops.attention import cached_attention
-
-            k_cache = lax.dynamic_update_slice(
-                kv_cache["k"], k.astype(kv_cache["k"].dtype),
-                (0, cache_index, 0, 0))
-            v_cache = lax.dynamic_update_slice(
-                kv_cache["v"], v.astype(kv_cache["v"].dtype),
-                (0, cache_index, 0, 0))
-            new_cache = {"k": k_cache, "v": v_cache}
-            if S > 1:
-                attn = by_window(
-                    attention, q, k, v, causal=True, scale=scale_,
-                    logits_soft_cap=soft_cap,
-                    attention_mask=(None if attention_mask is None
-                                    else attention_mask[:, :S]))
-            else:
-                attn = by_window(
-                    cached_attention, q, k_cache, v_cache,
-                    cache_index=cache_index, q_len=S,
-                    attention_mask=attention_mask, scale=scale_,
-                    logits_soft_cap=soft_cap)
+            state = kv_cache.write(k, v)
+            attn = by_window(kv_cache.attend, q, state, scale=scale,
+                             logits_soft_cap=soft_cap)
         else:
             attn = by_window(
-                attention, q, k, v, causal=True, scale=scale_,
+                attention, q, k, v, causal=True, scale=scale,
                 logits_soft_cap=soft_cap,
                 segment_ids=segment_ids, attention_mask=attention_mask)
         attn = proj(attn.reshape(B, S, Hq * D), p["self_attn"]["o_proj"],
@@ -268,11 +256,11 @@ class Gemma3ForCausalLM:
         down = rms_norm(down, p["post_feedforward_layernorm"]["weight"], eps,
                         offset=1.0)
         out = constrain(resid + down, ("act_batch", "act_seq", "act_embed"))
-        return (out, new_cache) if kv_cache is not None else out
+        return out, state
 
     def __call__(self, params, input_ids, position_ids=None, segment_ids=None,
                  attention_mask=None, return_hidden: bool = False,
-                 kv_cache=None, cache_index=None) -> Dict[str, jnp.ndarray]:
+                 kv_cache=None) -> Dict[str, jnp.ndarray]:
         cfg = self.config
         hidden = params["embed_tokens"]["embedding"][input_ids].astype(
             self.compute_dtype)
@@ -283,20 +271,24 @@ class Gemma3ForCausalLM:
         return self.forward_embeds(
             params, hidden, position_ids=position_ids,
             segment_ids=segment_ids, attention_mask=attention_mask,
-            return_hidden=return_hidden, kv_cache=kv_cache,
-            cache_index=cache_index)
+            return_hidden=return_hidden, kv_cache=kv_cache)
 
     def forward_embeds(self, params, hidden, position_ids=None,
                        segment_ids=None, attention_mask=None,
-                       return_hidden: bool = False,
-                       kv_cache=None, cache_index=None
+                       return_hidden: bool = False, kv_cache=None
                        ) -> Dict[str, jnp.ndarray]:
         cfg = self.config
         B, S = hidden.shape[:2]
+        if return_hidden and cfg.final_logit_softcapping is not None:
+            # the fused hidden@lm_head loss path cannot apply the tanh
+            # cap — training would silently diverge from HF semantics
+            raise NotImplementedError(
+                "final_logit_softcapping (Gemma-2) is incompatible with "
+                "hidden-state losses (FusedLinearCrossEntropy): the cap "
+                "must apply to the full logits; use a logits loss "
+                "(e.g. MaskedCrossEntropy) for this family")
         if position_ids is None:
-            start = 0 if cache_index is None else cache_index
-            position_ids = start + jnp.broadcast_to(
-                jnp.arange(S, dtype=jnp.int32), (B, S))
+            position_ids = default_position_ids(kv_cache, B, S)
         hidden = constrain(hidden.astype(self.compute_dtype),
                            ("act_batch", "act_seq", "act_embed"))
 
@@ -306,59 +298,39 @@ class Gemma3ForCausalLM:
             is_full[:, None], jnp.asarray(self.inv_freq_global)[None],
             jnp.asarray(self.inv_freq_local)[None])       # [L, D/2]
 
-        decoding = kv_cache is not None
+        def layer(h, layer_params, xs, idx, cache):
+            inv_freq, full_flag = xs
+            h, state = self._layer(h, layer_params, position_ids,
+                                   segment_ids, attention_mask, inv_freq,
+                                   full_flag, kv_cache=cache)
+            return h, state, None
 
-        def body(h, xs):
-            layer_params, inv_freq, full_flag, cache = xs
-            out = self._layer(h, layer_params, position_ids, segment_ids,
-                              attention_mask, inv_freq, full_flag,
-                              kv_cache=cache, cache_index=cache_index)
-            if decoding:
-                return out
-            return out, None
+        hidden, cache_state, _ = scan_layers(
+            hidden, [SubStack(params["layers"], layer, (inv_freqs, is_full))],
+            kv_cache, remat=self.remat, remat_policy=self.remat_policy)
 
-        if self.remat and not decoding:
-            policy = None
-            if self.remat_policy and self.remat_policy != "none":
-                policy = getattr(jax.checkpoint_policies, self.remat_policy,
-                                 None)
-            body = jax.checkpoint(body, policy=policy, prevent_cse=False)
-        hidden, new_cache = lax.scan(
-            body, hidden, (params["layers"], inv_freqs, is_full, kv_cache))
-
-        hidden = rms_norm(hidden, params["norm"]["weight"],
-                          cfg.rms_norm_eps, offset=1.0)
-        lm_kernel = (params["embed_tokens"]["embedding"].T
-                     if cfg.tie_word_embeddings
-                     else params["lm_head"]["kernel"])
-        if return_hidden:
-            if cfg.final_logit_softcapping is not None:
-                # the fused hidden@lm_head loss path cannot apply the tanh
-                # cap — training would silently diverge from HF semantics
-                raise NotImplementedError(
-                    "final_logit_softcapping (Gemma-2) is incompatible with "
-                    "hidden-state losses (FusedLinearCrossEntropy): the cap "
-                    "must apply to the full logits; use a logits loss "
-                    "(e.g. MaskedCrossEntropy) for this family")
-            return {"hidden_states": hidden, "lm_head_kernel": lm_kernel}
-        logits = hidden @ lm_kernel.astype(self.compute_dtype)
-        if cfg.final_logit_softcapping is not None:
+        out = norm_and_head(
+            hidden, params,
+            lambda h, p: rms_norm(h, p["weight"], cfg.rms_norm_eps,
+                                  offset=1.0),
+            tied=cfg.tie_word_embeddings, compute_dtype=self.compute_dtype,
+            return_hidden=return_hidden)
+        if not return_hidden and cfg.final_logit_softcapping is not None:
             cap = jnp.asarray(cfg.final_logit_softcapping, jnp.float32)
-            logits = (jnp.tanh(logits.astype(jnp.float32) / cap)
-                      * cap).astype(logits.dtype)
-        out = {"logits": constrain(
-            logits, ("act_batch", "act_seq_nosp", "act_vocab"))}
-        if decoding:
-            out["kv_cache"] = new_cache
+            logits = out["logits"]
+            out["logits"] = (jnp.tanh(logits.astype(jnp.float32) / cap)
+                             * cap).astype(logits.dtype)
+        if kv_cache is not None:
+            out["kv_cache"] = cache_state
         return out
 
     def init_kv_cache(self, batch: int, max_len: int,
                       dtype: Optional[Any] = None) -> Dict[str, jnp.ndarray]:
         cfg = self.config
-        dtype = dtype or self.compute_dtype
-        shape = (cfg.num_hidden_layers, batch, max_len,
-                 cfg.num_key_value_heads, cfg.head_dim)
-        return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+        return dense_kv_state(
+            cfg.num_hidden_layers, batch, max_len,
+            (cfg.num_key_value_heads, cfg.head_dim),
+            dtype or self.compute_dtype)
 
     @property
     def num_params(self) -> int:
@@ -473,8 +445,8 @@ class Gemma3ForConditionalGeneration:
 
     def __call__(self, params, input_ids, pixel_values=None,
                  position_ids=None, segment_ids=None, attention_mask=None,
-                 return_hidden: bool = False, kv_cache=None,
-                 cache_index=None) -> Dict[str, jnp.ndarray]:
+                 return_hidden: bool = False,
+                 kv_cache=None) -> Dict[str, jnp.ndarray]:
         cfg = self.config
         lm, lp = self.language_model, params["language_model"]
         B, S = input_ids.shape
@@ -496,7 +468,7 @@ class Gemma3ForConditionalGeneration:
         return lm.forward_embeds(
             lp, embeds, position_ids=position_ids, segment_ids=segment_ids,
             attention_mask=attention_mask, return_hidden=return_hidden,
-            kv_cache=kv_cache, cache_index=cache_index)
+            kv_cache=kv_cache)
 
     def init_kv_cache(self, batch: int, max_len: int, dtype=None):
         return self.language_model.init_kv_cache(batch, max_len, dtype)

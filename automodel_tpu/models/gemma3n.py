@@ -54,6 +54,7 @@ import numpy as np
 from jax import lax
 
 from automodel_tpu.distributed.shardings import constrain
+from automodel_tpu.models.layer_scan import SubStack, scan_layers
 from automodel_tpu.ops.attention import attention
 from automodel_tpu.ops.rotary import apply_rope, rope_frequencies
 
@@ -455,13 +456,12 @@ class Gemma3nForCausalLM:
 
     def __call__(self, params, input_ids, position_ids=None, segment_ids=None,
                  attention_mask=None, return_hidden: bool = False,
-                 kv_cache=None, cache_index=None) -> Dict[str, jnp.ndarray]:
+                 kv_cache=None) -> Dict[str, jnp.ndarray]:
         cfg = self.config
         cd = self.compute_dtype
         B, S = input_ids.shape
         if position_ids is None:
-            start = 0 if cache_index is None else cache_index
-            position_ids = start + jnp.broadcast_to(
+            position_ids = jnp.broadcast_to(
                 jnp.arange(S, dtype=jnp.int32), (B, S))
         if kv_cache is not None:
             raise NotImplementedError(
@@ -505,20 +505,15 @@ class Gemma3nForCausalLM:
         std_mult = jnp.asarray(self._std_mult)
         sparse = jnp.asarray(self._sparse_flag)
 
-        def body(h, xs):
-            return self._layer(h, xs, position_ids, segment_ids,
-                               attention_mask), None
+        def layer(h, p, xs, idx, cache):
+            return self._layer(h, (p, *xs), position_ids, segment_ids,
+                               attention_mask), None, None
 
-        if self.remat:
-            policy = None
-            if self.remat_policy and self.remat_policy != "none":
-                policy = getattr(jax.checkpoint_policies, self.remat_policy,
-                                 None)
-            body = jax.checkpoint(body, policy=policy, prevent_cse=False)
-        h, _ = lax.scan(
-            body, h,
-            (params["layers"], per_layer_l, inv_freqs, is_full, std_mult,
-             sparse))
+        h, _, _ = scan_layers(
+            h, [SubStack(params["layers"], layer,
+                         (per_layer_l, inv_freqs, is_full, std_mult,
+                          sparse))],
+            remat=self.remat, remat_policy=self.remat_policy)
         hidden = self._merge_streams(
             h, params["altup_unembed_projections"]["kernel"])
         hidden = _rms_norm(hidden, params["norm"]["weight"],
@@ -812,13 +807,13 @@ class Gemma3nForConditionalGeneration:
     def __call__(self, params, input_ids, pixel_values=None,
                  position_ids=None, segment_ids=None, attention_mask=None,
                  return_hidden: bool = False,
-                 kv_cache=None, cache_index=None) -> Dict[str, jnp.ndarray]:
+                 kv_cache=None) -> Dict[str, jnp.ndarray]:
         cfg = self.config
         tc = cfg.text_config
         cd = self.compute_dtype
         lp = params["language_model"]
         B, S = input_ids.shape
-        if kv_cache is not None or cache_index is not None:
+        if kv_cache is not None:
             raise NotImplementedError(
                 "gemma3n decode uses the cacheless forward (see the KV "
                 "sharing note in the module docstring); generation runs "

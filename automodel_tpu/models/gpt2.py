@@ -14,9 +14,9 @@ from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 
 from automodel_tpu.distributed.shardings import constrain
+from automodel_tpu.models.layer_scan import SubStack, scan_layers
 from automodel_tpu.ops.attention import attention
 from automodel_tpu.ops.norms import layer_norm
 
@@ -147,12 +147,11 @@ class GPT2LMHeadModel:
         ).astype(self.compute_dtype)
         hidden = constrain(hidden, ("act_batch", "act_seq", "act_embed"))
 
-        def body(h, p):
-            return self._block(h, p, segment_ids, attention_mask), None
+        def layer(h, p, _, idx, cache):
+            return self._block(h, p, segment_ids, attention_mask), None, None
 
-        if self.remat:
-            body = jax.checkpoint(body, prevent_cse=False)
-        hidden, _ = lax.scan(body, hidden, params["h"])
+        hidden, _, _ = scan_layers(hidden, [SubStack(params["h"], layer)],
+                                   remat=self.remat, remat_policy=None)
         hidden = layer_norm(hidden, params["ln_f"]["weight"], params["ln_f"]["bias"],
                             cfg.layer_norm_epsilon)
         lm_kernel = (

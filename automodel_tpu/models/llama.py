@@ -21,15 +21,21 @@ from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 
 import zlib
 
 from automodel_tpu.distributed.shardings import constrain
+from automodel_tpu.models.layer_scan import (
+    SubStack,
+    default_position_ids,
+    dense_kv_state,
+    norm_and_head,
+    scan_layers,
+)
 from automodel_tpu.ops.attention import attention
 from automodel_tpu.ops.norms import rms_norm
 from automodel_tpu.ops.quant import maybe_qdot
-from automodel_tpu.ops.remat import checkpoint_name, resolve_remat_policy
+from automodel_tpu.ops.remat import checkpoint_name
 from automodel_tpu.ops.rotary import apply_rope, rope_parameters
 
 
@@ -91,8 +97,8 @@ class LlamaConfig:
 
 
 def llama3_2_1b_config() -> "LlamaConfig":
-    """The Llama-3.2-1B shape — the BASELINE.md north-star benchmark config,
-    shared by ``bench.py`` and ``__graft_entry__.py``."""
+    """The Llama-3.2-1B shape — the BASELINE.md north-star config, which
+    ``__graft_entry__.py`` runs."""
     return LlamaConfig(
         vocab_size=128256, hidden_size=2048, intermediate_size=8192,
         num_hidden_layers=16, num_attention_heads=32, num_key_value_heads=8,
@@ -428,54 +434,21 @@ class LlamaForCausalLM:
         return proj
 
     def _attention_core(self, q, k, v, segment_ids, attention_mask,
-                        kv_cache, cache_index, local_window_size=None):
+                        kv_cache, local_window_size=None):
         """Train/prefill/decode attention + cache update on rotated q/k."""
-        S = q.shape[1]
         scale = self._attn_softmax_scale
-        if kv_cache is not None and hasattr(kv_cache, "at_layer"):
-            # Serving path: a block-paged cache view (duck-typed so models
-            # never import the serving layer — see
-            # ``serving/kv_cache.PagedKVView``) standing at this layer of
-            # the stacked pools.  Write this step's k/v into the layer's
-            # slots, then attend the paged history through the
-            # ``attention.paged_decode`` kernel chain; chunked prefill
-            # (S > 1) attends earlier chunks via the same block tables.
-            # What comes back as the new cache is the stacked pools.
+        if kv_cache is not None:
+            # Decoding: the cache (``models/layer_scan.py`` has the
+            # protocol) stands at this layer of its stacked state.  Write
+            # this step's k/v, then attend what the cache holds; the state
+            # that comes back is what the layer scan carries on.
             with jax.named_scope("kv_write"):
-                new_pools = kv_cache.write(k, v)
+                state = kv_cache.write(k, v)
             with jax.named_scope("attn_core"):
                 attn = kv_cache.attend(
-                    q, new_pools, scale=scale,
+                    q, state, scale=scale,
                     local_window_size=local_window_size)
-            return attn, new_pools
-        if kv_cache is not None:
-            # Autoregressive decode: write this step's k/v into the static
-            # [B, S_max, Hk, D] cache.  Prefill (S > 1) attends only over
-            # its own S keys — attending the full cache would double the
-            # attention FLOPs/memory on positions the causal mask forbids
-            # anyway; decode steps (S == 1) attend the cache.
-            from automodel_tpu.ops.attention import cached_attention
-
-            k_cache = lax.dynamic_update_slice(
-                kv_cache["k"], k.astype(kv_cache["k"].dtype),
-                (0, cache_index, 0, 0))
-            v_cache = lax.dynamic_update_slice(
-                kv_cache["v"], v.astype(kv_cache["v"].dtype),
-                (0, cache_index, 0, 0))
-            new_cache = {"k": k_cache, "v": v_cache}
-            if S > 1:
-                attn = attention(
-                    q, k, v, causal=True,
-                    attention_mask=(None if attention_mask is None
-                                    else attention_mask[:, :S]),
-                    scale=scale, local_window_size=local_window_size)
-            else:
-                attn = cached_attention(
-                    q, k_cache, v_cache,
-                    cache_index=cache_index, q_len=S,
-                    attention_mask=attention_mask,
-                    scale=scale, local_window_size=local_window_size)
-            return attn, new_cache
+            return attn, state
         with jax.named_scope("attn_core"):
             attn = attention(
                 q, k, v,
@@ -491,8 +464,7 @@ class LlamaForCausalLM:
                        attention_mask, inv_freq, adapters=None,
                        adapter_scale=1.0, adapter_dropout=0.0,
                        dropout_position="post", dropout_rng=None,
-                       kv_cache=None, cache_index=None, rope_scale=1.0,
-                       adapter_ids=None):
+                       kv_cache=None, rope_scale=1.0, adapter_ids=None):
         cfg = self.config
         B, S, H = hidden.shape
         D, Hq, Hk = cfg.head_dim, cfg.num_attention_heads, cfg.num_key_value_heads
@@ -515,7 +487,7 @@ class LlamaForCausalLM:
                 k = rms_norm(k, p["self_attn"]["k_norm"]["weight"], cfg.rms_norm_eps)
             q, k = self._apply_rope(q, k, position_ids, inv_freq, rope_scale)
             attn, new_cache = self._attention_core(
-                q, k, v, segment_ids, attention_mask, kv_cache, cache_index,
+                q, k, v, segment_ids, attention_mask, kv_cache,
                 local_window_size=self._sliding_window)
             attn = checkpoint_name(attn, "attn_core")
             attn = proj(attn.reshape(B, S, Hq * D), p["self_attn"]["o_proj"],
@@ -566,8 +538,7 @@ class LlamaForCausalLM:
         adapter_dropout: float = 0.0,
         adapter_dropout_position: str = "post",
         dropout_rng: Optional[jax.Array] = None,
-        kv_cache: Optional[Dict[str, jnp.ndarray]] = None,
-        cache_index: Optional[jnp.ndarray] = None,
+        kv_cache: Optional[Any] = None,
         adapter_ids: Optional[jnp.ndarray] = None,
     ) -> Dict[str, jnp.ndarray]:
         """Forward pass. Returns ``{"logits": ...}`` or, with ``return_hidden``,
@@ -582,9 +553,10 @@ class LlamaForCausalLM:
         [L, E, r, out]}`` and routes each batch row via ``adapter_ids``
         (``[B]`` int32, 0 = base model) — see ``serving/adapters.py``.
 
-        ``kv_cache``/``cache_index``: autoregressive decode (see
-        ``automodel_tpu/generation``) — the result carries the updated cache
-        under ``"kv_cache"``."""
+        ``kv_cache``: a decode cache view (``generation.DenseKVView``, the
+        serving engine's ``PagedKVView``; the protocol is in
+        ``models/layer_scan.py``) — the result carries the cache's new
+        state under ``"kv_cache"``."""
         with jax.named_scope("embed"):
             hidden = params["embed_tokens"]["embedding"][input_ids].astype(
                 self.compute_dtype)
@@ -600,8 +572,7 @@ class LlamaForCausalLM:
             return_hidden=return_hidden, adapters=adapters,
             adapter_scale=adapter_scale, adapter_dropout=adapter_dropout,
             adapter_dropout_position=adapter_dropout_position,
-            dropout_rng=dropout_rng, kv_cache=kv_cache,
-            cache_index=cache_index, **extra)
+            dropout_rng=dropout_rng, kv_cache=kv_cache, **extra)
 
     def paged_cache_planes(self) -> Dict[str, Any]:
         """What the serving engine's paged pools hold per token and layer,
@@ -613,12 +584,13 @@ class LlamaForCausalLM:
 
     def init_kv_cache(self, batch: int, max_len: int,
                       dtype: Optional[Any] = None) -> Dict[str, jnp.ndarray]:
-        """Static-shape decode cache: ``{"k"|"v": [L, B, max_len, Hk, D]}``."""
+        """The state of ``generate()``'s dense cache:
+        ``{"k"|"v": [L, B, max_len, Hk, D]}``."""
         cfg = self.config
-        dtype = dtype or self.compute_dtype
-        shape = (cfg.num_hidden_layers, batch, max_len,
-                 cfg.num_key_value_heads, cfg.head_dim)
-        return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+        return dense_kv_state(
+            cfg.num_hidden_layers, batch, max_len,
+            (cfg.num_key_value_heads, cfg.head_dim),
+            dtype or self.compute_dtype)
 
     def forward_embeds(
         self,
@@ -633,8 +605,7 @@ class LlamaForCausalLM:
         adapter_dropout: float = 0.0,
         adapter_dropout_position: str = "post",
         dropout_rng: Optional[jax.Array] = None,
-        kv_cache: Optional[Dict[str, jnp.ndarray]] = None,
-        cache_index: Optional[jnp.ndarray] = None,
+        kv_cache: Optional[Any] = None,
         adapter_ids: Optional[jnp.ndarray] = None,
     ) -> Dict[str, jnp.ndarray]:
         """Forward from input embeddings — the VLM path (image features
@@ -642,9 +613,7 @@ class LlamaForCausalLM:
         cfg = self.config
         B, S = hidden.shape[:2]
         if position_ids is None:
-            start = 0 if cache_index is None else cache_index
-            position_ids = start + jnp.broadcast_to(
-                jnp.arange(S, dtype=jnp.int32), (B, S))
+            position_ids = default_position_ids(kv_cache, B, S)
         hidden = constrain(hidden.astype(self.compute_dtype),
                            ("act_batch", "act_seq", "act_embed"))
         inv_freq, rope_scale = self._rope_tables(position_ids)
@@ -656,122 +625,36 @@ class LlamaForCausalLM:
             layer_adapters = {
                 k[len("layers."):]: v for k, v in adapters.items()
                 if k.startswith("layers.")}
-        layer_idx = jnp.arange(cfg.num_hidden_layers, dtype=jnp.int32)
+        # Grouped multi-LoRA routing only exists on models whose
+        # _decoder_layer takes adapter_ids; subclasses that override it
+        # (olmo2, phi4_mm) never see the kwarg unless it's armed.
+        extra = {} if adapter_ids is None else {"adapter_ids": adapter_ids}
 
-        decoding = kv_cache is not None
-        # Paged serving cache: the stacked [L, ...] pools ride the layer
-        # scan as CARRY beside the hidden state, and each layer writes and
-        # reads them in place at its own index.  As xs/ys a scan would
-        # slice one layer of pool out and stack one back into a fresh
-        # buffer per layer (and XLA would copy the donated pools whole to
-        # keep them readable meanwhile).  The addressing arrays (block
-        # tables, slot mapping, context lengths) are layer-invariant and
-        # close over the scan body.  The returned "kv_cache" is the carried
-        # pools dict.  The dense dict cache keeps its xs/ys.
-        paged_view = kv_cache if (decoding
-                                  and hasattr(kv_cache, "at_layer")) \
-            else None
-        cache_xs = None if paged_view is not None else kv_cache
-
-        def one_layer(carry, xs):
-            layer_params, ad, idx, cache = xs
-            if paged_view is not None:
-                h, pools = carry
-                cache = paged_view.at_layer(pools, idx)
-            else:
-                h = carry
+        def layer(h, layer_params, ad, idx, cache):
             rng = (jax.random.fold_in(dropout_rng, idx)
                    if dropout_rng is not None else None)
-            # Grouped multi-LoRA routing only exists on models whose
-            # _decoder_layer takes adapter_ids; subclasses that override it
-            # (olmo2, phi4_mm) never see the kwarg unless it's armed.
-            extra = {} if adapter_ids is None else {"adapter_ids": adapter_ids}
-            h, new_cache, aux = self._decoder_layer(
+            return self._decoder_layer(
                 h, layer_params, position_ids, segment_ids, attention_mask,
                 inv_freq, adapters=ad, adapter_scale=adapter_scale,
                 adapter_dropout=adapter_dropout,
                 dropout_position=adapter_dropout_position, dropout_rng=rng,
-                kv_cache=cache, cache_index=cache_index,
-                rope_scale=rope_scale, **extra,
-            )
-            if paged_view is not None:
-                return (h, new_cache), (None, aux)
-            return h, (new_cache, aux)
+                kv_cache=cache, rope_scale=rope_scale, **extra)
 
-        L = cfg.num_hidden_layers
-        if self.scan_block < 1:
-            raise ValueError(f"model.scan_block must be >= 1, got "
-                             f"{self.scan_block}")
-        if self.scan_block > 1 and L % self.scan_block:
-            raise ValueError(
-                f"model.scan_block={self.scan_block} must divide "
-                f"num_hidden_layers={L}")
-        block = self.scan_block if not decoding else 1
-        if block == 1:
-            body = one_layer
-        else:
-            # Scan over L/block groups; the body runs `block` layers.  Only
-            # the group-boundary hidden state is carried/stacked, so the
-            # scan's saved-residual memory shrinks by `block` while the
-            # backward recomputes a block-sized window.
-            def body(h, xs):
-                ys = []
-                for i in range(block):
-                    h, y = one_layer(h, jax.tree.map(lambda a: a[i], xs))
-                    ys.append(y)
-                return h, jax.tree.map(lambda *a: jnp.stack(a), *ys)
+        hidden, cache_state, (aux_losses,) = scan_layers(
+            hidden, [SubStack(params["layers"], layer, layer_adapters)],
+            kv_cache, remat=self.remat, remat_policy=self.remat_policy,
+            scan_block=self.scan_block, scan_unroll=self.scan_unroll)
 
-        if self.remat and not decoding:
-            body = jax.checkpoint(
-                body, policy=resolve_remat_policy(self.remat_policy),
-                prevent_cse=False)
-        xs = (params["layers"], layer_adapters, layer_idx, cache_xs)
-        if block > 1:
-            xs = jax.tree.map(
-                lambda a: a.reshape(L // block, block, *a.shape[1:]), xs)
-        init = hidden if paged_view is None else (hidden, paged_view.pools)
-        with jax.named_scope("layers"):
-            carry, (new_cache, aux_losses) = lax.scan(
-                body, init, xs, unroll=self.scan_unroll)
-        if paged_view is None:
-            hidden = carry
-        else:
-            hidden, new_cache = carry
-        if block > 1 and (new_cache is not None or aux_losses is not None):
-            # ys come back [L/block, block, ...] -> flatten to [L, ...]
-            new_cache, aux_losses = jax.tree.map(
-                lambda a: a.reshape(L, *a.shape[2:]), (new_cache, aux_losses))
-
-        with jax.named_scope("final_norm"):
-            hidden = self._norm(hidden, params["norm"], cfg.rms_norm_eps)
-        with jax.named_scope("lm_head"):
-            lm_kernel = (
-                params["embed_tokens"]["embedding"].T
-                if cfg.tie_word_embeddings
-                # headless backbones (sequence classification) have no
-                # lm_head
-                else params.get("lm_head", {}).get("kernel")
-            )
-            if return_hidden:
-                out = {"hidden_states": hidden}
-                if lm_kernel is not None:
-                    if self._logits_divisor != 1.0:
-                        # fold the divisor into the head so the fused-CE
-                        # path sees the scaled logits too
-                        lm_kernel = lm_kernel / jnp.asarray(
-                            self._logits_divisor, lm_kernel.dtype)
-                    out["lm_head_kernel"] = lm_kernel
-            else:
-                logits = hidden @ lm_kernel.astype(self.compute_dtype)
-                if self._logits_divisor != 1.0:
-                    logits = logits / jnp.asarray(self._logits_divisor,
-                                                  logits.dtype)
-                out = {"logits": constrain(
-                    logits, ("act_batch", "act_seq_nosp", "act_vocab"))}
+        out = norm_and_head(
+            hidden, params,
+            lambda h, p: self._norm(h, p, cfg.rms_norm_eps),
+            tied=cfg.tie_word_embeddings, compute_dtype=self.compute_dtype,
+            return_hidden=return_hidden,
+            logits_divisor=self._logits_divisor)
         if aux_losses is not None:
             out["aux_loss"] = self._combine_aux(aux_losses)
-        if decoding:
-            out["kv_cache"] = new_cache
+        if kv_cache is not None:
+            out["kv_cache"] = cache_state
         return out
 
     @property

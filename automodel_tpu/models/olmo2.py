@@ -67,7 +67,7 @@ class Olmo2ForCausalLM(LlamaForCausalLM):
                        attention_mask, inv_freq, adapters=None,
                        adapter_scale=1.0, adapter_dropout=0.0,
                        dropout_position="post", dropout_rng=None,
-                       kv_cache=None, cache_index=None, rope_scale=1.0):
+                       kv_cache=None, rope_scale=1.0):
         cfg = self.config
         B, S, H = hidden.shape
         D, Hq, Hk = cfg.head_dim, cfg.num_attention_heads, cfg.num_key_value_heads
@@ -91,7 +91,7 @@ class Olmo2ForCausalLM(LlamaForCausalLM):
             v = v.reshape(B, S, Hk, D)
             q, k = self._apply_rope(q, k, position_ids, inv_freq, rope_scale)
             attn, new_cache = self._attention_core(
-                q, k, v, segment_ids, attention_mask, kv_cache, cache_index)
+                q, k, v, segment_ids, attention_mask, kv_cache)
             attn = checkpoint_name(attn, "attn_core")
             attn = proj(attn.reshape(B, S, Hq * D), p["self_attn"]["o_proj"],
                         "self_attn.o_proj")

@@ -448,7 +448,7 @@ class Phi4MMTextModel(LlamaForCausalLM):
                        attention_mask, inv_freq, adapters=None,
                        adapter_scale=1.0, adapter_dropout=0.0,
                        dropout_position="post", dropout_rng=None,
-                       kv_cache=None, cache_index=None, rope_scale=1.0):
+                       kv_cache=None, rope_scale=1.0):
         cfg = self.config
         B, S, H = hidden.shape
         D, Hq, Hk = cfg.head_dim, cfg.num_attention_heads, cfg.num_key_value_heads
@@ -476,7 +476,7 @@ class Phi4MMTextModel(LlamaForCausalLM):
         v = qkv[..., (Hq + Hk) * D:].reshape(B, S, Hk, D)
         q, k = self._apply_rope(q, k, position_ids, inv_freq, rope_scale)
         attn, new_cache = self._attention_core(
-            q, k, v, segment_ids, attention_mask, kv_cache, cache_index,
+            q, k, v, segment_ids, attention_mask, kv_cache,
             local_window_size=self._sliding_window)
         attn = maybe_qdot(attn.reshape(B, S, Hq * D),
                           p["self_attn"]["o_proj"]["kernel"].astype(cd),
@@ -575,8 +575,8 @@ class Phi4MMForCausalLM:
     def __call__(self, params, input_ids, input_audio_embeds=None,
                  audio_embed_sizes=None, audio_attention_mask=None,
                  position_ids=None, segment_ids=None, attention_mask=None,
-                 return_hidden: bool = False, kv_cache=None,
-                 cache_index=None) -> Dict[str, jnp.ndarray]:
+                 return_hidden: bool = False,
+                 kv_cache=None) -> Dict[str, jnp.ndarray]:
         lm = self.language_model
         lp = params["language_model"]
         B, S = input_ids.shape
@@ -605,7 +605,7 @@ class Phi4MMForCausalLM:
         return lm.forward_embeds(
             lp, embeds, position_ids=position_ids, segment_ids=segment_ids,
             attention_mask=attention_mask, return_hidden=return_hidden,
-            kv_cache=kv_cache, cache_index=cache_index)
+            kv_cache=kv_cache)
 
     @property
     def checkpoint_dir(self):

@@ -507,7 +507,7 @@ class Qwen25VLForConditionalGeneration:
                  image_grid_thw=None, pixel_values_videos=None,
                  video_grid_thw=None, position_ids=None, segment_ids=None,
                  attention_mask=None, return_hidden: bool = False,
-                 kv_cache=None, cache_index=None) -> Dict[str, jnp.ndarray]:
+                 kv_cache=None) -> Dict[str, jnp.ndarray]:
         lm = self.language_model
         lp = params["language_model"]
         B, S = input_ids.shape
@@ -538,7 +538,7 @@ class Qwen25VLForConditionalGeneration:
         return lm.forward_embeds(
             lp, embeds, position_ids=position_ids, segment_ids=segment_ids,
             attention_mask=attention_mask, return_hidden=return_hidden,
-            kv_cache=kv_cache, cache_index=cache_index)
+            kv_cache=kv_cache)
 
     @property
     def checkpoint_dir(self):

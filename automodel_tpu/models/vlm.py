@@ -148,8 +148,7 @@ class VLMForConditionalGeneration:
         segment_ids: Optional[jnp.ndarray] = None,
         attention_mask: Optional[jnp.ndarray] = None,
         return_hidden: bool = False,
-        kv_cache: Optional[Dict[str, jnp.ndarray]] = None,
-        cache_index: Optional[jnp.ndarray] = None,
+        kv_cache: Optional[Any] = None,
     ) -> Dict[str, jnp.ndarray]:
         lm = self.language_model
         lp = params["language_model"]
@@ -167,7 +166,7 @@ class VLMForConditionalGeneration:
             lp, embeds,
             position_ids=position_ids, segment_ids=segment_ids,
             attention_mask=attention_mask, return_hidden=return_hidden,
-            kv_cache=kv_cache, cache_index=cache_index)
+            kv_cache=kv_cache)
 
     def flops_per_token(self) -> float:
         return self.language_model.flops_per_token()

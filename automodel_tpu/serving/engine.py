@@ -256,7 +256,7 @@ def _paged_step(model, block_size: int, quantized: bool, cow_enabled: bool,
     and greedy-pick EVERY column's next token.  Returns ``(greedy [B, W],
     last_logits [B, V], pools)`` and, from a model with routed expert
     layers, ``expert_tokens [n_moe_layers, held]``.  The pools are donated
-    and come back as the layer scan's carry (``models/llama.py::forward_embeds``), so the
+    and come back as the layer scan's carry (``models/layer_scan.py::scan_layers``), so the
     cache updates in place.  Plain decode reads its one token at its last
     valid column of ``greedy``; the speculative verify reads the argmax at
     each draft position from the same array — the per-column argmax IS the

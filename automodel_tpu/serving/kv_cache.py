@@ -1,8 +1,9 @@
 """Block-paged KV cache: static pools, a host-side block allocator, and
 the pytree view the model's attention core consumes.
 
-The dense decode cache (``model.init_kv_cache``) reserves ``[B, S_max]``
-rows per request — at serving batch sizes that is almost entirely dead HBM
+The dense decode cache (``generation/dense_kv.DenseKVView``, the other
+implementation of the cache protocol in ``models/layer_scan.py``) reserves
+``[B, S_max]`` rows per request — at serving batch sizes that is almost entirely dead HBM
 (most requests are far shorter than the max).  The paged cache instead
 keeps ONE static pool of fixed-size blocks per layer, stacked over the
 layers into one buffer the step donates and updates in place,
@@ -450,8 +451,8 @@ class PagedKVView:
     addressing arrays and the layer the view stands at, with the layout
     facts (block size, quantization) as static aux data.
 
-    ``forward_embeds`` carries the pools through its layer scan (the
-    loop's CARRY, never its ``xs``/``ys``: a scan slices its ``xs`` and
+    ``models/layer_scan.scan_layers`` carries the pools through every
+    layer scan (the loop's CARRY, never its ``xs``/``ys``: a scan slices its ``xs`` and
     stacks its ``ys`` into a new buffer, one layer of pool out and one in
     per layer) and closes over the addressing arrays, which every layer
     shares; inside the body :meth:`at_layer` rewraps the carried pools
@@ -483,7 +484,7 @@ class PagedKVView:
         carries them) standing at ``layer``."""
         return dataclasses.replace(self, pools=pools, layer=layer)
 
-    # -- the model-facing seam (llama._attention_core's paged branch) ------
+    # -- the model-facing seam (the cache protocol of models/layer_scan.py) --
     def write(self, k: jnp.ndarray, v: jnp.ndarray) -> Dict[str, jnp.ndarray]:
         """Scatter this step's ``[B, S, Hk, D]`` k/v into the view's layer
         of the stacked pools — flat slot ``layer * NB * BS + slot_mapping``
@@ -545,7 +546,7 @@ class PagedKVView:
         busy = self.block_tables[:, :1] != 0
         return (jnp.arange(pos.shape[1], dtype=pos.dtype)[None, :] < held) & busy
 
-    # -- the latent seam (deepseek_v3._mla_attention's paged branch) --------
+    # -- the latent plane's pair (deepseek_v3._mla_attention, absorbed form) --
     def write_latent(self, latent: jnp.ndarray) -> Dict[str, jnp.ndarray]:
         """Scatter this step's ``[B, S, R]`` latent rows (MLA: the normalised
         ``c_kv`` and the rotated rope key, side by side) into the view's

@@ -260,21 +260,18 @@ def run_stage_layers(model, slab_params, hidden, position_ids, segment_ids,
                      attention_mask):
     """One stage's local ``L/pp`` layer scan over ``hidden`` [B_mb, S, H].
 
-    ``slab_params`` is the stage's layer slab (leading dim ``L/pp``); remat
-    applies exactly as in the stock forward (``model.remat`` /
-    ``remat_policy``, with ``model.scan_block`` layers per checkpointed
-    block — the pp path must not silently grow saved-residual memory by
-    ``scan_block``x vs the dense step).  MoE aux losses are rejected at
-    trace time — the pipelined loss has no cross-stage aux combination.
+    ``slab_params`` is the stage's layer slab (leading dim ``L/pp``); the
+    loop is the stock forward's (``models/layer_scan.scan_layers``: remat
+    and ``model.scan_block`` grouping apply per stage as they do there, so
+    the pp path cannot silently grow saved-residual memory).  MoE aux
+    losses are rejected at trace time — the pipelined loss has no
+    cross-stage aux combination.
     """
-    import jax
-    from jax import lax
-
-    from automodel_tpu.ops.remat import resolve_remat_policy
+    from automodel_tpu.models.layer_scan import SubStack, scan_layers
 
     inv_freq, rope_scale = model._rope_tables(position_ids)
 
-    def one_layer(h, layer_params):
+    def layer(h, layer_params, _, idx, cache):
         h, _, aux = model._decoder_layer(
             h, layer_params, position_ids, segment_ids, attention_mask,
             inv_freq, rope_scale=rope_scale)
@@ -284,51 +281,24 @@ def run_stage_layers(model, slab_params, hidden, position_ids, segment_ids,
                 "per-layer aux loss (MoE load balancing) — combining aux "
                 "terms across pipeline stages is not wired; use pp_size 1 "
                 "for MoE families.")
-        return h, None
+        return h, None, None
 
-    l_local = jax.tree.leaves(slab_params)[0].shape[0]
-    block = model.scan_block
-    if block > 1 and l_local % block:
-        raise ValueError(
-            f"pipeline: model.scan_block={block} must divide the per-stage "
-            f"layer slab L/pp={l_local} (num_hidden_layers / pp_size) — "
-            "shrink scan_block or change pp_size")
-    if block == 1:
-        body, xs = one_layer, slab_params
-    else:
-        # mirror the stock forward's block grouping: only group-boundary
-        # hidden states are carried/saved, the backward recomputes a
-        # block-sized window (models/llama.py::forward_embeds)
-        def body(h, xs_block):
-            for i in range(block):
-                h, _ = one_layer(h, jax.tree.map(lambda a: a[i], xs_block))
-            return h, None
-
-        xs = jax.tree.map(
-            lambda a: a.reshape(l_local // block, block, *a.shape[1:]),
-            slab_params)
-    if model.remat:
-        body = jax.checkpoint(
-            body, policy=resolve_remat_policy(model.remat_policy),
-            prevent_cse=False)
-    hidden, _ = lax.scan(body, hidden, xs, unroll=model.scan_unroll)
+    hidden, _, _ = scan_layers(
+        hidden, [SubStack(slab_params, layer)], remat=model.remat,
+        remat_policy=model.remat_policy, scan_block=model.scan_block,
+        scan_unroll=model.scan_unroll)
     return hidden
 
 
 def stage_head_loss(model, loss_fn, params, hidden, labels):
-    """Last stage's exit: final norm + lm head + sum-CE — byte-for-byte the
-    tail of the stock forward followed by the dense step's loss call."""
-    import jax.numpy as jnp
-
-    from automodel_tpu.distributed.shardings import constrain
+    """Last stage's exit: the stock forward's final norm + lm head
+    (``models/layer_scan.norm_and_head``), then the dense step's loss
+    call."""
+    from automodel_tpu.models.layer_scan import norm_and_head
 
     cfg = model.config
-    hidden = model._norm(hidden, params["norm"], cfg.rms_norm_eps)
-    lm_kernel = (params["embed_tokens"]["embedding"].T
-                 if cfg.tie_word_embeddings
-                 else params["lm_head"]["kernel"])
-    logits = hidden @ lm_kernel.astype(model.compute_dtype)
-    if model._logits_divisor != 1.0:
-        logits = logits / jnp.asarray(model._logits_divisor, logits.dtype)
-    logits = constrain(logits, ("act_batch", "act_seq_nosp", "act_vocab"))
-    return loss_fn(logits, labels)
+    out = norm_and_head(
+        hidden, params, lambda h, p: model._norm(h, p, cfg.rms_norm_eps),
+        tied=cfg.tie_word_embeddings, compute_dtype=model.compute_dtype,
+        logits_divisor=model._logits_divisor)
+    return loss_fn(out["logits"], labels)

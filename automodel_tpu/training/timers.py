@@ -42,8 +42,9 @@ INPUT_TIMERS = ("data_wait", "data_staging")
 
 def input_idle_fraction(elapsed: Dict[str, float], window: float) -> float:
     """Steady-state input idle: (data_wait + data_staging) as a fraction of
-    a wall-clock window — bench.py's secondary metric for the async input
-    pipeline; drop it toward 0 by raising ``dataloader.prefetch_depth``."""
+    a wall-clock window (the benchmark's ``input_wait_share.train`` reads
+    the same two spans off a trace); drop it toward 0 by raising
+    ``dataloader.prefetch_depth``."""
     if window <= 0:
         return 0.0
     idle = sum(elapsed.get(name, 0.0) for name in INPUT_TIMERS)
@@ -63,8 +64,7 @@ CKPT_TIMERS = ("ckpt_stall", "ckpt_background")
 def ckpt_stall_fraction(elapsed: Dict[str, float], window: float) -> float:
     """Fraction of a wall-clock window the loop spent blocked on
     checkpointing — the number asynchronous saves exist to drive toward 0
-    (logged each profiling interval; bench.py's ``ckpt_stall_ms`` secondary
-    measures the per-save absolute under both modes)."""
+    (logged each profiling interval)."""
     if window <= 0:
         return 0.0
     return min(elapsed.get("ckpt_stall", 0.0) / window, 1.0)

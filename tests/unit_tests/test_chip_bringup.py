@@ -1,6 +1,6 @@
 """What the chip bring-up (ISSUE 22) established, pinned on the CPU:
 
-* importing the package, ``bench``, ``__graft_entry__`` and ``chip_smoke``
+* importing the package, ``__graft_entry__`` and ``chip_smoke``
   initialises no JAX backend — on a machine with a chip, a parent that has
   touched JAX holds the chip and starves every child it starts;
 * ``chip_smoke.py`` fails fast without a TPU and prints no result;
@@ -19,7 +19,7 @@ _REPO = os.path.dirname(os.path.dirname(os.path.dirname(
 
 _IMPORT_EVERYTHING = """
 import importlib, pkgutil
-import automodel_tpu, bench, __graft_entry__, chip_smoke
+import automodel_tpu, __graft_entry__, chip_smoke
 for m in pkgutil.walk_packages(automodel_tpu.__path__, "automodel_tpu."):
     importlib.import_module(m.name)
 from jax._src import xla_bridge

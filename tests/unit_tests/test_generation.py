@@ -36,15 +36,16 @@ def test_cached_decode_matches_full_forward(model_and_params):
 
     full = model(params, ids)["logits"]
 
+    from automodel_tpu.generation import DenseKVView
+
     cache = model.init_kv_cache(2, 12)
-    out = model(params, ids[:, :4], kv_cache=cache,
-                cache_index=jnp.int32(0))
+    out = model(params, ids[:, :4], kv_cache=DenseKVView.at(cache, 0, 4))
     cache = out["kv_cache"]
     np.testing.assert_allclose(np.asarray(out["logits"]),
                                np.asarray(full[:, :4]), atol=1e-4, rtol=1e-4)
     for t in range(4, 12):
-        out = model(params, ids[:, t:t + 1], kv_cache=cache,
-                    cache_index=jnp.int32(t))
+        out = model(params, ids[:, t:t + 1],
+                    kv_cache=DenseKVView.at(cache, t, 1))
         cache = out["kv_cache"]
         np.testing.assert_allclose(
             np.asarray(out["logits"][:, 0]), np.asarray(full[:, t]),

@@ -162,12 +162,21 @@ def test_spec_on_token_identical_and_generate(model_and_params,
     assert on_eng.allocator.all_free
 
 
-@pytest.mark.parametrize("spec_k", [1, 2, 4])
-@pytest.mark.parametrize("cache", [None, "on"])
+@pytest.mark.parametrize("spec_k,cache,traffic", [
+    (1, None, "mixed"), (2, None, "mixed"), (4, None, "mixed"),
+    (1, "on", "mixed"), (2, "on", "mixed"), (4, "on", "mixed"),
+    (4, None, "distinct")])
 def test_spec_matrix_token_identical(model_and_params, spec_prompts,
-                                     baseline, spec_k, cache):
+                                     baseline, spec_k, cache, traffic):
     """The spec_k x prefix-caching matrix: every cell token-identical to
-    the spec-off baseline, pool drained after."""
+    the spec-off baseline, pool drained after.  ``distinct`` is the
+    adversarial traffic: prompts of all-distinct tokens, whose trailing
+    n-gram has no earlier occurrence to draft from."""
+    if traffic == "distinct":
+        rng = np.random.default_rng(22)
+        spec_prompts = [rng.permutation(np.arange(1, 255))[:n].tolist()
+                        for n in (20, 11, 9, 17)]
+        baseline = _run_prompts(_engine(model_and_params), spec_prompts)
     eng = _engine(model_and_params, speculative="ngram", spec_k=spec_k,
                   prefix_caching=cache)
     out = _run_prompts(eng, spec_prompts)

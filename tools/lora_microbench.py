@@ -13,7 +13,7 @@ Measures device time (profiler, not wall clock: per-op device durations
 exclude dispatch) of fwd + grads-to-(x, A, B) at Llama-1B
 bench shapes (T=16384 tokens, H=2048) for r in {8, 16, 64}.
 
-Usage: python tools/lora_microbench.py
+Usage: python -m tools.lora_microbench
 """
 
 from __future__ import annotations
