@@ -11,8 +11,12 @@ _transformers/auto_model.py:50-144``).  Advantages over
   bandwidth stays at ``Hkv/Hq`` of the repeat path (4x less for Llama-3).
 * **soft-cap support** (``attn_logits_soft_cap``) — lifts the Gemma-style
   restriction the flash path had.
-* mask structure is processed host-side once per shape and skipped blocks
-  are never executed (causal = ~2x fewer FLOPs, exactly).
+* the STATIC mask structure (causal, sliding window) is processed
+  host-side once per shape and its skipped blocks are never executed
+  (causal = ~2x fewer FLOPs, exactly); with segment ids the block map is
+  then narrowed PER ROW, in the step, to the blocks a document spans
+  (``_segment_block_maps``): a block whose query rows and key columns
+  share no segment id is not run and not fetched.
 
 Block sizes route through the substrate autotuner (``kernel_lib/autotune``,
 kernel key ``"splash"``) with a LAYOUT-AWARE default: a partially-masked
@@ -26,6 +30,23 @@ blocks win (grid overhead dominates); at long S the diagonal waste does:
 the waste), and the autotuner can refine further per (shape, dtype,
 topology).
 
+What is static and what is per row.  Per (shape, mask kind, blocks) the
+library's kernel is built once and cached (``_build_kernel``): its
+``block_mask`` / ``data_next`` scalar-prefetch arrays describe the causal
+or windowed mask alone.  Without segment ids that kernel runs as built.
+With them, each row's ids give every query block and key block a range of
+ids (least, greatest); a block can hold an unmasked pair only if the two
+ranges meet, so ``block_mask`` is zeroed elsewhere and ``data_next`` is
+pointed past the skipped blocks, as traced ``[1, q_blocks, kv_blocks]``
+arrays swapped into the cached kernel's pytree, one pair for the forward
+grid and one for the fused backward's.  Inside a block that runs nothing
+changes: the mask function and ``SegmentIds`` mask pair by pair as before.
+The block plan is the same with and without segment ids: on a v5e, over a
+packed SFT mix at S=4096, no edge under 1024 beat 1024 with the map (a
+512-edge block costs ~29 % more per pair than a 1024-edge one, which eats
+what the finer map skips; the sweep's table is in PERF.md section 6).  A
+call without segment ids builds no map: its program is unchanged.
+
 Segment ids (packed sequences) and padding masks use the framework-wide
 convention: pad positions get segment 0 (``ops/attention.py:
 fold_padding_into_segments``).
@@ -38,6 +59,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from automodel_tpu.ops.kernel_lib import autotune, registry, tiling
 
@@ -176,6 +198,125 @@ def _build_kernel(q_seq: int, kv_seq: int, q_heads_per_kv: int,
             interpret=interpret)
 
 
+# ---------------------------------------------------------------------------
+# The per-row block map: which blocks of the static mask a row's documents
+# reach.  One rule, written once for NumPy (the host's counter) and for
+# jax.numpy (the traced map), by passing the array module.
+# ---------------------------------------------------------------------------
+_PAD_RANGE_ID = np.iinfo(np.int32).max
+
+
+def _block_ranges(xp, seg, block: int):
+    """Least and greatest segment id of every ``block`` positions of
+    ``[..., S]`` ids.  Padding is segment 0 AFTER a packed row's last
+    document; for the range test alone it takes an id above every
+    document's, so the row's ids stay monotone and a tail block's range
+    does not reach back over every document before it."""
+    ids = xp.where(seg == 0, _PAD_RANGE_ID, seg)
+    ids = ids.reshape(*seg.shape[:-1], -1, block)
+    return ids.min(-1), ids.max(-1)
+
+
+def _blocks_meet(xp, seg_q, seg_kv, bq: int, bkv: int):
+    """``[..., q_blocks, kv_blocks]`` bool: may block (i, j) hold a pair of
+    equal segment ids?  Two positions with one id put that id inside both
+    blocks' ranges, so ranges that do not meet prove there is no such pair,
+    in whatever order the ids come (ids that are not monotone only make the
+    map less sparse, never wrong).
+
+    Two blocks that share a position share its id, so they always meet: a
+    causal or windowed mask keeps every query row's own (q, q) pair, the
+    block that holds it runs, and no softmax row is left empty (a row with
+    every block skipped would divide 0 by 0 into the residual stream)."""
+    qlo, qhi = _block_ranges(xp, seg_q, bq)
+    klo, khi = _block_ranges(xp, seg_kv, bkv)
+    return ((qlo[..., :, None] <= khi[..., None, :])
+            & (klo[..., None, :] <= qhi[..., :, None]))
+
+
+def _narrowed(info, meet, *, dkv: bool):
+    """``info`` (the cached kernel's static ``MaskInfo`` of one grid) with
+    ``block_mask`` zeroed where ``meet`` ``[q_blocks, kv_blocks]`` is false
+    and ``data_next`` recomputed, for ONE row, as traced arrays.
+
+    In a static ``MaskInfo`` a block that runs holds its OWN data index in
+    ``data_next`` (the key block for the forward grid, also where the grid
+    was shrunk to a window's width; the query block for the fused
+    backward's), and a block that does not run holds the index of the next
+    one that does, in the order the grid is walked, so the pipeline fetches
+    nothing for it.  The forward walks a query block's key blocks; the
+    fused backward walks a key block's query blocks: its order is the
+    transpose.  Where the grid was not shrunk (every causal grid) a block's
+    own index is its place in the walk and no gather is needed."""
+    mask = info.block_mask[0]
+    shrunk = mask.shape != meet.shape
+    if shrunk:
+        own = info.data_next[0].astype(jnp.int32)
+        meet = jnp.take_along_axis(meet, own, axis=0 if dkv else 1)
+    run = (mask != 0) & meet
+    run_w = run.T if dkv else run
+    n = run_w.size
+    at = jnp.where(run_w.reshape(-1), jnp.arange(n, dtype=jnp.int32), n)
+    following = jax.lax.cummin(at, axis=0, reverse=True)
+    # past the last block that runs: the first, as the library wraps
+    following = jnp.where(following == n, at.min(), following)
+    if shrunk:
+        data_next = (own.T if dkv else own).reshape(-1)[following]
+    else:
+        data_next = following % run_w.shape[1]
+    data_next = data_next.reshape(run_w.shape)
+    data_next = data_next.T if dkv else data_next
+    return info._replace(
+        block_mask=jnp.where(run, mask, 0).astype(mask.dtype)[None],
+        data_next=data_next.astype(info.data_next.dtype)[None])
+
+
+def _segment_block_maps(kernel, seg, blocks, bwd_blocks):
+    """The cached static ``kernel`` with both grids' maps narrowed to the
+    blocks one row's ``[S]`` segment ids reach."""
+    fwd = _narrowed(kernel.fwd_mask_info,
+                    _blocks_meet(jnp, seg, seg, blocks[0], blocks[1]),
+                    dkv=False)
+    dkv = _narrowed(kernel.dkv_mask_info,
+                    _blocks_meet(jnp, seg, seg, bwd_blocks[0], bwd_blocks[1]),
+                    dkv=True)
+    return type(kernel)(fwd, kernel.dq_mask_info, dkv, **kernel.kwargs)
+
+
+def _static_blocks(nq: int, nkv: int, bq: int, bkv: int, causal: bool,
+                   local_window: Optional[int]) -> np.ndarray:
+    """``[nq, nkv]`` bool: blocks of the static mask that hold a pair."""
+    q_lo = (np.arange(nq) * bq)[:, None]
+    k_lo = (np.arange(nkv) * bkv)[None, :]
+    if local_window is not None:        # attend [q - window + 1, q]
+        return ((k_lo <= q_lo + bq - 1)
+                & (k_lo + bkv - 1 >= q_lo - (local_window - 1)))
+    if causal:
+        return np.broadcast_to(k_lo <= q_lo + bq - 1, (nq, nkv))
+    return np.ones((nq, nkv), bool)
+
+
+def segment_block_counts(segment_ids, *, causal: bool = True,
+                         local_window_size: Optional[int] = None
+                         ) -> Tuple[int, int]:
+    """(blocks run, static blocks) of the FORWARD grid over a host batch's
+    ``[B, S]`` segment ids: the rule of :func:`_segment_block_maps` in
+    NumPy at the plan's forward edges, for the train loop's counters
+    (``attn_blocks_run`` / ``attn_blocks_static`` on the ``dispatch``
+    span).  Per layer and head the kernel runs the first number where the
+    static mask alone would run the second."""
+    seg = np.atleast_2d(np.asarray(segment_ids))
+    seg = np.pad(seg, ((0, 0), (0, (-seg.shape[-1]) % _SEQ_ALIGN)))
+    S = seg.shape[-1]
+    bq, bkv, _ = _block_plan(S, S, causal=causal,
+                             local_window=local_window_size,
+                             dtype=jnp.bfloat16)
+    static = _static_blocks(S // bq, S // bkv, bq, bkv, causal,
+                            local_window_size)
+    run = _blocks_meet(np, seg, seg, bq, bkv) & static
+    return int(run.sum()), int(static.sum()) * seg.shape[0]
+
+
 def splash_attention_bshd(
     q: jnp.ndarray,                         # [B, S, Hq, D]
     k: jnp.ndarray,                         # [B, Skv, Hk, D]
@@ -246,13 +387,23 @@ def splash_attention_bshd(
     kt = k.transpose(0, 2, 1, 3)            # [B, Hk, Skv, D]
     vt = v.transpose(0, 2, 1, 3)
 
-    per_kv = jax.vmap(kernel, in_axes=(0, 0, 0, None))      # over kv heads
     if segment_ids is None:
+        per_kv = jax.vmap(kernel, in_axes=(0, 0, 0, None))  # over kv heads
         out = jax.vmap(per_kv, in_axes=(0, 0, 0, None))(qs, kt, vt, None)
     else:
-        seg = sk.SegmentIds(q=segment_ids.astype(jnp.int32),
-                            kv=segment_ids.astype(jnp.int32))
-        out = jax.vmap(per_kv, in_axes=(0, 0, 0, 0))(qs, kt, vt, seg)
+        def row(q, k, v, seg):
+            # one row: its own block maps, shared by its kv heads
+            mapped = _segment_block_maps(kernel, seg, blocks, bwd_blocks)
+            return jax.vmap(mapped, in_axes=(0, 0, 0, None))(
+                q, k, v, sk.SegmentIds(q=seg, kv=seg))
+
+        # The maps are scalar-prefetch operands, which a Pallas grid cannot
+        # batch: rows run one after another.  Unrolled here rather than by
+        # vmap's loop over a batched operand, which carries every row's
+        # unreduced dq through a while loop (AOT for v5e, 2 rows, an edge
+        # of 512: 689 MB of temporaries against 182 for this form).
+        seg = segment_ids.astype(jnp.int32)
+        out = jnp.stack([row(qs[b], kt[b], vt[b], seg[b]) for b in range(B)])
     # [B, Hk, G, S, D] -> [B, S, Hq, D] (alignment pads sliced off)
     out = out.reshape(B, Hq, S, D).transpose(0, 2, 1, 3)
     return out[:, :orig_S] if orig_S != S else out

@@ -32,6 +32,7 @@ from automodel_tpu.distributed.init import initialize_distributed
 from automodel_tpu.distributed.mesh import MeshManager
 from automodel_tpu.distributed.shardings import build_parallel_plan
 from automodel_tpu.loss.masked_ce import MaskedCrossEntropy
+from automodel_tpu.ops.kernel_lib import registry
 from automodel_tpu.optim import (
     OptimizerParamScheduler,
     build_optimizer,
@@ -98,6 +99,33 @@ def build_dataset(cfg_ds: ConfigNode, tokenizer=None):
     return cfg_ds.instantiate()
 
 
+_ATTN_BLOCKS_SAMPLE = 1024     # rows of a packed dataset the log line reads
+
+
+def _attn_blocks(batches) -> Dict[str, int]:
+    """``attn_blocks_run`` / ``attn_blocks_static`` of host rows that carry
+    segment ids: the blocks splash attention's per-row map runs a layer and
+    head against those of the causal mask alone (``ops/splash_attention.py
+    ::segment_block_counts``).  Empty where the rows carry none."""
+    from automodel_tpu.ops.splash_attention import segment_block_counts
+
+    segs = [np.atleast_2d(b["segment_ids"]) for b in batches
+            if b.get("segment_ids") is not None]
+    if not segs:
+        return {}
+    run, static = segment_block_counts(np.concatenate(segs))
+    return {"attn_blocks_run": run, "attn_blocks_static": static}
+
+
+def _attn_blocks_note(packs) -> str:
+    blocks = _attn_blocks(packs[:_ATTN_BLOCKS_SAMPLE])
+    if not blocks:
+        return ""
+    return ", attn_blocks_run_share %.4f over the first %d rows" % (
+        blocks["attn_blocks_run"] / blocks["attn_blocks_static"],
+        min(len(packs), _ATTN_BLOCKS_SAMPLE))
+
+
 def build_dataloader(cfg: ConfigNode, dataset, cfg_key: str = "dataloader",
                      local_batch_size: int = 1, seed: int = 0,
                      host_rows=None):
@@ -119,8 +147,9 @@ def build_dataloader(cfg: ConfigNode, dataset, cfg_key: str = "dataloader",
             packed_sequence_size=int(packed_cfg.get("packed_sequence_size")),
             split_across_pack=bool(packed_cfg.get("split_across_pack", False)),
         ).pack()
-        logger.info("%s: pack_fill %.4f (%d rows hold %d tokens)", cfg_key,
-                    dataset.fill, dataset.rows, dataset.tokens)
+        logger.info("%s: pack_fill %.4f (%d rows hold %d tokens)%s", cfg_key,
+                    dataset.fill, dataset.rows, dataset.tokens,
+                    _attn_blocks_note(dataset.packed_dataset))
 
     dl_cfg = cfg.get(cfg_key)
     kwargs: Dict[str, Any] = {}
@@ -775,7 +804,12 @@ class TrainFinetuneRecipeForNextTokenPrediction(BaseRecipe):
                     self.params, self.opt_state, batch)
                 jax.block_until_ready(metrics)  # lint: disable=L004 (profiling.barrier measurement mode only: per-step latency is the thing being measured; dispatch overlap is forfeited on purpose)
         else:
-            with self.timers.record("dispatch", step=self.step_scheduler.step):
+            # once the step has resolved splash attention, its span says
+            # how many blocks this step's rows make the kernel run
+            blocks = (_attn_blocks(batches) if registry.resolved_rungs().get(
+                "attention.splash") else {})
+            with self.timers.record("dispatch", step=self.step_scheduler.step,
+                                    **blocks):
                 self.params, self.opt_state, metrics = self.step_fns.train_step(
                     self.params, self.opt_state, batch)
         if not getattr(self, "_first_dispatch_logged", False):
