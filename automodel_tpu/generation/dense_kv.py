@@ -49,7 +49,7 @@ class DenseKVView:
         """The view for a forward of ``q_len`` tokens written from
         ``cache_index`` on."""
         cache_index = jnp.asarray(cache_index, jnp.int32)
-        batch = pools["k"].shape[1]
+        batch = next(iter(pools.values())).shape[1]
         positions = cache_index + jnp.broadcast_to(
             jnp.arange(q_len, dtype=jnp.int32), (batch, q_len))
         return cls(pools, cache_index, positions, attention_mask)
@@ -95,3 +95,31 @@ class DenseKVView:
             attention_mask=mask, scale=scale,
             logits_soft_cap=logits_soft_cap,
             local_window_size=local_window_size)
+
+    # -- per-sequence state (power retention: models/brumby.py) ------------
+    def retain(self, q, k, v, log_g):
+        """The state-plane call of the protocol (``serving/kv_cache.
+        StatePlaneView.retain`` is the engine's): ``pools`` are then
+        ``{"state", "norm": [L, B, ...]}`` (``model.init_kv_cache``), a row
+        a request.  The batch is left-padded, so a prefill's valid columns
+        are its LAST ones: it runs the chunked form under the mask; a
+        decode step goes down the ``attention.retention_decode`` chain."""
+        from automodel_tpu.ops import power_retention as pr
+
+        B, S = q.shape[:2]
+        valid = (jnp.ones((B, S), bool) if self.attention_mask is None
+                 else lax.dynamic_slice_in_dim(
+                     self.attention_mask.astype(bool), self.cache_index, S,
+                     axis=1))
+        state, norm = self.pools["state"], self.pools["norm"]
+        if S == 1:
+            o, state, norm = pr.retention(
+                q, k, v, log_g, state, norm, layer=self.layer,
+                n_valid=valid[:, 0].astype(jnp.int32),
+                reset=jnp.zeros((B,), bool))
+        else:
+            o, s, z = pr.retention_scan(
+                q, k, v, log_g, *pr.read_state(state, norm, self.layer),
+                valid, jnp.zeros((B, S), bool))
+            state, norm = pr.write_state(state, norm, self.layer, s, z)
+        return o, {"state": state, "norm": norm}

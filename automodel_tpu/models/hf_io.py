@@ -110,6 +110,18 @@ def llama_key_map(config) -> Dict[Tuple[str, ...], HfSpec]:
     return m
 
 
+def brumby_key_map(config) -> Dict[Tuple[str, ...], HfSpec]:
+    """Brumby (``model_type: brumby``): the Qwen3 names plus the retention
+    gate ``self_attn.g_proj`` (weight and bias, one logit a kv head)."""
+    m = llama_key_map(config)
+    m[("layers", "self_attn", "g_proj", "kernel")] = HfSpec(
+        "model.layers.{i}.self_attn.g_proj.weight", stacked=True,
+        transpose=True)
+    m[("layers", "self_attn", "g_proj", "bias")] = HfSpec(
+        "model.layers.{i}.self_attn.g_proj.bias", stacked=True)
+    return m
+
+
 def mixtral_key_map(config) -> Dict[Tuple[str, ...], HfSpec]:
     """Mixtral (HF ``MixtralForCausalLM`` naming): Llama attention plus
     ``block_sparse_moe.gate`` and per-expert ``experts.{e}.w1/w2/w3``."""

@@ -188,3 +188,8 @@ def _ensure_builtin() -> None:
     # llama key map verbatim: Granite's deltas are scalars, not tensors
     register_model(ModelFamily("granite", GraniteConfig, GraniteForCausalLM,
                                hf_io.llama_key_map, ["GraniteForCausalLM"]))
+    from automodel_tpu.models.brumby import BrumbyConfig, BrumbyForCausalLM
+
+    # Qwen3's block over power retention: no softmax attention, no KV cache
+    register_model(ModelFamily("brumby", BrumbyConfig, BrumbyForCausalLM,
+                               hf_io.brumby_key_map, ["BrumbyForCausalLM"]))

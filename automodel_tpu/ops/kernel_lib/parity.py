@@ -40,6 +40,8 @@ CPU_EXECUTABLE = {
     "attention.splash", "attention.ring", "attention.sdpa",
     "attention.paged_decode", "attention.paged_gather",
     "attention.mla_paged_decode", "attention.mla_paged_gather",
+    "attention.retention_decode", "attention.retention_decode_xla",
+    "attention.retention_chunk", "attention.retention_chunk_xla",
     "linear_ce.pallas", "linear_ce.chunked",
     "gmm.pallas", "gmm.xla_blocked", "gmm.ragged",
     "qdot.pallas", "qdot.xla",
@@ -53,6 +55,7 @@ _INTERPRET_MODULES = (
     "automodel_tpu.ops.qdot_kernel",
     "automodel_tpu.ops.paged_attention_kernel",
     "automodel_tpu.ops.mla_paged_attention_kernel",
+    "automodel_tpu.ops.power_retention_kernel",
 )
 
 
@@ -436,6 +439,88 @@ def run_mla_paged_attention_parity(spec_name: str, case: Dict,
 
 
 # ---------------------------------------------------------------------------
+# power retention family (the per-sequence state planes)
+# ---------------------------------------------------------------------------
+def retention_cases() -> List[Dict]:
+    """Decode (``q_seq`` 1) and a chunk over the stacked planes ``state [L,
+    B, Hk, O, dv, d]`` / ``norm [L, B, Hk, O, d]``: ``valid`` < ``q_seq``
+    makes a row's trailing columns padding (0: an idle row, whose state
+    must come back as it went in), ``reset`` rows start from zero whatever
+    their row of the planes held.  Shape keys: ``B Hq Hk L layer``;
+    ``gate``: the gate's logit is ``gate + normal`` (8: slow decay)."""
+    return [
+        dict(name="decode", q_seq=1),
+        dict(name="decode_first_layer", q_seq=1, layer=0),
+        dict(name="decode_idle_and_reset", q_seq=1, B=4, valid=(1, 0, 1, 1),
+             reset=(False, False, True, False)),
+        dict(name="decode_mha", q_seq=1, Hq=2, Hk=2),
+        dict(name="decode_fast_decay", q_seq=1, gate=0.0),
+        dict(name="chunk", q_seq=8),
+        dict(name="chunk_ragged_idle_reset", q_seq=8, B=4,
+             valid=(8, 0, 3, 1), reset=(False, False, True, False)),
+        dict(name="chunk_fast_decay", q_seq=16, gate=-2.0, layer=2),
+    ]
+
+
+def build_retention_case(case: Dict, *, B=2, Hq=4, Hk=2, D=128, L=3,
+                         layer=1):
+    B, Hq, Hk = (case.get(k, d) for k, d in
+                 (("B", B), ("Hq", Hq), ("Hk", Hk)))
+    L, layer = case.get("L", L), case.get("layer", layer)
+    from automodel_tpu.ops import power_retention as pr
+
+    C = case["q_seq"]
+    dtype = jnp.dtype(case.get("dtype", "float32"))
+    keys = iter(jax.random.split(jax.random.key(13), 8))
+    q, k, v = (jax.random.normal(next(keys), (B, C, h, D), jnp.float32)
+               .astype(dtype) for h in (Hq, Hk, Hk))
+    log_g = jax.nn.log_sigmoid(
+        case.get("gate", 6.0) + jax.random.normal(next(keys), (B, C, Hk)))
+    # planes as a sequence of `ctx` tokens would have left them: phi of
+    # random keys against random values, so read-outs are well conditioned
+    ctx = case.get("ctx", 24)
+    pk = pr.phi_k(jax.random.normal(next(keys), (L, B, Hk, ctx, D)))
+    pv = jax.random.normal(next(keys), (L, B, Hk, ctx, D))
+    state = jnp.einsum("nbhcol,nbhcv->nbhovl", pk, pv)
+    norm = jnp.sum(pk, axis=3)
+    norm = jnp.pad(norm, ((0, 0),) * 3 + (
+        (0, pr.norm_rows(D) - norm.shape[3]), (0, 0)))
+    valid = np.asarray(case.get("valid") or [C] * B, np.int32)
+    reset = np.asarray(case.get("reset") or [False] * B, bool)
+    args = (q, k, v, log_g, state, norm, jnp.int32(layer),
+            jnp.asarray(valid), jnp.asarray(reset))
+    return args, pr.build_retention_request(q, k, v, state), valid
+
+
+def run_retention_parity(spec_name: str, case: Dict,
+                         native: bool = False) -> float:
+    """The rung over the stacked planes at the case's layer against the
+    chunked XLA form: the valid columns of the output, the layer's state
+    and normaliser after the step, and every OTHER layer untouched."""
+    spec = registry.get_kernel(spec_name)
+    args, request, valid = build_retention_case(case)
+    (o, s, z), (ro, rs, rz) = _execute(spec, request, args, {}, native)
+    C, layer = args[0].shape[1], int(args[6])
+    keep = (np.arange(C)[None, :] < valid[:, None])[..., None, None]
+    tol = _tol(str(args[0].dtype), native, 2e-3)
+    what = f"{spec_name} on {case['name']}"
+    others = [i for i in range(s.shape[0]) if i != layer]
+    np.testing.assert_array_equal(
+        np.asarray(s)[others], np.asarray(args[4])[others],
+        err_msg=f"{what}: another layer's state changed")
+    np.testing.assert_array_equal(
+        np.asarray(z)[others], np.asarray(args[5])[others],
+        err_msg=f"{what}: another layer's normaliser changed")
+    errs = [_compare(np.where(keep, np.asarray(o, np.float32), 0.0),
+                     np.where(keep, np.asarray(ro, np.float32), 0.0),
+                     tol, native, what + " (output)"),
+            _compare(s[layer], rs[layer], tol, native, what + " (state)"),
+            _compare(z[layer], rz[layer], tol, native,
+                     what + " (normaliser)")]
+    return max(errs)
+
+
+# ---------------------------------------------------------------------------
 # linear_ce family
 # ---------------------------------------------------------------------------
 def linear_ce_cases() -> List[Dict]:
@@ -638,6 +723,7 @@ def chip_cases() -> Dict[str, List[Dict]]:
     at K=14336 are the VMEM-heaviest shape any of them sees."""
     l3b = dict(B=8, Hq=24, Hk=8, D=128, BS=16, MB=64)
     kimi = dict(Hq=64, R=640, V=512, BS=16, MB=1056, L=7, layer=5)
+    brumby = dict(B=16, Hq=40, Hk=8, L=2, layer=1, ctx=64)
     mixtral_up = dict(m=4096, k=4096, n=14336, sizes=_ragged_sizes(4096, 8))
     mixtral_down = dict(m=4096, k=14336, n=4096, sizes=_ragged_sizes(4096, 8))
     moonlight_up = dict(m=4096, k=2048, n=1408, sizes=_ragged_sizes(4096, 64))
@@ -675,6 +761,20 @@ def chip_cases() -> Dict[str, List[Dict]]:
             dict(name="kimi_k2_mixed_step_w64", q_seq=64, dtype="bfloat16",
                  ctx_range=(300, 16384), B=8,
                  valid=(1, 64, 1, 1, 37, 1, 64, 1), **kimi),
+        ],
+        # Brumby-14B's state planes as the serving cell holds them (40
+        # query heads in 8 groups of 5, head size 128, 16 rows), two layers
+        # of the eight: a run keeps the planes three times over (in, out,
+        # reference)
+        "attention.retention_decode": [
+            dict(name="brumby_14b_decode_16rows", q_seq=1, dtype="bfloat16",
+                 valid=(1,) * 12 + (0, 1, 1, 1),
+                 reset=(False,) * 5 + (True,) + (False,) * 10, **brumby),
+        ],
+        "attention.retention_chunk": [
+            dict(name="brumby_14b_chunk64_mixed", q_seq=64, dtype="bfloat16",
+                 valid=(64, 1, 37, 1, 64, 0, 1, 20, 1, 1, 64, 1, 1, 8, 1, 1),
+                 reset=(True, False, True) + (False,) * 13, **brumby),
         ],
         "linear_ce.pallas": [
             dict(name="llama3_2_1b_vocab128256", t=4096, h=2048, v=128256,

@@ -155,6 +155,9 @@ _DEFAULT_KERNEL_MODULES = (
     ("automodel_tpu.ops.mla_paged_attention_kernel",
      "attention.mla_paged_decode"),
     ("automodel_tpu.ops.mla_paged_attention", "attention.mla_paged_gather"),
+    ("automodel_tpu.ops.power_retention_kernel",
+     "attention.retention_decode"),
+    ("automodel_tpu.ops.power_retention", "attention.retention_chunk_xla"),
     ("automodel_tpu.ops.linear_ce_kernel", "linear_ce.pallas"),
     ("automodel_tpu.loss.linear_ce", "linear_ce.chunked"),
     ("automodel_tpu.ops.gmm_kernel", "gmm.pallas"),

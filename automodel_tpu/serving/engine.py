@@ -85,12 +85,15 @@ from automodel_tpu.serving.kv_cache import (
     BlockAllocator,
     PagedKVView,
     PrefixIndex,
+    StatePlaneView,
     blocks_needed,
     cow_copy_blocks,
     init_paged_pools,
+    init_state_planes,
     normalize_kv_cache_dtype,
     normalize_prefix_caching,
     pool_bytes,
+    sequence_planes,
     slot_for,
     validate_kv_cache_dtype,
     validate_prefix_caching,
@@ -246,7 +249,7 @@ def build_serving_config(cfg: Any) -> ServingConfig:
 
 
 def _paged_step(model, block_size: int, quantized: bool, cow_enabled: bool,
-                adapters_enabled: bool,
+                adapters_enabled: bool, state_planes: bool,
                 params, pools,
                 input_ids, positions, slot_mapping, block_tables,
                 context_lens, last_col, cow_src, cow_dst,
@@ -278,13 +281,22 @@ def _paged_step(model, block_size: int, quantized: bool, cow_enabled: bool,
     delta through the grouped GEMM (``ops/lora_gmm.py``).  A base-only
     engine passes NEITHER — its traced program is the pre-multi-tenant
     one, byte-identical.  Swapping a slot only changes slab CONTENTS, so
-    hot-swap never adds a program shape."""
+    hot-swap never adds a program shape.
+
+    ``state_planes`` (trace-time too): the model keeps per-SEQUENCE state
+    (``kv_cache.StatePlaneView``: power retention).  ``pools`` are then the
+    state planes, a row per step-buffer row; ``block_tables`` is ``[B, 1]``
+    and only says which rows hold a request, ``slot_mapping`` and
+    ``context_lens`` address nothing."""
     if cow_enabled:
         with jax.named_scope("cow_copy"):
             pools = cow_copy_blocks(pools, cow_src, cow_dst)
-    view = PagedKVView(
-        pools, block_tables, slot_mapping, context_lens, positions,
-        block_size=block_size, quantized=quantized)
+    if state_planes:
+        view = StatePlaneView(pools, block_tables, positions)
+    else:
+        view = PagedKVView(
+            pools, block_tables, slot_mapping, context_lens, positions,
+            block_size=block_size, quantized=quantized)
     if adapters_enabled:
         out = model(params, input_ids, position_ids=positions,
                     kv_cache=view, adapters=adapter_slabs,
@@ -332,16 +344,28 @@ class DecodeEngine:
         dtype = self.config.kv_cache_dtype or DEFAULT_KV_CACHE_DTYPE
         self.quantized = dtype == "int8"
         cache_dtype = jnp.int8 if self.quantized else model.compute_dtype
-        num_blocks = self.config.resolved_num_blocks()
-        self.max_blocks_per_seq = self.config.blocks_per_seq
         # the planes of the cache are the MODEL's to say: per-head k and v,
-        # or MLA's one latent plane (refused with int8, loudly, there)
-        self._pool_spec = dict(
-            num_layers=mcfg.num_hidden_layers,
-            planes=model.paged_cache_planes(), num_blocks=num_blocks,
-            block_size=self.config.kv_block_size, cache_dtype=cache_dtype,
-            quantized=self.quantized)
-        self.pools = init_paged_pools(**self._pool_spec)
+        # MLA's one latent plane (refused with int8, loudly, there), or
+        # per-sequence state (power retention), which takes no block pool
+        planes = model.paged_cache_planes()
+        self.state_planes = sequence_planes(planes)
+        if self.state_planes:
+            self._refuse_for_state_planes(planes)
+            # a row per step-buffer row; the allocator's blocks are
+            # bookkeeping that never binds (its minimum: null + 1)
+            num_blocks, self.max_blocks_per_seq = 2, 1
+            self._new_pools = functools.partial(
+                init_state_planes, num_layers=mcfg.num_hidden_layers,
+                rows=self.config.max_num_seqs, planes=planes)
+        else:
+            num_blocks = self.config.resolved_num_blocks()
+            self.max_blocks_per_seq = self.config.blocks_per_seq
+            self._new_pools = functools.partial(
+                init_paged_pools, num_layers=mcfg.num_hidden_layers,
+                planes=planes, num_blocks=num_blocks,
+                block_size=self.config.kv_block_size,
+                cache_dtype=cache_dtype, quantized=self.quantized)
+        self.pools = self._new_pools()
         self.allocator = BlockAllocator(num_blocks)
         self.prefix_index: Optional[PrefixIndex] = None
         if (self.config.prefix_caching
@@ -376,7 +400,8 @@ class DecodeEngine:
         self.scheduler = Scheduler(
             self.allocator, max_num_seqs=self.config.max_num_seqs,
             prefill_chunk=self.config.prefill_chunk,
-            block_size=self.config.kv_block_size,
+            block_size=(None if self.state_planes
+                        else self.config.kv_block_size),
             max_model_len=self.config.max_model_len,
             policy=self.config.scheduler_policy
             or DEFAULT_SCHEDULER_POLICY,
@@ -412,10 +437,41 @@ class DecodeEngine:
         # layers in its serving step)
         self.expert_assignments_sum = None
         self.experts_hit_sum = None
+        # state-plane counters, summed over the steps of a model with
+        # per-sequence state: rows whose state a step read and wrote, and
+        # rows it started from zero (None for a per-token cache)
+        self.state_rows_sum = 0 if self.state_planes else None
+        self.state_resets_sum = 0 if self.state_planes else None
         self.watchdog_recoveries = 0
         # clock stamp of the FIRST of the current run of no-progress steps
         # (None while the engine is productive or idle)
         self._no_progress_since: Optional[float] = None
+
+    def _refuse_for_state_planes(self, planes) -> None:
+        """A per-sequence state plane has no blocks to share, no way back
+        from a rejected draft and no scale: the three options that assume a
+        per-token cache are refused at build, each with what is missing."""
+        cfg, names = self.config, sorted(planes)
+        if (cfg.prefix_caching or DEFAULT_PREFIX_CACHING) != "off":
+            raise NotImplementedError(
+                f"serving.prefix_caching: on is not wired for the "
+                f"per-sequence state planes {names}: a shared prefix would "
+                "need a SNAPSHOT of the state at block boundaries to start "
+                "from (the state is one running sum, not blocks that can be "
+                "shared); serve this family with prefix_caching: off")
+        if (cfg.speculative or DEFAULT_SPECULATIVE) != "off":
+            raise NotImplementedError(
+                f"serving.speculative: {cfg.speculative} is not wired for "
+                f"the per-sequence state planes {names}: a verify step "
+                "advances the state past every draft token and nothing "
+                "rolls it BACK to the last accepted one; serve this family "
+                "with speculative: off")
+        if self.quantized:
+            raise NotImplementedError(
+                f"serving.kv_cache_dtype: int8 is not wired for the "
+                f"per-sequence state planes {names}: the float32 state has "
+                "no scale plane (and a rounding error in it is carried "
+                "into every later token); serve it as it is")
 
     # -- compiled step per width (the "compiles once per bucket" seam) -----
     def step_fn(self, width: int):
@@ -425,7 +481,8 @@ class DecodeEngine:
                                      self.config.kv_block_size,
                                      self.quantized,
                                      self.prefix_index is not None,
-                                     self.adapter_slots is not None)
+                                     self.adapter_slots is not None,
+                                     self.state_planes)
             # a jitted partial is "jit__unknown" in a trace: name the program
             # by its width, which is already the key of ``_steps``
             step.__name__ = f"paged_step_w{width}"
@@ -649,10 +706,13 @@ class DecodeEngine:
             ids[b, :t] = toks
             pos[b, :t] = np.arange(start, start + t)
             pos[b, t:] = start + t - 1      # pads clamp to the last valid
-            blocks = work.req.blocks
-            tables[b, :len(blocks)] = blocks
-            slots[b, :t] = [slot_for(blocks, p, bs)
-                            for p in range(start, start + t)]
+            if self.state_planes:
+                tables[b, 0] = b + 1        # the row holds a request
+            else:
+                blocks = work.req.blocks
+                tables[b, :len(blocks)] = blocks
+                slots[b, :t] = [slot_for(blocks, p, bs)
+                                for p in range(start, start + t)]
             ctx[b] = start + t
             last[b] = t - 1
             if work.cow is not None:
@@ -707,7 +767,7 @@ class DecodeEngine:
             # every table is back on the free list; zero pools replace the
             # untrusted donated buffers (cheap relative to the stall
             # absorbed)
-            self.pools = init_paged_pools(**self._pool_spec)
+            self.pools = self._new_pools()
             if self.prefix_index is not None:
                 # rebuilt pools zero the cached contents — a stale prefix
                 # hit would read garbage, so the index forgets everything
@@ -810,6 +870,13 @@ class DecodeEngine:
             self.expert_assignments_sum = (self.expert_assignments_sum
                                            or 0) + assignments
             self.experts_hit_sum = (self.experts_hit_sum or 0) + hit
+        if self.state_planes:
+            resets = sum(1 for w in active if w.start_pos == 0)
+            timers.event("serve_state", step=self.steps_run,
+                         rows=len(active), resets=resets,
+                         chunk_rows=prefill_rows)
+            self.state_rows_sum += len(active)
+            self.state_resets_sum += resets
         with timers.record("serve_finish"):
             # slot -> this row's greedy/sampled CHAIN: column t-1 is the
             # plain next token, columns t..t+d-1 are the argmax at the d
@@ -1016,6 +1083,11 @@ class DecodeEngine:
             # None unless the model's step has routed expert layers
             "expert_assignments_sum": self.expert_assignments_sum,
             "experts_hit_sum": self.experts_hit_sum,
+            # None unless the model keeps per-sequence state planes
+            "state_rows_sum": self.state_rows_sum,
+            "state_resets_sum": self.state_resets_sum,
+            "state_plane_bytes": (pool_bytes(self.pools)
+                                  if self.state_planes else None),
             "preemptions": self.scheduler.preemptions,
             "admissions": self.scheduler.admissions,
             "aborts": self.aborts,

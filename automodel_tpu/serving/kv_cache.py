@@ -25,6 +25,15 @@ and value at once (:meth:`PagedKVView.write_latent` /
 :meth:`PagedKVView.attend_latent`); allocator, block tables, scheduler and
 :func:`cow_copy_blocks` see block ids only and do not change.
 
+A model with per-SEQUENCE state (power retention: Brumby) declares planes
+of kind ``"sequence"`` and gets NO block pool at all: ``{name: [L,
+max_num_seqs, *per-row shape]}`` float32, one row per step-buffer row
+(``Request.slot``), read, advanced and written in place through the ONE
+method of :class:`StatePlaneView`, ``retain``.  A request's memory does not
+grow with its context, so the allocator's blocks never bind for such a
+family (the scheduler is told a token costs nothing) and ``max_model_len``
+costs no HBM.
+
 Block 0 is the reserved **null page**: pad tokens write into it and pad
 block-table entries point at it, so scatter/gather shapes stay static and
 garbage is never read (context-length masks exclude it).
@@ -420,6 +429,10 @@ def init_paged_pools(*, num_layers: int, num_blocks: int, block_size: int,
     latent cache (MLA: ONE plane whose leading values are key AND value).
     A quantized per-head cache adds ``{"k_scale"|"v_scale": [L, NB, BS,
     Hk]}``; a latent plane has no per-head scale to carry and is refused."""
+    if sequence_planes(planes):
+        raise ValueError(
+            "per-sequence state planes are allocated by init_state_planes "
+            "(a row per step-buffer row), not as block pools")
     dtype = jnp.int8 if quantized else jnp.dtype(cache_dtype)
     pools = {}
     for name, per_slot in planes.items():
@@ -437,6 +450,31 @@ def init_paged_pools(*, num_layers: int, num_blocks: int, block_size: int,
             # rejects donating one buffer twice
             pools[name + "_scale"] = jnp.zeros(shape[:-1], jnp.float32)
     return pools
+
+
+def sequence_planes(planes: Dict[str, Tuple]) -> bool:
+    """True where the model's planes hold per-SEQUENCE state (``("sequence",
+    *per-row shape)``: a retention layer's ``S`` and ``z``) and not
+    per-token rows.  A cache is of one kind: a mix is refused."""
+    kinds = {bool(p) and p[0] == "sequence" for p in planes.values()}
+    if len(kinds) != 1:
+        raise NotImplementedError(
+            f"cache planes {planes} mix per-sequence state with per-token "
+            "planes: a hybrid stack needs both kinds of view in one step "
+            "(ROADMAP Reach A4)")
+    return kinds.pop()
+
+
+def init_state_planes(*, num_layers: int, rows: int,
+                      planes: Dict[str, Tuple]) -> Dict[str, jnp.ndarray]:
+    """The static per-layer-stacked state planes of a family with
+    per-sequence state: ``{name: [L, rows, *per-row shape]}`` float32
+    zeros, ``rows`` = ``max_num_seqs``.  Row ``b`` belongs to step-buffer
+    row ``b`` for good: an idle row keeps (and never reads) what its last
+    request left, and a request's first chunk starts it from zero, so there
+    is no null row."""
+    return {name: jnp.zeros((num_layers, rows, *per_row[1:]), jnp.float32)
+            for name, per_row in planes.items()}
 
 
 def pool_bytes(pools: Dict[str, jnp.ndarray]) -> int:
@@ -579,6 +617,61 @@ class PagedKVView:
             q, pools["kv"], layer=self.layer,
             block_tables=self.block_tables, context_lens=self.context_lens,
             positions=self.positions, value_dim=value_dim, scale=scale)
+
+
+@jax.tree_util.register_pytree_node_class
+@dataclasses.dataclass
+class StatePlaneView:
+    """A per-sequence state cache as one model forward sees it: the stacked
+    planes ``{"state": [L, B, Hk, O, dv, d], "norm": [L, B, Hk, O, d]}`` (the
+    layer scan's carry, like the paged pools), the step's ``positions [B,
+    S]`` and ``block_tables [B, 1]`` whose one entry says whether the row
+    holds a request (the engine writes ``slot + 1``; 0 = idle).  Row ``b``
+    of the step buffer owns row ``b`` of the planes: there is nothing to
+    look up."""
+
+    pools: Dict[str, jnp.ndarray]
+    block_tables: jnp.ndarray     # [B, 1] int32, 0 = idle row
+    positions: jnp.ndarray        # [B, S] int32 absolute positions
+    layer: Any = None             # int32 scalar (traced in the layer scan)
+
+    def tree_flatten(self):
+        return (self.pools, self.block_tables, self.positions,
+                self.layer), None
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls(*children)
+
+    def at_layer(self, pools, layer) -> "StatePlaneView":
+        return dataclasses.replace(self, pools=pools, layer=layer)
+
+    def valid_counts(self) -> jnp.ndarray:
+        """``[B]``: a row's leading columns that hold a token (the engine
+        writes consecutive positions and pads by repeating the last one;
+        an idle row holds none)."""
+        pos = self.positions
+        held = pos[:, -1] - pos[:, 0] + 1
+        return jnp.where(self.block_tables[:, 0] != 0, held, 0)
+
+    # -- the model-facing seam: ONE call (models/brumby.py) ----------------
+    def retain(self, q, k, v, log_g):
+        """Power retention of this step's ``q [B, S, Hq, d]``, ``k, v [B, S,
+        Hk, d]``, ``log_g [B, S, Hk]`` through the view's layer of the
+        planes: a row whose positions start at 0 starts from an empty state
+        (a request's first chunk, also after preemption or replay: whatever
+        the row's last owner left is dropped), then the state is read,
+        advanced by the row's valid tokens and written back in place.
+        Returns ``(o [B, S, Hq, d], pools)``."""
+        from automodel_tpu.ops.power_retention import retention
+
+        n_valid = self.valid_counts()
+        with jax.named_scope("state_reset"):
+            reset = (self.positions[:, 0] == 0) & (n_valid > 0)
+        o, state, norm = retention(
+            q, k, v, log_g, self.pools["state"], self.pools["norm"],
+            layer=self.layer, n_valid=n_valid, reset=reset)
+        return o, {"state": state, "norm": norm}
 
 
 def slot_for(block_table: List[int], position: int, block_size: int) -> int:

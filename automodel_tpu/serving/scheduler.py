@@ -313,7 +313,8 @@ class Scheduler:
     """Admission + step assembly + preemption over ``max_num_seqs`` slots."""
 
     def __init__(self, allocator: BlockAllocator, *, max_num_seqs: int,
-                 prefill_chunk: int, block_size: int, max_model_len: int,
+                 prefill_chunk: int, block_size: Optional[int],
+                 max_model_len: int,
                  policy: str = DEFAULT_SCHEDULER_POLICY,
                  max_waiting: Optional[int] = None,
                  shed_policy: str = DEFAULT_SHED_POLICY,
@@ -332,6 +333,9 @@ class Scheduler:
         self.allocator = allocator
         self.max_num_seqs = max_num_seqs
         self.prefill_chunk = prefill_chunk
+        # None: the cache keeps per-SEQUENCE state (``kv_cache.
+        # StatePlaneView``) and a token costs no block — admission is then
+        # bounded by the step slots alone and the allocator never binds
         self.block_size = block_size
         self.max_model_len = max_model_len
         self.policy = policy or DEFAULT_SCHEDULER_POLICY
@@ -407,7 +411,7 @@ class Scheduler:
                 f"request {req.rid}: prompt {len(req.prompt)} + "
                 f"max_new_tokens {req.max_new_tokens} exceeds "
                 f"serving.max_model_len {self.max_model_len}")
-        worst = blocks_needed(total, self.block_size)
+        worst = self._blocks_for(total)
         if self.prefix_index is not None:
             # A prefix hit means the leading cached blocks are SHARED, not
             # consumed: discount them from the worst case (keeping a
@@ -627,6 +631,13 @@ class Scheduler:
             return (aged, req.remaining_budget(now), req.arrival)
         return req.arrival                                   # fcfs
 
+    def _blocks_for(self, tokens: int) -> int:
+        """Blocks that ``tokens`` cached positions take: none where the
+        cache is per-sequence state."""
+        if self.block_size is None:
+            return 0
+        return blocks_needed(tokens, self.block_size)
+
     def _allocate(self, n: int) -> List[int]:
         # The drilled KV-exhaustion site: an armed ``serve_block_alloc``
         # fires here exactly like a genuinely empty free list, and the
@@ -659,7 +670,7 @@ class Scheduler:
         preempting strictly-younger UNPINNED active requests (youngest
         first) while the pool is exhausted; parks ``req`` itself when no
         victim remains.  Returns False when ``req`` was preempted."""
-        need = blocks_needed(new_total, self.block_size) - len(req.blocks)
+        need = self._blocks_for(new_total) - len(req.blocks)
         while True:
             try:
                 if need > 0:
@@ -879,7 +890,8 @@ class Scheduler:
                 self.expire(req, reason="budget")
                 continue
             first_chunk = min(len(req.pending), self.prefill_chunk)
-            if self.allocator.free_blocks * self.block_size < first_chunk:
+            if (self.block_size is not None and self.allocator.free_blocks
+                    * self.block_size < first_chunk):
                 self._unseed(req)
                 continue         # in-flight admission waits for frees
             self.waiting.remove(req)

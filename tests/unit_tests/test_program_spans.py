@@ -524,3 +524,59 @@ def test_latent_step_updates_its_plane_in_place(one_chip, monkeypatch, width):
     assert not _pool_sized_moves(text, stacks), _pool_sized_moves(text, stacks)
     assert compiled.memory_analysis().temp_size_in_bytes \
         < pool_bytes(pools) / 4
+
+
+# -- the retention step: per-sequence state planes, no block pool ------------
+# Brumby's serving step carries two state planes through its layer scan and
+# the retention kernels read and write a (row, kv head) tile of them in place
+# (``input_output_aliases``) at a prefetched layer.  The same question again:
+# nothing the size of a plane or of a layer of it is copied, sliced out or
+# relaid out; one Mosaic call a step program, named ``retention_decode`` in
+# the decode program and ``retention_chunk`` in the wider one.
+@pytest.mark.parametrize("width,kernel", [(1, "retention_decode"),
+                                          (8, "retention_chunk")],
+                         ids=["w1", "w8"])
+def test_retention_step_updates_its_state_planes_in_place(
+        one_chip, monkeypatch, width, kernel):
+    from automodel_tpu.models.brumby import BrumbyConfig, BrumbyForCausalLM
+    from automodel_tpu.ops.kernel_lib import registry
+    from automodel_tpu.serving.kv_cache import pool_bytes
+
+    monkeypatch.setattr(registry, "on_tpu", lambda: True)
+    cfg = BrumbyConfig(
+        vocab_size=256, hidden_size=256, intermediate_size=512,
+        num_hidden_layers=4, num_attention_heads=10, num_key_value_heads=2,
+        head_dim=128, rope_theta=1e6, max_position_embeddings=128)
+    model = BrumbyForCausalLM(cfg, param_dtype=jnp.bfloat16,
+                              compute_dtype=jnp.bfloat16, remat=False)
+    params = model.abstract_params()
+    eng = DecodeEngine(model, params, ServingConfig(
+        max_num_seqs=8, max_model_len=4096, prefill_chunk=8))
+    assert sorted(eng.pools) == ["norm", "state"]
+    assert eng.pools["state"].shape == (4, 8, 2, 65, 128, 128)
+    assert eng.max_blocks_per_seq == 1 and eng.allocator.num_blocks == 2
+
+    def spec(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    pools = jax.tree.map(spec, eng.pools)
+    B = 8
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+    compiled = eng.step_fn(width).lower(
+        jax.tree.map(spec, params), pools,
+        i32(B, width), i32(B, width), i32(B, width), i32(B, 1), i32(B),
+        i32(B), i32(B), i32(B)).compile()
+    text = compiled.as_text()
+    assert text.startswith(f"HloModule jit_paged_step_w{width}")
+
+    kernels = _kernels(text)
+    assert len(kernels) == 1, kernels
+    (name, scope), = kernels.items()
+    assert re.match(rf"^{kernel}(\.\d+)?$", name), kernels
+    assert f"/attn/attn_core/{kernel}/" in scope, kernels
+    for scope_name in ("retention_gate", "retention_out", "state_reset"):
+        assert f"/{scope_name}/" in text, scope_name
+
+    assert not _pool_sized_moves(text, pools), _pool_sized_moves(text, pools)
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < pool_bytes(pools) / 4

@@ -14,6 +14,8 @@ _RUNNERS = {
     "attention.splash": parity.run_attention_parity,
     "attention.paged_decode": parity.run_paged_attention_parity,
     "attention.mla_paged_decode": parity.run_mla_paged_attention_parity,
+    "attention.retention_decode": parity.run_retention_parity,
+    "attention.retention_chunk": parity.run_retention_parity,
     "linear_ce.pallas": parity.run_linear_ce_parity,
     # grads=True: dlhs is a second gmm, drhs the transposed kernel (tgmm)
     "gmm.pallas": functools.partial(parity.run_gmm_parity, grads=True),
@@ -37,6 +39,8 @@ def test_pallas_rung_matches_reference_natively(rung, case, record_property):
 
 def test_probes_accept_published_widths_on_the_chip():
     """Dispatch (not just the harness) picks the Pallas rung here."""
+    brumby = {"num_q_heads": 40, "num_kv_heads": 8, "head_dim": 128,
+              "value_dim": 128, "state_dtype": "float32"}
     for head, request in (
             ("attention.splash", {"q_seq": 2048, "kv_seq": 2048,
                                   "head_dim": 64}),
@@ -46,6 +50,8 @@ def test_probes_accept_published_widths_on_the_chip():
              {"q_seq": 1, "latent_dim": 640, "value_dim": 512}),
             ("attention.mla_paged_decode",
              {"q_seq": 64, "latent_dim": 640, "value_dim": 512}),
+            ("attention.retention_decode", dict(brumby, q_seq=1)),
+            ("attention.retention_chunk", dict(brumby, q_seq=64)),
             ("linear_ce.pallas", {"t": 16384, "h": 2048, "v": 128256}),
             ("gmm.pallas", {"m": 4096, "k": 4096, "n": 14336}),
             ("qdot.pallas", {"m": 4096, "k": 14336, "n": 4096}),
