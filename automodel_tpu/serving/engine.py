@@ -52,6 +52,18 @@ sample host-side from the returned last-token logits.  Greedy output is
 token-identical to ``generate()`` on the same model/params — the tier-1
 parity oracle (``tests/unit_tests/test_serving.py``).
 
+**Look-ahead of one step**: a greedy engine dispatches step N+1 BEFORE it
+fetches step N.  A decode row's input token for N+1 is taken on the device
+from N's output (``prev_tok`` / ``take_prev`` of the step program), the
+pools already chain from step to step as futures, so the device goes from
+program N straight to N+1 while the host fetches N, applies it
+(``Scheduler.deliver``) and prepares N+2: the host's whole loop and the
+launch + fetch latency are hidden behind the device step.  A token is on
+the host one ``step()`` call after the call that dispatched its step.
+``do_sample`` and speculation need the newest token ON the host to plan the
+next step, so those engines run the same loop at depth 0 (the fetch taken
+at once), decided at build.
+
 Speculative decoding (``serving.speculative: ngram``,
 ``serving/speculative.py``) changes only the pure-decode width: a
 host-side prompt-lookup proposer drafts up to ``serving.spec_k`` tokens
@@ -67,12 +79,13 @@ output is token-identical to spec-off by construction (tier-1 pinned,
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
 import itertools
 import logging
 import time
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Deque, Dict, List, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -248,17 +261,32 @@ def build_serving_config(cfg: Any) -> ServingConfig:
     return ServingConfig(**data)
 
 
+class LastColumn(NamedTuple):
+    """What each row's last valid column produced in one step."""
+
+    logits: Any     # [B, V] float32
+    token: Any      # [B] int32: its argmax, the next step's ``prev_tok``
+
+
 def _paged_step(model, block_size: int, quantized: bool, cow_enabled: bool,
                 adapters_enabled: bool, state_planes: bool,
                 params, pools,
                 input_ids, positions, slot_mapping, block_tables,
                 context_lens, last_col, cow_src, cow_dst,
+                prev_tok, take_prev,
                 adapter_ids=None, adapter_slabs=None):
     """ONE traced program per step width: run any pending copy-on-write
     block forks, write this step's tokens into the paged cache, attend,
     and greedy-pick EVERY column's next token.  Returns ``(greedy [B, W],
-    last_logits [B, V], pools)`` and, from a model with routed expert
-    layers, ``expert_tokens [n_moe_layers, held]``.  The pools are donated
+    last: LastColumn, pools)`` and, from a model with routed expert
+    layers, ``expert_tokens [n_moe_layers, held]``.  ``last`` is what each
+    row's last valid column produced: its ``logits [B, V]`` (host-side
+    sampling reads them) and its greedy ``token [B]`` — the program feeds
+    itself with it: the NEXT step hands it back as ``prev_tok [B]`` (still
+    a device future, never fetched for this) with ``take_prev [B]`` bool,
+    and a row whose flag is set reads column 0 of its ``input_ids`` from
+    there.  Both are ``[B]`` at either width, so the two programs chain in
+    any order.  The pools are donated
     and come back as the layer scan's carry (``models/layer_scan.py::scan_layers``), so the
     cache updates in place.  Plain decode reads its one token at its last
     valid column of ``greedy``; the speculative verify reads the argmax at
@@ -288,6 +316,8 @@ def _paged_step(model, block_size: int, quantized: bool, cow_enabled: bool,
     state planes, a row per step-buffer row; ``block_tables`` is ``[B, 1]``
     and only says which rows hold a request, ``slot_mapping`` and
     ``context_lens`` address nothing."""
+    input_ids = input_ids.at[:, 0].set(
+        jnp.where(take_prev, prev_tok, input_ids[:, 0]))
     if cow_enabled:
         with jax.named_scope("cow_copy"):
             pools = cow_copy_blocks(pools, cow_src, cow_dst)
@@ -309,10 +339,25 @@ def _paged_step(model, block_size: int, quantized: bool, cow_enabled: bool,
         last = jnp.take_along_axis(
             logits, last_col[:, None, None], axis=1)[:, 0]    # [B, V]
         greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [B, W]
+        last = LastColumn(logits=last, token=jnp.take_along_axis(
+            greedy, last_col[:, None], axis=1)[:, 0])         # [B]
     if "expert_tokens" in out:
         # [n_moe_layers, held] int32: tokens each held expert got this step
         return greedy, last, out["kv_cache"], out["expert_tokens"]
     return greedy, last, out["kv_cache"]
+
+
+@dataclasses.dataclass
+class _Flight:
+    """One dispatched step whose tokens are not on the host yet: the plan,
+    the step program's outputs (device futures) and what the dispatch
+    already knew of it."""
+
+    plan: StepPlan
+    step: int                  # ``steps_run`` at dispatch (the span's stat)
+    greedy: Any
+    logits: Any                # ``last.logits``, kept for ``do_sample`` only
+    routed: list
 
 
 class DecodeEngine:
@@ -397,6 +442,17 @@ class DecodeEngine:
                 "set and speculative verification is greedy-only", spec_mode)
             spec_mode = "off"
         self.spec_mode = spec_mode
+        # Steps dispatched ahead of the fetch: 1 where the next step can be
+        # planned without the newest token (greedy: the device feeds it),
+        # 0 where the host needs it first (it samples it, or the n-gram
+        # proposer reads it and the verify accepts a variable number)
+        self.lookahead = (0 if self.generation.do_sample
+                          or spec_mode != "off" else 1)
+        self._in_flight: Deque[_Flight] = collections.deque()
+        self._done_between: List[Request] = []
+        # the newest step's ``last.token``, a device future: what a fed row
+        # of the next step reads (zeros before the first step: no row is fed)
+        self._prev_tok = jnp.zeros((self.config.max_num_seqs,), jnp.int32)
         self.scheduler = Scheduler(
             self.allocator, max_num_seqs=self.config.max_num_seqs,
             prefill_chunk=self.config.prefill_chunk,
@@ -432,6 +488,8 @@ class DecodeEngine:
         self.mixed_steps = 0
         self.aborts = 0
         self.tokens_generated = 0
+        # steps dispatched while the one before was still unfetched
+        self.ahead_steps = 0
         # routed-expert counters, summed over the steps of a model whose
         # step returns ``expert_tokens`` (None for a model without routed
         # layers in its serving step)
@@ -563,6 +621,9 @@ class DecodeEngine:
                 "invalid; build a new engine for a different model")
         if self.param_sharding is not None:
             params = self._copy_into_decode_plan(params)
+        # the step in flight ran under the old weights; its tokens are
+        # delivered before the first step under the new ones is planned
+        self._flush()
         self.params = params
         self.weight_syncs += 1
 
@@ -656,11 +717,14 @@ class DecodeEngine:
         flags (``was_admitted``, pinned, tokens-so-far) and leave
         ``self.requests`` entirely: the fleet decides where they land."""
         harvested = []
+        # a lost slice's step never comes back: what is in flight is dropped
+        self._in_flight.clear()
         for req in list(self.scheduler.active) + list(self.scheduler.waiting):
             if req.finished:
                 continue
             self.scheduler._release(req)
             req.num_computed = 0
+            req.in_flight = 0
             req.state = RequestState.WAITING
             harvested.append(req)
             self.requests.pop(req.rid, None)
@@ -671,6 +735,12 @@ class DecodeEngine:
         freed immediately (the ``serve_request_abort`` contract)."""
         req = self.requests.get(rid)
         if req is None or req.finished:
+            return
+        # what the device already computed for it is delivered first: the
+        # request ends with the tokens of every step dispatched before the
+        # cancel, as it does at depth 0 (and may have FINISHED on them)
+        self._flush()
+        if req.finished:
             return
         self.scheduler.abort(req)
         self.aborts += 1
@@ -690,11 +760,14 @@ class DecodeEngine:
         # COW fork pairs: (0, 0) = null page onto itself = content no-op
         cow_src = np.zeros((B,), np.int32)
         cow_dst = np.zeros((B,), np.int32)
+        # rows whose one token is the previous step's sample, on the device
+        take_prev = np.zeros((B,), np.bool_)
         for work in plan.active:
             b = work.req.slot
+            take_prev[b] = work.fed
             # draft tokens are ordinary written tokens to the device step:
             # (adapter routing is assembled separately — see
-            # ``_assemble_adapter_ids`` — so this 8-tuple, and every
+            # ``_assemble_adapter_ids`` — so this tuple, and every
             # base-only caller that splats it into the step, is unchanged)
             # same ids/pos/slot treatment, context covers them, and the
             # per-column argmax at their positions is the verify readout.
@@ -717,7 +790,8 @@ class DecodeEngine:
             last[b] = t - 1
             if work.cow is not None:
                 cow_src[b], cow_dst[b] = work.cow
-        return ids, pos, slots, tables, ctx, last, cow_src, cow_dst
+        return (ids, pos, slots, tables, ctx, last, cow_src, cow_dst,
+                self._prev_tok, take_prev)
 
     def _assemble_adapter_ids(self, plan: StepPlan) -> np.ndarray:
         """``[B]`` int32 slot routing for a multi-tenant step — idle rows
@@ -728,11 +802,10 @@ class DecodeEngine:
             aids[work.req.slot] = work.req.adapter_id
         return aids
 
-    def _sample(self, row: int, last_logits) -> int:
+    def _sample(self, step: int, row: int, last_logits) -> int:
         # host-side sampling path (do_sample only — greedy rows read the
         # in-step argmax): one extra [V] fetch per sampled row
-        key = jax.random.fold_in(self._sample_key, self.steps_run * 4096
-                                 + row)
+        key = jax.random.fold_in(self._sample_key, step * 4096 + row)
         return int(np.asarray(sample_logits(
             jnp.asarray(last_logits[row])[None], self.generation, key))[0])
 
@@ -766,7 +839,9 @@ class DecodeEngine:
                 self.scheduler.requeue_for_replay(req)
             # every table is back on the free list; zero pools replace the
             # untrusted donated buffers (cheap relative to the stall
-            # absorbed)
+            # absorbed); a step still in flight is abandoned with them (the
+            # replay regenerates its tokens)
+            self._in_flight.clear()
             self.pools = self._new_pools()
             if self.prefix_index is not None:
                 # rebuilt pools zero the cached contents — a stale prefix
@@ -776,15 +851,28 @@ class DecodeEngine:
         self._no_progress_since = None
 
     def step(self) -> List[Request]:
-        """One scheduler + device step; returns the requests that finished
-        on it.  No-op (empty list) when idle.  Never raises for load or
-        stall reasons: exhaustion preempts, deadlines expire, a full queue
-        sheds, and a detected wedge recovers — the engine loop under fire
-        keeps stepping.  A REAL runtime failure out of the device step
-        (not the drilled fault) still propagates — but only after the same
-        recovery ran, so the engine's state (tables reclaimed, pools
-        rebuilt) stays consistent and a caller that catches it may keep
-        stepping."""
+        """One scheduler + device step; returns the requests whose LAST
+        token was delivered in this call.  No-op (empty list) when idle.
+
+        The order inside one call is: plan step N+1 -> assemble -> dispatch
+        N+1 -> fetch step N -> deliver N (``self.lookahead`` is 1: greedy
+        engines), so the device runs N+1 while the host fetches N, applies
+        it and comes back to plan N+2.  A caller that reads
+        ``req.out_tokens`` between calls therefore sees step N's token
+        after the call that DISPATCHED N+1: one call later than the call
+        that dispatched N.  A request leaves ``scheduler.active`` only when
+        its last token is delivered, so ``scheduler.has_work()`` stays true
+        while a step is in flight and a loop on it ends with none.  With
+        ``lookahead`` 0 (``do_sample``, speculation) the fetch is taken at
+        once and a call delivers the step it dispatched.
+
+        Never raises for load or stall reasons: exhaustion preempts,
+        deadlines expire, a full queue sheds, and a detected wedge recovers
+        — the engine loop under fire keeps stepping.  A REAL runtime
+        failure out of the device step (not the drilled fault) still
+        propagates — but only after the same recovery ran, so the engine's
+        state (tables reclaimed, pools rebuilt) stays consistent and a
+        caller that catches it may keep stepping."""
         with self.timers.record("serve_step"):
             return self._step()
 
@@ -806,55 +894,23 @@ class DecodeEngine:
                     f"no slot progress across consecutive steps spanning > "
                     f"serving.watchdog_s={self.config.watchdog_s}")
             plan = self.scheduler.schedule(now=t0)
-        if plan is None:
-            if self.scheduler.has_work():
-                # work pending but nothing schedulable: the no-progress
-                # window starts (or continues) here
-                if self._no_progress_since is None:
-                    self._no_progress_since = t0
-            else:
-                self._no_progress_since = None       # idle is not a wedge
-            return []
-        with timers.record("serve_assemble"):
-            (ids, pos, slots, tables, ctx, last,
-             cow_src, cow_dst) = self._assemble(plan)
-            # multi-tenant engines append the row->slot routing + the live
-            # slabs; base-only engines call with exactly the pre-multi-
-            # tenant ten args (their traced program is byte-unchanged)
-            extra = (() if self.adapter_slots is None
-                     else (self._assemble_adapter_ids(plan),
-                           self.adapter_slots.slabs))
-        # How full this step is, known once the plan is: it rides on the
-        # dispatch span and sums into stats().  ``positions`` are pending
-        # tokens written (drafts are a guess, not counted); ``slots`` the
-        # positions the dense [max_num_seqs, width] program computes.
-        active, width = plan.active, plan.step_width
-        positions = sum(len(w.tokens) for w in active)
-        prefill_rows = sum(1 for w in active if len(w.tokens) > 1)
-        n_slots = self.config.max_num_seqs * width
+        # requests that finished in a flush between two calls (an abort, a
+        # weight handoff) are this call's to return
+        done, self._done_between = self._done_between, []
         try:
-            # The drilled wedged-step site: an armed ``serve_watchdog_stall``
-            # stands in for a device step that never completed (the runtime
-            # surfacing a timeout/cancellation) — the watchdog recovery
-            # path must absorb it without crashing the engine loop.
-            fault_point("serve_watchdog_stall")
-            with timers.record(
-                    "serve_dispatch", step=self.steps_run, width=width,
-                    rows=len(active), positions=positions, slots=n_slots,
-                    prefill_rows=prefill_rows,
-                    sampled=sum(1 for w in active if w.samples_next)):
-                greedy, last_logits, self.pools, *routed = self.step_fn(
-                    width)(self.params, self.pools, ids, pos, slots, tables,
-                           ctx, last, cow_src, cow_dst, *extra)
-            # the engine's one host sync: the [B, W] per-column argmax
-            # drives the host-side request state machine — plain decode
-            # reads one column, the speculative verify reads k+1, SAME
-            # fetch either way
-            with timers.record("serve_fetch"):
-                greedy, *routed = (np.asarray(a) for a in jax.device_get((greedy, *routed)))  # lint: disable=L004 (continuous batching IS a per-step host decision loop: one [B, W]-int fetch per step — the speculative verify rides it too — and the logits stay on device unless do_sample)
+            if plan is not None:
+                self._dispatch(plan)
+            # fetch what is due: every step beyond the look-ahead — and
+            # with nothing new to run behind it, the one in flight too
+            keep = self.lookahead if plan is not None else 0
+            delivered = len(self._in_flight) > keep
+            while len(self._in_flight) > keep:
+                done.extend(self._deliver(self._in_flight.popleft()))
+            if delivered:
+                self.scheduler.note_step_time(self.clock() - t0)
         except InjectedFault:
             self._watchdog_recover("injected stall (serve_watchdog_stall)")
-            return []
+            return done
         except Exception as e:
             # a genuine runtime failure mid-dispatch: the donated pools
             # cannot be trusted — recover FIRST (tables reclaimed, pools
@@ -862,53 +918,130 @@ class DecodeEngine:
             # real bug stays loud
             self._watchdog_recover(f"device step failed: {e!r}")
             raise
+        if plan is not None or delivered:
+            self._no_progress_since = None           # this step progressed
+        elif self.scheduler.has_work():
+            # work pending but nothing schedulable: the no-progress
+            # window starts (or continues) here
+            if self._no_progress_since is None:
+                self._no_progress_since = t0
+        else:
+            self._no_progress_since = None           # idle is not a wedge
+        return done
+
+    def _dispatch(self, plan: StepPlan) -> None:
+        """Assemble ``plan``'s buffers, call the step program (the call
+        returns at once: its outputs are futures) and run the half of the
+        scheduler's step that needs no token."""
+        timers = self.timers
+        with timers.record("serve_assemble"):
+            args = self._assemble(plan)
+            # multi-tenant engines append the row->slot routing + the live
+            # slabs; base-only engines call with exactly the twelve args
+            # (their traced program has no adapter input)
+            extra = (() if self.adapter_slots is None
+                     else (self._assemble_adapter_ids(plan),
+                           self.adapter_slots.slabs))
+        # How full this step is, known once the plan is: it rides on the
+        # dispatch span and sums into stats().  ``positions`` are pending
+        # tokens written (drafts are a guess, not counted); ``slots`` the
+        # positions the dense [max_num_seqs, width] program computes;
+        # ``ahead`` whether the step before is still unfetched, ``fed_rows``
+        # the rows whose token comes from it on the device.
+        active, width = plan.active, plan.step_width
+        positions = sum(len(w.tokens) for w in active)
+        prefill_rows = sum(1 for w in active if len(w.tokens) > 1)
+        n_slots = self.config.max_num_seqs * width
+        ahead = int(bool(self._in_flight))
+        # The drilled wedged-step site: an armed ``serve_watchdog_stall``
+        # stands in for a device step that never completed (the runtime
+        # surfacing a timeout/cancellation) — the watchdog recovery
+        # path must absorb it without crashing the engine loop.
+        fault_point("serve_watchdog_stall")
+        step = self.steps_run
+        with timers.record(
+                "serve_dispatch", step=step, width=width,
+                rows=len(active), positions=positions, slots=n_slots,
+                prefill_rows=prefill_rows,
+                sampled=sum(1 for w in active if w.samples_next),
+                ahead=ahead, fed_rows=sum(1 for w in active if w.fed)):
+            greedy, last, self.pools, *routed = self.step_fn(width)(
+                self.params, self.pools, *args, *extra)
+        self._prev_tok = last.token
+        # a greedy engine lets go of the [B, V] logits here, so two steps'
+        # worth are never alive at once
+        self._in_flight.append(_Flight(
+            plan, step, greedy,
+            last.logits if self.generation.do_sample else None, routed))
+        self.scheduler.advance(plan)
+        if self.state_planes:
+            resets = sum(1 for w in active if w.start_pos == 0)
+            timers.event("serve_state", step=step, rows=len(active),
+                         resets=resets, chunk_rows=prefill_rows)
+            self.state_rows_sum += len(active)
+            self.state_resets_sum += resets
+        self.steps_run += 1
+        self.ahead_steps += ahead
+        self.rows_sum += len(active)
+        self.positions_sum += positions
+        self.slots_sum += n_slots
+        # a decode step carries no prefill work — under speculation its
+        # width is spec_k+1, so classify by the rows, not the width
+        if not prefill_rows:
+            self.decode_steps += 1
+        else:
+            self.mixed_steps += 1
+
+    def _deliver(self, flight: _Flight) -> List[Request]:
+        """Fetch one dispatched step's tokens and run the half of the
+        scheduler's step that needs them; returns the requests it
+        finished."""
+        timers = self.timers
+        # the engine's one host sync a step, one step behind the dispatch
+        # where it looks ahead: the [B, W] per-column argmax drives the
+        # host-side request state machine — plain decode reads one column,
+        # the speculative verify reads k+1, SAME fetch either way
+        with timers.record("serve_fetch"):
+            greedy, *routed = (np.asarray(a) for a in jax.device_get((flight.greedy, *flight.routed)))  # lint: disable=L004 (continuous batching IS a per-step host decision loop: one [B, W]-int fetch per step — the speculative verify rides it too — taken one step BEHIND the dispatch on a greedy engine, so the device runs the next step while this one's tokens come back; the logits stay on device unless do_sample)
         if routed:
             # a span's stats are fixed when it opens, so this is an event
             assignments, hit = int(routed[0].sum()), int((routed[0] > 0).sum())
-            timers.event("serve_experts", step=self.steps_run,
+            timers.event("serve_experts", step=flight.step,
                          assignments=assignments, hit=hit)
             self.expert_assignments_sum = (self.expert_assignments_sum
                                            or 0) + assignments
             self.experts_hit_sum = (self.experts_hit_sum or 0) + hit
-        if self.state_planes:
-            resets = sum(1 for w in active if w.start_pos == 0)
-            timers.event("serve_state", step=self.steps_run,
-                         rows=len(active), resets=resets,
-                         chunk_rows=prefill_rows)
-            self.state_rows_sum += len(active)
-            self.state_resets_sum += resets
         with timers.record("serve_finish"):
             # slot -> this row's greedy/sampled CHAIN: column t-1 is the
             # plain next token, columns t..t+d-1 are the argmax at the d
-            # draft positions (the verify read — finish_step accepts the
+            # draft positions (the verify read — deliver accepts the
             # longest matching prefix).  do_sample rows (never drafted)
             # sample host-side.
             sampled = {}
-            for w in active:
-                if not w.samples_next:
+            for b, w in enumerate(flight.plan.rows):
+                # a row of the plan IS its slot when the plan was made, and
+                # a request the delivery applies to still holds it
+                if w is None or not w.samples_next:
                     continue
-                b, t = w.req.slot, len(w.tokens)
+                t = len(w.tokens)
                 if self.generation.do_sample:
-                    sampled[b] = [self._sample(b, last_logits)]
+                    sampled[b] = [self._sample(flight.step, b,
+                                               flight.logits)]
                 else:
                     sampled[b] = greedy[b, t - 1:t + len(w.draft)].tolist()
-            self.steps_run += 1
-            self.rows_sum += len(active)
-            self.positions_sum += positions
-            self.slots_sum += n_slots
-            # a decode step carries no prefill work — under speculation its
-            # width is spec_k+1, so classify by the rows, not the width
-            if not prefill_rows:
-                self.decode_steps += 1
-            else:
-                self.mixed_steps += 1
             appended0 = self.scheduler.tokens_appended
-            done = self.scheduler.finish_step(plan, sampled)
+            done = self.scheduler.deliver(flight.plan, sampled)
             self.tokens_generated += (self.scheduler.tokens_appended
                                       - appended0)
-            self.scheduler.note_step_time(self.clock() - t0)
-            self._no_progress_since = None           # this step progressed
         return done
+
+    def _flush(self) -> None:
+        """Deliver every step in flight now (before an abort or a weight
+        handoff, after a drain's deadline): the caller goes on with nothing
+        in flight.  What finishes here is returned by the next ``step()``."""
+        while self._in_flight:
+            self._done_between.extend(
+                self._deliver(self._in_flight.popleft()))
 
     def run(self, max_steps: Optional[int] = None) -> Dict[int, List[int]]:
         """Drive until every submitted request reaches a terminal state;
@@ -960,6 +1093,7 @@ class DecodeEngine:
                     for req in (list(self.scheduler.active)
                                 + list(self.scheduler.waiting)):
                         self.scheduler.expire(req, reason="drain_deadline")
+                    self._flush()    # rows of expired requests: dropped
                     break
                 self.step()
         return self.outcome_counts()
@@ -1072,6 +1206,12 @@ class DecodeEngine:
             "speculative": spec,
             "multi_tenant": multi_tenant,
             "steps": self.steps_run,
+            # the look-ahead: steps dispatched before the one before was
+            # fetched, and rows a step computed for a request that had
+            # finished or been preempted by the time it was delivered
+            "lookahead": self.lookahead,
+            "ahead_steps": self.ahead_steps,
+            "discarded_rows": self.scheduler.discarded_rows,
             # step fill: positions_sum / slots_sum is the share of computed
             # positions that held a pending token (rows_sum: active rows)
             "rows_sum": self.rows_sum,

@@ -630,6 +630,8 @@ class FleetRouter:
                 r.replica_id: {
                     "alive": r.alive,
                     "steps": r.engine.steps_run,
+                    "ahead_steps": r.engine.ahead_steps,
+                    "discarded_rows": r.engine.scheduler.discarded_rows,
                     "tokens_generated": r.engine.tokens_generated,
                     "compiled_widths": sorted(r.engine._steps),
                     "kv_blocks_free": r.engine.allocator.free_blocks,

@@ -25,6 +25,25 @@ the vLLM "recompute" policy: on re-admission the prompt AND the tokens
 generated so far re-prefill, which under greedy decoding reproduces the
 identical continuation, so a preempted request is slower, never wrong.
 
+Look-ahead (the engine dispatches step N+1 before it fetches step N): the
+per-step contract is split where the token is needed.  :meth:`Scheduler.
+advance` runs at DISPATCH and does what needs no token (``num_computed``,
+the COW source's release, ``Request.in_flight``: samples dispatched and not
+yet delivered); :meth:`Scheduler.deliver` runs after the FETCH and does the
+rest (append, eos / length, commit, retire).  While a sample is in flight
+the row's next token is not on the host: ``schedule()`` plans it as a ``fed``
+row (one placeholder id; the step program takes the token from the previous
+step's output on the device), and leaves out a request whose delivered plus
+in-flight samples already reach ``max_new_tokens`` — a finish by LENGTH is
+known without the token.  A finish by EOS is learnt one step late: the row
+that step already dispatched for it is dropped at delivery
+(``discarded_rows``); its writes land past the sequence's end in blocks that
+are freed, and the device runs its programs in order, so whoever is given
+those blocks next writes them after.  A request leaves ``active`` only at
+delivery, so :meth:`Scheduler.has_work` stays true while a sample is in
+flight.  The pending invariant becomes: ``num_computed`` may run ahead of
+``seq_len`` by the samples in flight less one.
+
 Robustness layer (the serving-under-fire contract):
 
 * **Deadlines & TTLs** — ``Request.deadline_s`` is an end-to-end wall
@@ -71,7 +90,7 @@ Speculative decoding (``serving.speculative: ngram``): on pure-decode
 steps a host-side proposer (``serving/speculative.py``) drafts up to
 ``spec_k`` tokens per sampling row from the row's own prompt+generated
 history; the engine writes pending token + drafts in ONE step at width
-``spec_k + 1`` and hands :meth:`finish_step` the greedy argmax at EVERY
+``spec_k + 1`` and hands :meth:`Scheduler.deliver` the greedy argmax at EVERY
 written position.  The longest draft prefix matching that chain is
 accepted plus the bonus token — token-identical to plain greedy by
 construction.  The pending invariant absorbs it because acceptance
@@ -194,6 +213,7 @@ class Request:
     slot: Optional[int] = None     # step-buffer row while active
     arrival: int = 0               # admission-order tiebreak
     preemptions: int = 0
+    in_flight: int = 0             # samples dispatched, not yet delivered
     # -- multi-tenant serving ----------------------------------------------
     # adapter slot this request decodes under (0 = base model); rides the
     # step buffers as the [B] int32 routing vector and namespaces the
@@ -294,6 +314,17 @@ class RowWork:
     # ``tokens``: drafts are a guess about the future, never pending work,
     # and ``num_computed`` only ever advances past the accepted ones
     draft: List[int] = dataclasses.field(default_factory=list)
+    # the row's one token is the previous step's sample, still on the
+    # device: ``tokens`` holds ``FED_TOKEN`` in its place and the step
+    # program takes the real one from that step's output
+    fed: bool = False
+    # ``req.preemptions`` as the plan saw it: a delivery that finds another
+    # count belongs to a life of the request that was preempted since
+    preemptions: int = 0
+
+
+# what a ``fed`` row carries in ``tokens`` (never read by the model)
+FED_TOKEN = 0
 
 
 @dataclasses.dataclass
@@ -303,6 +334,7 @@ class StepPlan:
     # even when every draft came back empty: draft length is data, not
     # shape) or prefill_chunk (any row still prefilling)
     step_width: int
+    tick: int = 0                      # the schedule() call that made it
 
     @property
     def active(self) -> List[RowWork]:
@@ -389,6 +421,9 @@ class Scheduler:
         self.spec_draft_faults = 0
         self.spec_verify_failures = 0
         self.tokens_appended = 0         # out_tokens grown, all rows
+        # rows a step computed for nothing: delivered to a request that had
+        # finished (an EOS learnt one step late, an abort) or been preempted
+        self.discarded_rows = 0
         # Accepted-tokens-per-sampling-row EWMA: the admission budget
         # guard prices prefill in STEPS, and speculation makes one step
         # worth >1 token — dividing the priced step count by this keeps
@@ -551,6 +586,7 @@ class Scheduler:
             return
         self._release(req)
         req.num_computed = 0
+        req.in_flight = 0
         req.state = RequestState.WAITING
         req.pinned = True
         if req not in self.waiting:
@@ -574,6 +610,7 @@ class Scheduler:
         req.slot = None
         req.blocks = []
         req.num_computed = 0
+        req.in_flight = 0
         # the dead engine's chain state died with its pools: the refs were
         # released by the harvest, and THIS scheduler's index re-seeds on
         # re-admission
@@ -654,6 +691,7 @@ class Scheduler:
             self.allocator.free(victim.blocks)
             victim.blocks = []
         victim.num_computed = 0          # recompute policy (see docstring)
+        victim.in_flight = 0             # its delivery will find it stale
         victim.state = RequestState.WAITING
         victim.preemptions += 1
         self.preemptions += 1
@@ -844,7 +882,8 @@ class Scheduler:
             return
         bs = self.block_size
         seq = req.seq
-        full = min(req.num_computed // bs, len(req.blocks))
+        # a position whose token is still in flight cannot be hashed yet
+        full = min(min(req.num_computed, len(seq)) // bs, len(req.blocks))
         while req.committed_blocks < full:
             i = req.committed_blocks
             # block 0 commits under the request's TENANT root, not the
@@ -951,21 +990,31 @@ class Scheduler:
         width = self.prefill_chunk if any_prefill else self._spec_width
         speculate = self.spec_proposer is not None and not any_prefill
         rows: List[Optional[RowWork]] = [None] * self.max_num_seqs
+        awaited = False
         for req in list(self.active):
             if req.slot is None:
                 continue       # preempted by an earlier row's allocation
-            t = min(len(req.pending), width)
-            samples_next = req.num_computed + t == req.seq_len
+            fed = req.in_flight > 0
+            if fed and (len(req.out_tokens) + req.in_flight
+                        >= req.max_new_tokens):
+                awaited = True     # its last sample is in flight: no row
+                continue
+            # a fed row's pending token is the sample in flight: one
+            # position, at num_computed, and it samples the one after
+            tokens = [FED_TOKEN] if fed else req.pending[:width]
+            t = len(tokens)
+            samples_next = fed or req.num_computed + t == req.seq_len
             draft = (self._propose_draft(req, width - t)
                      if speculate and samples_next else [])
             if not self._ensure_blocks(req, req.num_computed + t
                                        + len(draft)):
                 continue                       # preempted back to WAITING
             rows[req.slot] = RowWork(
-                req=req, tokens=req.pending[:t], start_pos=req.num_computed,
+                req=req, tokens=tokens, start_pos=req.num_computed,
                 samples_next=samples_next, draft=draft,
                 cow=((req.cow_src, req.cow_dst)
-                     if req.cow_dst is not None else None))
+                     if req.cow_dst is not None else None),
+                fed=fed, preemptions=req.preemptions)
         for i, w in enumerate(rows):
             if w is not None and w.req.slot != i:
                 # a LATER row's allocation preempted this already-planned
@@ -974,26 +1023,56 @@ class Scheduler:
                 # num_computed reset, so the stale RowWork must not run
                 rows[i] = None
         if not any(r is not None for r in rows):
+            if awaited:
+                return None    # nothing to run until the delivery retires them
             return self.schedule(now) if self.has_work() else None
-        return StepPlan(rows=rows, step_width=width)
+        return StepPlan(rows=rows, step_width=width, tick=self._ticks)
 
-    def finish_step(self, plan: StepPlan,
-                    sampled: Dict[int, Sequence[int]]) -> List[Request]:
-        """Apply one executed plan: advance ``num_computed``, append the
-        sampled tokens where the pending list emptied, retire finished
-        requests (freeing their blocks).  ``sampled`` maps slot -> the
-        row's greedy/sampled chain: entry 0 is the token after the last
+    def _stale(self, work: RowWork) -> bool:
+        """The row's request reached a terminal state, lost its slot or was
+        preempted (and perhaps re-admitted) since the plan was made: its
+        blocks were reclaimed and its replay state must not be moved by
+        what that plan computed."""
+        req = work.req
+        return (req.finished or req.slot is None
+                or req.preemptions != work.preemptions)
+
+    def advance(self, plan: StepPlan) -> None:
+        """The half of a step that needs no token, run when the plan is
+        DISPATCHED: ``num_computed`` moves past the tokens written, a COW
+        source whose copy rides this step is released (the device runs its
+        programs in order, so whoever is given the block next writes it
+        after the copy), and a sampling row counts one sample in flight."""
+        for work in plan.active:
+            req = work.req
+            if self._stale(work):
+                continue
+            req.num_computed += len(work.tokens)
+            if work.cow is not None and req.cow_src is not None:
+                # the COW copy rides this step: the private dst will hold
+                # the shared slots, so the source ref can be released
+                self.allocator.free([req.cow_src])
+                req.cow_src = None
+                req.cow_dst = None
+            if work.samples_next:
+                req.in_flight += 1
+
+    def deliver(self, plan: StepPlan,
+                sampled: Dict[int, Sequence[int]]) -> List[Request]:
+        """The half of a step that needs its tokens, run after the FETCH:
+        append the sampled tokens where the pending list emptied, retire
+        finished requests (freeing their blocks).  ``sampled`` maps slot ->
+        the row's greedy/sampled chain: entry 0 is the token after the last
         pending token (plain decode's one sample); entries ``1..d`` are
         the argmax AT the row's ``d`` draft positions — the verify read.
         The longest draft prefix matching the chain is accepted, plus the
         bonus token after it; ``num_computed`` advances past accepted
         drafts ONLY (their KV is valid), never the bonus token and never
         a rejected position — rejected slots are dead KV past the
-        high-water mark, overwritten by whatever comes next.  Rows whose
-        request reached a terminal state mid-step (an abort or watchdog
-        expiry issued between ``schedule()`` and here) are skipped —
-        their blocks were already reclaimed and their replay state must
-        not be advanced by stale device results."""
+        high-water mark, overwritten by whatever comes next.  Stale rows
+        (:meth:`_stale`: an abort or expiry issued since ``schedule()``, an
+        EOS the look-ahead learnt one step late, a preemption) are dropped
+        and counted in ``discarded_rows``."""
         done: List[Request] = []
         # The drilled verify-failure site: an armed ``spec_verify`` models
         # the whole verify step's draft results being unusable — EVERY
@@ -1012,22 +1091,18 @@ class Scheduler:
         appended_total = 0
         for work in plan.active:
             req = work.req
-            if req.finished or req.slot is None:
+            if self._stale(work):
+                self.discarded_rows += 1
                 continue
-            req.num_computed += len(work.tokens)
-            if work.cow is not None and req.cow_src is not None:
-                # the COW copy rode this step: the private dst now holds
-                # the shared slots, so the source ref can be released
-                self.allocator.free([req.cow_src])
-                req.cow_src = None
-                req.cow_dst = None
-            # Commit BEFORE acceptance: ``num_computed`` here covers no
-            # draft token, so an unaccepted draft can never reach the
-            # prefix index even transiently (accepted ones commit next
-            # step, once they are provably part of the sequence).
+            # Commit BEFORE acceptance and before the append: what is
+            # indexed covers no draft token and no token still in flight,
+            # so an unaccepted draft can never reach the prefix index even
+            # transiently (accepted ones commit next step, once they are
+            # provably part of the sequence).
             self._commit_full(req)
             if not work.samples_next:
                 continue
+            req.in_flight -= 1
             raw = sampled[req.slot]
             # a bare int is the no-draft chain of one (plain decode
             # callers — and the pre-speculation contract — pass scalars)
@@ -1068,7 +1143,7 @@ class Scheduler:
                     "serve_first_token", rid=req.rid,
                     queue_us=_us(admit - req.submit_time),
                     prefill_us=_us(t - admit),
-                    prefill_steps=self._ticks - req.admit_tick + 1,
+                    prefill_steps=plan.tick - req.admit_tick + 1,
                     prompt_len=len(req.prompt))
             if finish_reason is not None:
                 self.slots[req.slot] = None
@@ -1090,3 +1165,11 @@ class Scheduler:
             self._tokens_per_row_ewma = (
                 0.5 * self._tokens_per_row_ewma + 0.5 * mean)
         return done
+
+    def finish_step(self, plan: StepPlan,
+                    sampled: Dict[int, Sequence[int]]) -> List[Request]:
+        """Apply one executed plan at once: :meth:`advance`, then
+        :meth:`deliver` (the order of an engine that fetches a step before
+        it plans the next)."""
+        self.advance(plan)
+        return self.deliver(plan, sampled)

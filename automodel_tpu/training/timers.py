@@ -123,7 +123,8 @@ def restore_time_by_source(elapsed: Dict[str, float]) -> Dict[str, float]:
 # pools.  SERVE_PHASE_TIMERS split ``serve_step`` where the work happens:
 # scheduler (expiry, admission, preemption), buffer assembly, the call
 # into the jitted step (its span carries the step's row/position counts),
-# the one host sync, and the scheduler's ``finish_step``.  The outcome-rate
+# the one host sync (one step behind the dispatch where the engine looks
+# ahead), and the scheduler's ``deliver``.  The outcome-rate
 # helpers below read ``DecodeEngine.outcome_counts()``-shaped dicts
 # (state-name -> request count) — the four numbers the serving acceptance
 # bar pins under a 2x-capacity overload trace.

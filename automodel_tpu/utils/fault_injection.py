@@ -164,7 +164,7 @@ Serving-engine points (see ``serving/scheduler.py`` / ``serving/engine.py``):
                       identical greedy output, just no speedup), every
                       other row's drafts are unaffected, and the failure
                       is counted (``spec_draft_faults``).
-    spec_verify       in ``Scheduler.finish_step``, before draft
+    spec_verify       in ``Scheduler.deliver``, before draft
                       acceptance on a step that carried any draft — the
                       verify results being unusable.  Contract: every
                       draft of the step is DISCARDED with no partial

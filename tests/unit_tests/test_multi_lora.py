@@ -436,6 +436,8 @@ def test_adapter_decode_step_census_clean(model_and_params, adapters):
                            np.zeros((2,), np.int32),
                            np.zeros((2,), np.int32),
                            np.zeros((2,), np.int32),
+                           np.zeros((2,), np.int32),      # prev_tok
+                           np.zeros((2,), np.bool_),
                            np.zeros((2,), np.int32),
                            eng.adapter_slots.slabs)
     census = jaxpr_census(jaxpr)

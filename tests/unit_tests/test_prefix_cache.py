@@ -406,7 +406,9 @@ def test_compile_once_across_hits_misses_and_forks(model_and_params,
                            np.ones((4,), np.int32),
                            np.zeros((4,), np.int32),
                            np.zeros((4,), np.int32),
-                           np.zeros((4,), np.int32))
+                           np.zeros((4,), np.int32),
+                           np.zeros((4,), np.int32),      # prev_tok
+                           np.zeros((4,), np.bool_))
     census = jaxpr_census(jaxpr)
     assert not census.collectives, census.collectives
     assert not census.host_callbacks

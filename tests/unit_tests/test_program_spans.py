@@ -88,19 +88,31 @@ def test_engine_step_spans_and_request_events(engine_parts, tmp_path):
     stats = eng.stats()
     steps = [s for s in spans if s[0] == "serve_step"]
     ran = stats["steps"] - base["steps"]
-    assert len(steps) == ran + 1                # the idle call has one too
+    # run() makes one call more than it dispatches steps: the engine looks
+    # ahead, so the last call only fetches; then the idle call
+    assert len(steps) == ran + 2
     line = steps[0][3]
     dispatched = []
+    shapes = []
     for key, s, e, _, _ in steps:
         kids = [k for k in spans if k[0] in PHASES and s <= k[1]
                 and k[2] <= e and k[3] == line]
-        if len(kids) == 1:                      # idle: scheduled, no plan
-            assert kids[0][0] == "serve_schedule"
-            continue
-        assert [k[0] for k in kids] == PHASES   # all five, in order
         assert all(a[2] <= b[1] for a, b in zip(kids, kids[1:]))
-        dispatched.append(kids[2][4])
+        names = [k[0] for k in kids]
+        shapes.append(names)
+        if "serve_dispatch" in names:
+            dispatched.append(kids[2][4])
+    # the first call has nothing to fetch yet, the steady ones dispatch
+    # step N+1 BEFORE they fetch step N, the last only fetches, then idle
+    assert shapes[0] == PHASES[:3]
+    assert all(names == PHASES for names in shapes[1:-2])
+    assert shapes[-2] == ["serve_schedule", "serve_fetch", "serve_finish"]
+    assert shapes[-1] == ["serve_schedule"]
     assert len(dispatched) == ran
+    assert [d["ahead"] for d in dispatched] == [0] + [1] * (ran - 1)
+    assert stats["ahead_steps"] - base["ahead_steps"] == ran - 1
+    assert all(0 <= d["fed_rows"] <= d["rows"] for d in dispatched)
+    assert sum(d["fed_rows"] for d in dispatched) > 0
     assert [d["step"] for d in dispatched] == list(
         range(base["steps"], stats["steps"]))
     for key in ("rows", "positions", "slots"):
@@ -258,7 +270,7 @@ def test_paged_step_names_its_program_and_phases(engine_parts):
         text = eng.step_fn(width).lower(
             eng.params, eng.pools, i32(B, width), i32(B, width),
             i32(B, width), i32(B, MB), i32(B), i32(B), i32(B),
-            i32(B)).compile().as_text()
+            i32(B), i32(B), jnp.zeros((B,), jnp.bool_)).compile().as_text()
         assert text.startswith(f"HloModule jit_paged_step_w{width}")
         names = _op_names(text)
         for scope in ("embed", "layers", "attn", "kv_write", "attn_core",
@@ -428,7 +440,8 @@ def test_paged_step_updates_its_pools_in_place(
     compiled = eng.step_fn(width).lower(
         jax.tree.map(spec, eng.params), pools,
         i32(B, width), i32(B, width), i32(B, width), i32(B, MB), i32(B),
-        i32(B), i32(B), i32(B)).compile()
+        i32(B), i32(B), i32(B), i32(B),
+        jax.ShapeDtypeStruct((B,), jnp.bool_, sharding=one_chip)).compile()
     text = compiled.as_text()
 
     kernels = _kernels(text)
@@ -503,7 +516,8 @@ def test_latent_step_updates_its_plane_in_place(one_chip, monkeypatch, width):
     compiled = eng.step_fn(width).lower(
         jax.tree.map(spec, params), pools,
         i32(B, width), i32(B, width), i32(B, width), i32(B, MB), i32(B),
-        i32(B), i32(B), i32(B)).compile()
+        i32(B), i32(B), i32(B), i32(B),
+        jax.ShapeDtypeStruct((B,), jnp.bool_, sharding=one_chip)).compile()
     text = compiled.as_text()
 
     kernels = _kernels(text)
@@ -565,7 +579,8 @@ def test_retention_step_updates_its_state_planes_in_place(
     compiled = eng.step_fn(width).lower(
         jax.tree.map(spec, params), pools,
         i32(B, width), i32(B, width), i32(B, width), i32(B, 1), i32(B),
-        i32(B), i32(B), i32(B)).compile()
+        i32(B), i32(B), i32(B), i32(B),
+        jax.ShapeDtypeStruct((B,), jnp.bool_, sharding=one_chip)).compile()
     text = compiled.as_text()
     assert text.startswith(f"HloModule jit_paged_step_w{width}")
 

@@ -216,7 +216,9 @@ def test_decode_step_census_clean(model_and_params):
                            np.ones((2,), np.int32),
                            np.zeros((2,), np.int32),
                            np.zeros((2,), np.int32),
-                           np.zeros((2,), np.int32))
+                           np.zeros((2,), np.int32),
+                           np.zeros((2,), np.int32),      # prev_tok
+                           np.zeros((2,), np.bool_))
     census = jaxpr_census(jaxpr)
     assert not census.collectives, census.collectives
     assert not census.host_callbacks
@@ -311,7 +313,7 @@ def test_int8_kv_decode_parity_bounded(model_and_params, prompts,
         plan = e.scheduler.schedule()
         args = e._assemble(plan)
         _, last, _ = e.step_fn(plan.step_width)(e.params, e.pools, *args)
-        return np.asarray(last)
+        return np.asarray(last.logits)
 
     dev = np.max(np.abs(first_step_logits(None)
                         - first_step_logits("int8")))

@@ -916,7 +916,9 @@ def test_lifecycle_states_keep_compile_once_and_census_clean(
                            np.ones((4,), np.int32),
                            np.zeros((4,), np.int32),
                            np.zeros((4,), np.int32),
-                           np.zeros((4,), np.int32))
+                           np.zeros((4,), np.int32),
+                           np.zeros((4,), np.int32),      # prev_tok
+                           np.zeros((4,), np.bool_))
     census = jaxpr_census(jaxpr)
     assert not census.collectives, census.collectives
     assert not census.host_callbacks

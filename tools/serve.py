@@ -6,8 +6,10 @@ Loads a serving YAML (model + ``serving:`` knobs, see
 drives synthetic prompts — or, with ``--eval``, the config's
 ``validation_dataset`` rows through the greedy-continuation scorer — and
 prints one JSON report: tokens/s, engine stats (preemptions, peak blocks,
-compiled widths), the per-terminal-state outcome summary, and the eval
-score when asked.
+compiled widths; ``lookahead``, ``ahead_steps`` of ``steps`` dispatched
+while the step before was still unfetched, ``discarded_rows`` computed for
+a request that had already finished), the per-terminal-state outcome
+summary, and the eval score when asked.
 
 Robustness drills (docs/guides/serving.md "Production hardening"):
 
