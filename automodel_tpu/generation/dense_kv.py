@@ -54,8 +54,21 @@ class DenseKVView:
             jnp.arange(q_len, dtype=jnp.int32), (batch, q_len))
         return cls(pools, cache_index, positions, attention_mask)
 
-    def at_layer(self, pools, layer) -> "DenseKVView":
+    def at_layer(self, pools, layer, group=None) -> "DenseKVView":
+        """Standing at ``layer`` of the whole stack.  ``group`` (a family
+        whose paged cache is of several block groups names the layer's
+        there) is not this cache's concern: it keeps every token of every
+        layer, and a window layer masks."""
         return dataclasses.replace(self, pools=pools, layer=layer)
+
+    def valid_tokens(self) -> jnp.ndarray:
+        """``[B, S]`` bool: this forward's columns that hold a token (the
+        batch is left-padded: a prefill's pad columns are its first)."""
+        B, S = self.positions.shape
+        if self.attention_mask is None:
+            return jnp.ones((B, S), bool)
+        return lax.dynamic_slice_in_dim(
+            self.attention_mask.astype(bool), self.cache_index, S, axis=1)
 
     def write(self, k: jnp.ndarray, v: jnp.ndarray) -> Dict[str, jnp.ndarray]:
         """``[B, S, heads, D]`` k/v into the view's layer at
@@ -107,10 +120,7 @@ class DenseKVView:
         from automodel_tpu.ops import power_retention as pr
 
         B, S = q.shape[:2]
-        valid = (jnp.ones((B, S), bool) if self.attention_mask is None
-                 else lax.dynamic_slice_in_dim(
-                     self.attention_mask.astype(bool), self.cache_index, S,
-                     axis=1))
+        valid = self.valid_tokens()
         state, norm = self.pools["state"], self.pools["norm"]
         if S == 1:
             o, state, norm = pr.retention(

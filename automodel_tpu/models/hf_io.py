@@ -154,6 +154,26 @@ def qwen3_moe_key_map(config) -> Dict[Tuple[str, ...], HfSpec]:
     return m
 
 
+def smallthinker_key_map(config) -> Dict[Tuple[str, ...], HfSpec]:
+    """SmallThinker (``model_type: smallthinker``): Llama attention names
+    plus ``block_sparse_moe.primary_router`` and per-expert
+    ``block_sparse_moe.experts.{e}.gate/up/down``.  The names follow the
+    published ``modeling_smallthinker.py`` AS REMEMBERED (no checkpoint
+    could be read where this was written): check them against a
+    checkpoint's index before the first load."""
+    m = llama_key_map(config)
+    for proj in ("gate_proj", "up_proj", "down_proj"):
+        del m[("layers", "mlp", proj, "kernel")]
+    moe = "model.layers.{i}.block_sparse_moe."
+    m[("layers", "block_sparse_moe", "primary_router", "kernel")] = HfSpec(
+        moe + "primary_router.weight", stacked=True, transpose=True)
+    for w in ("gate", "up", "down"):
+        m[("layers", "block_sparse_moe", "experts", w, "kernel")] = HfSpec(
+            moe + f"experts.{{e}}.{w}.weight", stacked=True,
+            expert_stacked=True, transpose=True)
+    return m
+
+
 def deepseek_v2_key_map(config) -> Dict[Tuple[str, ...], HfSpec]:
     """DeepSeek-V2: the V3 map without the correction-bias tensor (the V2
     softmax gate has none)."""

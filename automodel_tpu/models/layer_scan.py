@@ -7,14 +7,19 @@ layer index running across the sub-stacks, the decode cache's state as
 scan CARRY, ``scan_block`` grouping and ``jax.checkpoint`` with the
 model's remat policy when not decoding.
 
-The decode cache is a protocol of three methods, and the loop and the
+The decode cache is a protocol of a few methods, and the loop and the
 families know it by those names alone (``serving/kv_cache.PagedKVView``
 and ``generation/dense_kv.DenseKVView`` implement it; nothing here imports
 either):
 
 * ``cache.pools`` — the state the scan carries (stacked over ALL layers);
   ``cache.positions`` — ``[B, S]`` positions of this step's tokens;
-* ``cache.at_layer(state, idx)`` — the cache standing at layer ``idx``;
+* ``cache.at_layer(state, idx)`` — the cache standing at layer ``idx``; a
+  family whose serving cache is of several BLOCK GROUPS (window and full
+  layers in one stack) adds ``group=(name, index among the group's
+  layers)``, which the paged view stands at and the dense view ignores;
+* ``cache.valid_tokens()`` — ``[B, S]`` bool, the columns of this forward
+  that hold a token (a routed layer keeps the others out of its routing);
 * ``cache.write(k, v) -> state`` and ``cache.attend(q, state, *, scale,
   logits_soft_cap, local_window_size)`` — what a layer's attention calls.
 """

@@ -188,6 +188,16 @@ def _ensure_builtin() -> None:
     # llama key map verbatim: Granite's deltas are scalars, not tensors
     register_model(ModelFamily("granite", GraniteConfig, GraniteForCausalLM,
                                hf_io.llama_key_map, ["GraniteForCausalLM"]))
+    from automodel_tpu.models.smallthinker import (
+        SmallThinkerConfig,
+        SmallThinkerForCausalLM,
+    )
+
+    # window and full (NoPE) layers in one stack, routed ReGLU experts
+    register_model(ModelFamily("smallthinker", SmallThinkerConfig,
+                               SmallThinkerForCausalLM,
+                               hf_io.smallthinker_key_map,
+                               ["SmallThinkerForCausalLM"]))
     from automodel_tpu.models.brumby import BrumbyConfig, BrumbyForCausalLM
 
     # Qwen3's block over power retention: no softmax attention, no KV cache

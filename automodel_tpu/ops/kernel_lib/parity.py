@@ -268,6 +268,17 @@ def paged_attention_cases() -> List[Dict]:
         dict(name="decode_first_layer", q_seq=1, dtype="float32", layer=0),
         dict(name="chunked_prefill_last_layer_int8_kv", q_seq=8,
              dtype="float32", quantized=True, layer=2),
+        # seven query heads a kv head (S * G = 7 rows at decode) and a
+        # window layer's walk: it starts at the first block the window
+        # touches, and ``released`` puts the null page in every entry
+        # before it, as the engine's window group leaves them
+        dict(name="decode_g7", q_seq=1, dtype="float32", Hq=7, Hk=1),
+        dict(name="decode_g7_window_released", q_seq=1, dtype="float32",
+             Hq=7, Hk=1, MB=8, window=24, released=True),
+        dict(name="chunked_prefill_g7_window_released", q_seq=8,
+             dtype="float32", Hq=14, Hk=2, MB=8, window=24, released=True),
+        dict(name="decode_bf16_g7_window_released", q_seq=1,
+             dtype="bfloat16", Hq=7, Hk=1, MB=8, window=40, released=True),
     ]
 
 
@@ -300,11 +311,17 @@ def build_paged_attention_case(case: Dict, *, B=2, Hq=4, Hk=2, D=128,
         k_scale = v_scale = None
     # scrambled, per-row-disjoint block tables (block 0 = null page)
     perm = rng.permutation(np.arange(1, NB)).reshape(B, MB)
-    block_tables = jnp.asarray(perm, jnp.int32)
     # ragged contexts: even rows nearly full, odd rows short
     ctx = np.asarray([MB * BS - 7 - 3 * (b // 2) if b % 2 == 0
                       else 2 * BS + 3 + b // 2 for b in range(B)], np.int32)
     ctx = np.maximum(ctx, S)
+    if case.get("released"):
+        from automodel_tpu.ops.paged_attention import window_first_block
+
+        for b in range(B):
+            perm[b, :window_first_block(int(ctx[b]) - S, int(case["window"]),
+                                        BS)] = 0
+    block_tables = jnp.asarray(perm, jnp.int32)
     positions = jnp.asarray(
         ctx[:, None] - S + np.arange(S)[None, :], jnp.int32)
     kwargs: Dict = {}
@@ -723,6 +740,11 @@ def chip_cases() -> Dict[str, List[Dict]]:
     at K=14336 are the VMEM-heaviest shape any of them sees."""
     l3b = dict(B=8, Hq=24, Hk=8, D=128, BS=16, MB=64)
     kimi = dict(Hq=64, R=640, V=512, BS=16, MB=1056, L=7, layer=5)
+    # SmallThinker-21B-A3B's cache as the serving cell holds it: seven query
+    # heads a kv head, blocks of 128, tables of 128 blocks (16,384
+    # positions), contexts to 16k; a window layer's walk starts at the first
+    # block the window (4,096) touches, the entries before it released
+    small = dict(B=8, Hq=28, Hk=4, D=128, BS=128, MB=128, L=2)
     brumby = dict(B=16, Hq=40, Hk=8, L=2, layer=1, ctx=64)
     mixtral_up = dict(m=4096, k=4096, n=14336, sizes=_ragged_sizes(4096, 8))
     mixtral_down = dict(m=4096, k=14336, n=4096, sizes=_ragged_sizes(4096, 8))
@@ -749,6 +771,13 @@ def chip_cases() -> Dict[str, List[Dict]]:
                  dtype="bfloat16", quantized=True, **l3b),
             dict(name="llama3_2_3b_prefill_chunk32", q_seq=32,
                  dtype="bfloat16", **l3b),
+            dict(name="smallthinker_decode_full_g7", q_seq=1,
+                 dtype="bfloat16", **small),
+            dict(name="smallthinker_decode_window_released", q_seq=1,
+                 dtype="bfloat16", window=4096, released=True, **small),
+            dict(name="smallthinker_prefill_chunk64_window_released",
+                 q_seq=64, dtype="bfloat16", window=4096, released=True,
+                 **small),
         ],
         # Kimi-K2's latent plane as the serving cell holds it: 7 layers,
         # 576 values a token stored 640 wide, 64 heads, value 512, tables

@@ -69,6 +69,11 @@ def resolve_moe_dispatch(dispatch: Optional[str]) -> str:
     return dispatch if dispatch is not None else DEFAULT_MOE_DISPATCH
 
 
+# An expert is ``(act(x W_gate) * x W_up) W_down``: SwiGLU (``silu``) for
+# Mixtral, Qwen3-MoE and DeepSeek; ReGLU (``relu``) for SmallThinker.
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
 def topk_routing(router_logits: jnp.ndarray, k: int, norm_topk: bool = True
                  ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """HF Mixtral routing: fp32 softmax over all experts, top-k, renormalize.
@@ -195,8 +200,10 @@ def moe_mlp_block(
     norm_topk: bool = True,
     dispatch: Optional[str] = None,
     quant=None,
+    router_input: Optional[jnp.ndarray] = None,     # [B, S, H]; None: x
+    activation: str = "silu",
 ) -> Tuple[jnp.ndarray, Tuple[jnp.ndarray, jnp.ndarray]]:
-    """Top-k routed SwiGLU expert FFN.  Returns ``(out [B, S, H],
+    """Top-k routed gated expert FFN.  Returns ``(out [B, S, H],
     (tokens_per_expert [k, E], router_prob [E]))`` — see
     :func:`routing_stats` for how to fold the stats into the aux loss.
 
@@ -212,6 +219,11 @@ def moe_mlp_block(
     routes the sorted path's grouped matmuls through the int8/fp8
     ``gmm_quant`` chain (models pass theirs through
     ``quant_for(self.quant, "<experts fqn>")`` so ``filter_fqns`` applies).
+
+    ``router_input``: the tensor the ROUTER reads where it is not the
+    experts' input (SmallThinker routes on the pre-attention normed stream
+    and feeds the experts the post-attention one).  ``activation``: the
+    gate's, a key of :data:`ACTIVATIONS`.
     """
     B, S, H = x.shape
     E = gate_kernel.shape[-1]
@@ -226,14 +238,18 @@ def moe_mlp_block(
     xg = constrain(xg, ("act_tokens", None, None))
 
     # Router in fp32 (HF computes gating in float32 for stability).
-    router_logits = xg.astype(jnp.float32) @ gate_kernel.astype(jnp.float32)
+    rg = xg
+    if router_input is not None:
+        rg, _ = group_tokens(router_input.reshape(T, H), M)
+        rg = constrain(rg, ("act_tokens", None, None))
+    router_logits = rg.astype(jnp.float32) @ gate_kernel.astype(jnp.float32)
     weights, idx, probs = topk_routing(router_logits, k,
                                        norm_topk=norm_topk)     # [G, M, k]
     weights, idx, valid = mask_padded_tokens(weights, idx, pad, E)
     aux = routing_stats(probs, idx, E, valid_tokens=valid)
     out = expert_ffn(xg, weights, idx, w_gate, w_up, w_down,
                      capacity=C, dispatch=dispatch, compute_dtype=cd,
-                     quant=quant)
+                     quant=quant, activation=activation)
     out = out.reshape(-1, H)
     if pad:
         out = out[:T]
@@ -252,6 +268,7 @@ def expert_ffn(
     dispatch: Optional[str] = None,
     compute_dtype: jnp.dtype = jnp.bfloat16,
     quant=None,
+    activation: str = "silu",
 ) -> jnp.ndarray:
     """Routing-agnostic expert-FFN dispatcher (shared by Mixtral softmax
     top-k and the DeepSeek sigmoid/softmax gates): ``sorted`` grouped-matmul
@@ -263,10 +280,11 @@ def expert_ffn(
     if resolve_moe_dispatch(dispatch) == "onehot":
         return expert_dispatch_ffn(xg, weights, idx, w_gate, w_up, w_down,
                                    capacity=capacity,
-                                   compute_dtype=compute_dtype)
+                                   compute_dtype=compute_dtype,
+                                   activation=activation)
     return sorted_expert_ffn(xg, weights, idx, w_gate, w_up, w_down,
                              capacity=capacity, compute_dtype=compute_dtype,
-                             quant=quant)
+                             quant=quant, activation=activation)
 
 
 def expert_dispatch_ffn(
@@ -279,8 +297,9 @@ def expert_dispatch_ffn(
     *,
     capacity: int,
     compute_dtype: jnp.dtype = jnp.bfloat16,
+    activation: str = "silu",
 ) -> jnp.ndarray:
-    """Static-shape dispatch/combine + expert-batched SwiGLU FFN — the
+    """Static-shape dispatch/combine + expert-batched gated FFN — the
     GShard one-hot formulation, kept as the sorted path's parity oracle."""
     G, M, H = xg.shape
     E = w_gate.shape[0]
@@ -307,7 +326,7 @@ def expert_dispatch_ffn(
     expert_in = constrain(expert_in, ("experts", "act_tokens", None, None))
     h_gate = jnp.einsum("egch,ehi->egci", expert_in, w_gate.astype(cd))
     h_up = jnp.einsum("egch,ehi->egci", expert_in, w_up.astype(cd))
-    h_act = jax.nn.silu(h_gate) * h_up
+    h_act = ACTIVATIONS[activation](h_gate) * h_up
     expert_out = jnp.einsum("egci,eih->egch", h_act, w_down.astype(cd))
     expert_out = constrain(expert_out, ("experts", "act_tokens", None, None))
     return jnp.einsum("egch,gmec->gmh", expert_out, combine)
@@ -342,6 +361,7 @@ def sorted_expert_ffn(
     compute_dtype: jnp.dtype = jnp.bfloat16,
     block_rows: int = 128,
     quant=None,
+    activation: str = "silu",
 ) -> jnp.ndarray:
     """Sort-based expert FFN: ``O(T*k*H*I)`` compute, no ``[.., E, C]``
     tensors.
@@ -427,7 +447,8 @@ def sorted_expert_ffn(
 
     h_gate = _mm(x_sorted, wg)
     h_up = _mm(x_sorted, wu)
-    h_act = constrain(jax.nn.silu(h_gate) * h_up, ("act_tokens", "expert_mlp"))
+    h_act = constrain(ACTIVATIONS[activation](h_gate) * h_up,
+                      ("act_tokens", "expert_mlp"))
     out_sorted = _mm(h_act, wd)
     out_sorted = constrain(out_sorted, ("act_tokens", None))
 
@@ -463,6 +484,8 @@ def decode_expert_ffn(
     *,
     layer,                    # int32 scalar: which layer's experts
     compute_dtype: jnp.dtype = jnp.bfloat16,
+    activation: str = "silu",
+    quant=None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """The serving step's expert FFN: dropless, no capacity tiles, work in
     proportion to the assignments there ARE.  A decode step routes a few
@@ -471,8 +494,8 @@ def decode_expert_ffn(
     expert; each expert then takes its own segment in ``DECODE_CHUNK``-row pieces
     (one piece under a ``cond`` when the step's tokens fit one, else a loop
     whose trip count is the segment's: an expert nobody chose runs nothing
-    and its weights are never read), gathers the tokens, runs its SwiGLU
-    and scatter-adds the weighted result.  Forward only (a ``while`` loop
+    and its weights are never read), gathers the tokens, runs its gated FFN
+    (``activation``) and scatter-adds the weighted result.  Forward only (a ``while`` loop
     has no transpose): training keeps :func:`expert_ffn`.
 
     ``layer`` may be traced (a layer scan's index): the weights are the
@@ -482,7 +505,13 @@ def decode_expert_ffn(
     operand, and the scan would first copy the layer's whole stack out of
     its ``xs`` to make it one (a gigabyte a layer at Kimi-K2's widths).
 
+    ``quant``: an enabled :class:`~automodel_tpu.ops.quant.QuantConfig`
+    runs an expert's three products through ``maybe_qdot`` (the model's
+    quantized-compute path, as its projections take it).
+
     Returns ``(out [T, H], tokens_per_expert [E] int32)``."""
+    from automodel_tpu.ops.quant import maybe_qdot
+
     T, H = x.shape
     E = w_gate.shape[1]
     k = idx.shape[-1]
@@ -497,6 +526,7 @@ def decode_expert_ffn(
     w_sorted = jnp.take(weights.reshape(N), order).astype(jnp.float32)
     lane = jnp.arange(chunk, dtype=jnp.int32)
     layer = jnp.asarray(layer, jnp.int32)
+    act = ACTIVATIONS[activation]
 
     def piece(e, c, out):
         rank = c * chunk + lane
@@ -507,7 +537,8 @@ def decode_expert_ffn(
         wg, wu, wd = (lax.dynamic_slice(
             m, (layer, e, 0, 0), (1, 1, *m.shape[2:]))[0, 0].astype(cd)
             for m in (w_gate, w_up, w_down))
-        y = (jax.nn.silu(xs @ wg) * (xs @ wu)) @ wd
+        mm = lambda a, w: maybe_qdot(a, w, quant, "experts")
+        y = mm(act(mm(xs, wg)) * mm(xs, wu), wd)
         return out.at[tok].add(y.astype(jnp.float32) * w[:, None])
 
     def expert(e, out):

@@ -38,7 +38,12 @@ Both rungs speak one request/operand contract (:func:`paged_attention`):
   and a slice of them would be a copy of one layer per layer per step.  A
   caller with one layer's pools passes ``L = 1, layer = 0``;
 * ``block_tables [B, MB]`` int32, ``context_lens [B]`` int32 (valid
-  positions INCLUDING tokens written this step).  Rows must satisfy
+  positions INCLUDING tokens written this step).  Under a static
+  ``local_window_size`` the entries before :func:`window_first_block` of a
+  row's first query are never read (the engine's window group has released
+  those blocks and left the null page there): the Pallas rung starts its
+  walk at that entry and walks :func:`window_span_blocks` entries, the
+  gather anchor reads and masks them.  Rows must satisfy
   ``context_lens >= 1`` and ``positions >= 0`` so every query has at least
   one attendable key (softmax never sees an all-masked row).
 """
@@ -53,6 +58,24 @@ import jax.numpy as jnp
 from automodel_tpu.ops.kernel_lib import registry
 
 _NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
+
+
+def window_span_blocks(window: int, q_len: int, block_size: int) -> int:
+    """The most blocks that hold the keys ``q_len`` consecutive queries see
+    through a window of ``window`` keys: ``window + q_len - 1`` consecutive
+    positions, wherever they start in a block.  What a window layer's walk
+    is long, and the most blocks of a window group a row holds at a step
+    of that width (``ceil(window / block_size) + 1`` at decode)."""
+    return -(-(window + q_len - 2) // block_size) + 1
+
+
+def window_first_block(first_position, window: int, block_size: int):
+    """The table index of the first block that the query at
+    ``first_position`` (a row's first this step; int or array) sees through
+    a window of ``window`` keys: every block before it lies wholly behind
+    the window, for this query and for every later one."""
+    behind = first_position - window + 1
+    return behind * (behind > 0) // block_size
 
 
 def dequantize_pool(pool: jnp.ndarray, scale: Optional[jnp.ndarray],
@@ -88,7 +111,7 @@ def gathered_cache(pool: jnp.ndarray, scale: Optional[jnp.ndarray], layer,
 def _paged_gather_impl(request, q, k_pool, v_pool, k_scale, v_scale, layer,
                        block_tables, context_lens, positions, *,
                        scale=None, logits_soft_cap=None,
-                       local_window_size=None):
+                       local_window_size=None, kernel_name=None):
     """XLA anchor: gather-by-table + masked SDPA, any query length."""
     B, S, Hq, D = q.shape
     Hk = k_pool.shape[3]
@@ -166,10 +189,13 @@ def build_paged_request(q, k_pool, *, quantized: bool,
 
 def paged_attention(q, k_pool, v_pool, *, layer, block_tables, context_lens,
                     positions, k_scale=None, v_scale=None, scale=None,
-                    logits_soft_cap=None, local_window_size=None):
+                    logits_soft_cap=None, local_window_size=None,
+                    kernel_name=None):
     """The serving path's attention entry point: build one request and
     resolve the ``attention.paged_decode -> attention.paged_gather`` chain
-    (see module docstring for the operand contract)."""
+    (see module docstring for the operand contract).  ``kernel_name``: the
+    name the Pallas rung's call bears in a trace (a cache of several block
+    groups names one kernel a group)."""
     request = build_paged_request(
         q, k_pool, quantized=k_scale is not None,
         soft_cap=logits_soft_cap is not None,
@@ -179,7 +205,7 @@ def paged_attention(q, k_pool, v_pool, *, layer, block_tables, context_lens,
         request, q, k_pool, v_pool, k_scale, v_scale, layer, block_tables,
         context_lens, positions, scale=scale,
         logits_soft_cap=logits_soft_cap,
-        local_window_size=local_window_size)
+        local_window_size=local_window_size, kernel_name=kernel_name)
 
 
 def _paged_gather_probe(request: Mapping[str, Any]) -> bool:

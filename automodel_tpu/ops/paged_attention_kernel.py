@@ -10,6 +10,14 @@ a flash-style online softmax in VMEM scratch; pages wholly past the row's
 context length are compute-skipped (their DMA fetches the engine's null
 page 0, which every pad table entry points at).
 
+**A window layer's walk** (static ``local_window_size``) is
+``window_span_blocks`` entries long, not the table's length, and STARTS at
+the first entry the row's window touches: that index rides scalar prefetch
+beside the context lengths (a full layer's program carries none) and is
+added to the grid index in every page's index map.  So the time of a window layer does not
+grow with the context past the window, and the entries before the start,
+whose blocks the engine's window group has released, are never fetched.
+
 **Chunked q**: the kernel serves any small query length ``S`` — plain
 decode (S=1), the speculative verify step (S=spec_k+1) and chunked
 prefill — by FOLDING the S query tokens into the query-group dim (one
@@ -42,7 +50,11 @@ import jax
 import jax.numpy as jnp
 
 from automodel_tpu.ops.kernel_lib import autotune, registry, tiling
-from automodel_tpu.ops.paged_attention import paged_reference
+from automodel_tpu.ops.paged_attention import (
+    paged_reference,
+    window_first_block,
+    window_span_blocks,
+)
 
 # Pallas interpret mode: lets the CPU test suite execute the real kernel
 # logic (tests monkeypatch this, mirroring ops/gmm_kernel.py).
@@ -107,16 +119,26 @@ def _head_tile(hk: int, g: int, s: int, bs: int, d: int, kv_itemsize: int,
     return int(choice[0])
 
 
-def _decode_kernel(bt_ref, cl_ref, p0_ref, ly_ref, q_ref, k_ref, v_ref,
-                   ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref, *, bs, kt, g,
-                   s_q, scale, soft_cap, window, quantized):
+def _decode_kernel(bt_ref, cl_ref, p0_ref, ly_ref, *refs, bs, kt, g, s_q,
+                   scale, soft_cap, window, quantized):
     from jax.experimental import pallas as pl
 
-    b, j = pl.program_id(0), pl.program_id(2)
+    b, i = pl.program_id(0), pl.program_id(2)
     nj = pl.num_programs(2)
+    if window is None:
+        j = i                    # a full layer walks the table from 0
+    else:
+        # a window layer's walk starts at the row's own entry: one scalar
+        # more on prefetch, which a full layer's program does not carry (a
+        # grid step of a skipped page is all scalar work, and every load
+        # in it shows: +9 % kernel time where the walk is mostly skips)
+        st_ref, *refs = refs
+        j = st_ref[b] + i
+    (q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref, m_ref, l_ref,
+     acc_ref) = refs
     ge = s_q * g                 # S query tokens folded into the group dim
 
-    @pl.when(j == 0)
+    @pl.when(i == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
@@ -166,7 +188,7 @@ def _decode_kernel(bt_ref, cl_ref, p0_ref, ly_ref, q_ref, k_ref, v_ref,
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
-    @pl.when(j == nj - 1)
+    @pl.when(i == nj - 1)
     def _finish():
         l = l_ref[:, :1]
         l = jnp.where(l == 0.0, 1.0, l)
@@ -209,6 +231,13 @@ def paged_decode_pallas(q, k_pool, v_pool, k_scale, v_scale, layer,
         pos0 = context_lens.astype(jnp.int32) - 1
     else:
         pos0 = positions[:, 0].astype(jnp.int32)
+    # a window layer walks the blocks its window spans, from the first one
+    # the row's first query sees; a full layer walks the table from 0
+    windowed = local_window_size is not None
+    walk, start = MB, ()
+    if windowed:
+        walk = min(MB, window_span_blocks(local_window_size, S, BS))
+        start = (window_first_block(pos0, local_window_size, BS),)
 
     # [B, S, Hq, D] -> [B, S, Hk, G, D] -> [B, Hk, S, G, D] -> fold (S, G)
     q4 = q.reshape(B, S, Hk, G, D).transpose(0, 2, 1, 3, 4).reshape(
@@ -226,13 +255,20 @@ def paged_decode_pallas(q, k_pool, v_pool, k_scale, v_scale, layer,
         k_scale = jnp.ones((1, 1, BS, Hk), jnp.float32)
         v_scale = jnp.ones((1, 1, BS, Hk), jnp.float32)
 
-    def page_index(b, h, j, bt, cl, p0, ly):
-        return (ly[0], bt[b, j], 0, h, 0)
+    def entry(b, i, bt, st):
+        if not windowed:
+            return bt[b, i]
+        # past the table's end the last entry again: no new fetch, and the
+        # body skips it (its keys would lie past any context)
+        return bt[b, jnp.minimum(st[0][b] + i, MB - 1)]
 
-    def scale_index(b, h, j, bt, cl, p0, ly):
-        return (0, bt[b, j] if quantized else 0, 0, h)
+    def page_index(b, h, i, bt, cl, p0, ly, *st):
+        return (ly[0], entry(b, i, bt, st), 0, h, 0)
 
-    def q_index(b, h, j, bt, cl, p0, ly):
+    def scale_index(b, h, i, bt, cl, p0, ly, *st):
+        return (0, entry(b, i, bt, st) if quantized else 0, 0, h)
+
+    def q_index(b, h, i, bt, cl, p0, ly, *st):
         return (b, h, 0, 0)
 
     out = pl.pallas_call(
@@ -241,8 +277,8 @@ def paged_decode_pallas(q, k_pool, v_pool, k_scale, v_scale, layer,
             soft_cap=logits_soft_cap, window=local_window_size,
             quantized=quantized),
         grid_spec=tiling.prefetch_grid_spec(
-            num_scalar_prefetch=4,
-            grid=(B, Hk // kt, MB),
+            num_scalar_prefetch=4 + windowed,
+            grid=(B, Hk // kt, walk),
             in_specs=[
                 tiling.block_spec((1, kt, GE, D), q_index),
                 # the layer axis is squeezed: the body sees one page
@@ -262,8 +298,8 @@ def paged_decode_pallas(q, k_pool, v_pool, k_scale, v_scale, layer,
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_INTERPRET,
     )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
-      pos0, jnp.asarray(layer, jnp.int32).reshape(1), q4, k_pool, v_pool,
-      k_scale, v_scale)
+      pos0, jnp.asarray(layer, jnp.int32).reshape(1), *start, q4, k_pool,
+      v_pool, k_scale, v_scale)
     # unfold (S, G) and restore [B, S, Hq, D]
     return out.reshape(B, Hk, S, G, D).transpose(0, 2, 1, 3, 4).reshape(
         B, S, Hq, D)
@@ -285,14 +321,17 @@ def _paged_decode_probe(request) -> bool:
 def _paged_decode_impl(request, q, k_pool, v_pool, k_scale, v_scale, layer,
                        block_tables, context_lens, positions, *,
                        scale=None, logits_soft_cap=None,
-                       local_window_size=None):
+                       local_window_size=None, kernel_name=None):
     # ``paged_decode`` is the name to read in a trace.  XLA:TPU names a
     # Mosaic custom call after the innermost component of its scope path,
     # and ``benchmark/rooflines/paged_decode.py`` finds this kernel by the
     # name it had while nothing was scoped: ``closed_call``, the layer
     # scan's body.  The inner scope keeps that name until the reader is
-    # repointed at ``paged_decode`` (ROADMAP Design 10); then it goes.
-    with jax.named_scope("paged_decode"), jax.named_scope("closed_call"):
+    # repointed at ``paged_decode`` (ROADMAP Design 10); then it goes.  A
+    # cache of several block groups runs one kernel a group in one program
+    # and names each (``PagedKVView.attend``).
+    with jax.named_scope("paged_decode"), \
+            jax.named_scope(kernel_name or "closed_call"):
         return paged_decode_pallas(
             q, k_pool, v_pool, k_scale, v_scale, layer, block_tables,
             context_lens, positions, scale=scale,

@@ -92,14 +92,18 @@ import jax.numpy as jnp
 import numpy as np
 
 from automodel_tpu.generation.generate import GenerationConfig, sample_logits
+from automodel_tpu.ops.paged_attention import window_span_blocks
 from automodel_tpu.serving.kv_cache import (
     DEFAULT_KV_CACHE_DTYPE,
     DEFAULT_PREFIX_CACHING,
     BlockAllocator,
+    BlockGroup,
+    CacheGroup,
     PagedKVView,
     PrefixIndex,
     StatePlaneView,
     blocks_needed,
+    cache_groups,
     cow_copy_blocks,
     init_paged_pools,
     init_state_planes,
@@ -152,7 +156,9 @@ class ServingConfig:
     kv_cache_dtype: Optional[str] = None     # None/"auto" -> compute dtype
     max_num_seqs: int = 8
     max_model_len: int = 1024
-    num_kv_blocks: Optional[int] = None      # None -> full residency + null
+    # None -> full residency + null; for a cache of several block groups a
+    # mapping {group name: blocks} (a group left out takes full residency)
+    num_kv_blocks: Optional[Any] = None
     prefill_chunk: int = 32
     scheduler_policy: Optional[str] = None   # None -> fcfs
     # -- robustness layer (docs/guides/serving.md "Production hardening") --
@@ -184,10 +190,18 @@ class ServingConfig:
             if not isinstance(v, int) or v < 1:
                 raise ValueError(
                     f"serving.{field} must be a positive int, got {v!r}")
-        if self.num_kv_blocks is not None and self.num_kv_blocks < 2:
+        if hasattr(self.num_kv_blocks, "to_dict"):          # ConfigNode
+            self.num_kv_blocks = self.num_kv_blocks.to_dict()
+        counts = (list(self.num_kv_blocks.values())
+                  if isinstance(self.num_kv_blocks, dict)
+                  else [] if self.num_kv_blocks is None
+                  else [self.num_kv_blocks])
+        if any(isinstance(n, bool) or not isinstance(n, int) or n < 2
+               for n in counts):
             raise ValueError(
-                "serving.num_kv_blocks must be >= 2 (1 null + 1 usable), "
-                f"got {self.num_kv_blocks!r}")
+                "serving.num_kv_blocks must be >= 2 (1 null + 1 usable), or "
+                "a mapping of block-group name to such a count, got "
+                f"{self.num_kv_blocks!r}")
         from automodel_tpu.config.loader import normalize_null_spelling
 
         for field in ("max_waiting", "max_preemptions", "sjf_aging_steps",
@@ -236,10 +250,24 @@ class ServingConfig:
     def blocks_per_seq(self) -> int:
         return blocks_needed(self.max_model_len, self.kv_block_size)
 
-    def resolved_num_blocks(self) -> int:
-        if self.num_kv_blocks is not None:
-            return self.num_kv_blocks
-        return self.max_num_seqs * self.blocks_per_seq + 1
+    def resolved_num_blocks(self, group: Optional[CacheGroup] = None) -> int:
+        """The pool's blocks (of ``group``, in a cache of several): what
+        the config names, else full residency — every row at
+        ``max_model_len``, or at what the group's window lets it hold."""
+        n = self.num_kv_blocks
+        if isinstance(n, dict):
+            if group is None or group.name is None:
+                raise ValueError(
+                    f"serving.num_kv_blocks {n!r} names block groups, but "
+                    "this model's cache has one unnamed group: give one count")
+            n = n.get(group.name)
+        if n is not None:
+            return n
+        per_seq = self.blocks_per_seq
+        if group is not None and group.window is not None:
+            per_seq = min(per_seq, window_span_blocks(
+                group.window, self.prefill_chunk, self.kv_block_size))
+        return self.max_num_seqs * per_seq + 1
 
 
 def build_serving_config(cfg: Any) -> ServingConfig:
@@ -393,25 +421,41 @@ class DecodeEngine:
         # MLA's one latent plane (refused with int8, loudly, there), or
         # per-sequence state (power retention), which takes no block pool
         planes = model.paged_cache_planes()
-        self.state_planes = sequence_planes(planes)
+        # the cache's block groups as the model declares them: one unnamed
+        # group for a flat declaration, whose pools, tables and slots the
+        # engine then holds bare and not under a name
+        self.cache_groups: List[CacheGroup] = cache_groups(
+            planes, mcfg.num_hidden_layers)
+        self.grouped = self.cache_groups[0].name is not None
+        self.state_planes = (not self.grouped) and sequence_planes(planes)
+        if self.grouped:
+            self._refuse_for_block_groups()
         if self.state_planes:
             self._refuse_for_state_planes(planes)
             # a row per step-buffer row; the allocator's blocks are
             # bookkeeping that never binds (its minimum: null + 1)
-            num_blocks, self.max_blocks_per_seq = 2, 1
+            num_blocks, self.max_blocks_per_seq = [2], 1
             self._new_pools = functools.partial(
                 init_state_planes, num_layers=mcfg.num_hidden_layers,
                 rows=self.config.max_num_seqs, planes=planes)
         else:
-            num_blocks = self.config.resolved_num_blocks()
+            num_blocks = [self.config.resolved_num_blocks(g)
+                          for g in self.cache_groups]
             self.max_blocks_per_seq = self.config.blocks_per_seq
-            self._new_pools = functools.partial(
-                init_paged_pools, num_layers=mcfg.num_hidden_layers,
-                planes=planes, num_blocks=num_blocks,
-                block_size=self.config.kv_block_size,
+            makers = [functools.partial(
+                init_paged_pools, num_layers=g.layers, planes=g.planes,
+                num_blocks=n, block_size=self.config.kv_block_size,
                 cache_dtype=cache_dtype, quantized=self.quantized)
+                for g, n in zip(self.cache_groups, num_blocks)]
+            self._new_pools = (
+                (lambda: {g.name: make() for g, make in
+                          zip(self.cache_groups, makers)})
+                if self.grouped else makers[0])
         self.pools = self._new_pools()
-        self.allocator = BlockAllocator(num_blocks)
+        self.block_groups = [
+            BlockGroup(g.name, BlockAllocator(n), g.window)
+            for g, n in zip(self.cache_groups, num_blocks)]
+        self.allocator = self.block_groups[0].allocator
         self.prefix_index: Optional[PrefixIndex] = None
         if (self.config.prefix_caching
                 or DEFAULT_PREFIX_CACHING) == "on":
@@ -471,7 +515,7 @@ class DecodeEngine:
             spec_k=self.spec_k,
             tenant_quota=self.config.tenant_quota,
             multi_tenant=self.adapter_slots is not None,
-            clock=clock, event=self.timers.event)
+            clock=clock, event=self.timers.event, groups=self.block_groups)
         self.requests: Dict[int, Request] = {}
         self.rejections: List[RequestRejected] = []
         self._rids = itertools.count()
@@ -504,6 +548,37 @@ class DecodeEngine:
         # clock stamp of the FIRST of the current run of no-progress steps
         # (None while the engine is productive or idle)
         self._no_progress_since: Optional[float] = None
+
+    def _refuse_for_block_groups(self) -> None:
+        """What is not wired for a cache of several block groups is refused
+        at build, each with what is missing."""
+        cfg = self.config
+        names = [g.name for g in self.cache_groups]
+        if (cfg.prefix_caching or DEFAULT_PREFIX_CACHING) != "off":
+            raise NotImplementedError(
+                f"serving.prefix_caching: on is not wired for the block "
+                f"groups {names}: the index keys ONE table's blocks, and a "
+                "block that a window group released while its request ran "
+                "is gone for whoever shares the prefix later (a hit would "
+                "need the window's blocks recomputed); serve this family "
+                "with prefix_caching: off")
+        if (cfg.speculative or DEFAULT_SPECULATIVE) != "off":
+            raise NotImplementedError(
+                f"serving.speculative: {cfg.speculative} is not wired for "
+                f"the block groups {names}: a window group releases blocks "
+                "by the row's first query position, and no test holds that "
+                "against a verify step that is rolled back; serve this "
+                "family with speculative: off")
+        if self.quantized:
+            raise NotImplementedError(
+                f"serving.kv_cache_dtype: int8 is not wired for the block "
+                f"groups {names}: the scale planes would need a pool a group "
+                "too and no test holds them; serve it in the compute dtype")
+
+    @property
+    def all_free(self) -> bool:
+        """The leak oracle: every block of every group is back."""
+        return self.scheduler.all_free
 
     def _refuse_for_state_planes(self, planes) -> None:
         """A per-sequence state plane has no blocks to share, no way back
@@ -753,8 +828,12 @@ class DecodeEngine:
         ids = np.zeros((B, W), np.int32)
         pos = np.zeros((B, W), np.int32)
         # pad/idle tokens write into the null page (block 0), slot col % bs
-        slots = np.tile(np.arange(W, dtype=np.int32) % bs, (B, 1))
-        tables = np.zeros((B, MB), np.int32)
+        # (one table and one slot mapping a block group, by name; a cache
+        # of one group holds them bare)
+        names = [g.name for g in self.cache_groups]
+        slots = {n: np.tile(np.arange(W, dtype=np.int32) % bs, (B, 1))
+                 for n in names}
+        tables = {n: np.zeros((B, MB), np.int32) for n in names}
         ctx = np.ones((B,), np.int32)       # idle rows: 1 (null-page key 0)
         last = np.zeros((B,), np.int32)
         # COW fork pairs: (0, 0) = null page onto itself = content no-op
@@ -780,16 +859,20 @@ class DecodeEngine:
             pos[b, :t] = np.arange(start, start + t)
             pos[b, t:] = start + t - 1      # pads clamp to the last valid
             if self.state_planes:
-                tables[b, 0] = b + 1        # the row holds a request
+                tables[None][b, 0] = b + 1  # the row holds a request
             else:
-                blocks = work.req.blocks
-                tables[b, :len(blocks)] = blocks
-                slots[b, :t] = [slot_for(blocks, p, bs)
-                                for p in range(start, start + t)]
+                for i, name in enumerate(names):
+                    blocks = (work.req.blocks if i == 0
+                              else work.req.group_blocks[name])
+                    tables[name][b, :len(blocks)] = blocks
+                    slots[name][b, :t] = [slot_for(blocks, p, bs)
+                                          for p in range(start, start + t)]
             ctx[b] = start + t
             last[b] = t - 1
             if work.cow is not None:
                 cow_src[b], cow_dst[b] = work.cow
+        if not self.grouped:
+            slots, tables = slots[None], tables[None]
         return (ids, pos, slots, tables, ctx, last, cow_src, cow_dst,
                 self._prev_tok, take_prev)
 
@@ -974,6 +1057,13 @@ class DecodeEngine:
             plan, step, greedy,
             last.logits if self.generation.do_sample else None, routed))
         self.scheduler.advance(plan)
+        if self.grouped:
+            # the keys one layer of each block group had to read this step
+            # (the context, or what of it the group's window still shows)
+            timers.event("serve_kv_read", step=step, rows=len(active),
+                         positions=positions,
+                         **{f"{name}_keys": n for name, n in
+                            self.scheduler.keys_read(plan).items()})
         if self.state_planes:
             resets = sum(1 for w in active if w.start_pos == 0)
             timers.event("serve_state", step=step, rows=len(active),
@@ -1162,6 +1252,12 @@ class DecodeEngine:
     def stats(self) -> Dict[str, Any]:
         idx = self.prefix_index
         sched = self.scheduler
+
+        def per_group(read):
+            if not self.grouped:
+                return read(self.allocator)
+            return {g.name: read(g.allocator) for g in self.block_groups}
+
         prefix = {
             "enabled": idx is not None,
             "lookups": idx.lookups if idx else 0,
@@ -1237,9 +1333,13 @@ class DecodeEngine:
             "watchdog_recoveries": self.watchdog_recoveries,
             "weight_syncs": self.weight_syncs,
             "kv_pool_bytes": pool_bytes(self.pools),
-            "kv_blocks_peak": self.allocator.peak_used,
-            "kv_blocks_free": self.allocator.free_blocks,
-            "failed_allocs": self.allocator.failed_allocs,
+            # a cache of several block groups: by group name
+            "kv_blocks_peak": per_group(lambda a: a.peak_used),
+            "kv_blocks_free": per_group(lambda a: a.free_blocks),
+            "failed_allocs": sum(g.allocator.failed_allocs
+                                 for g in self.block_groups),
+            # blocks a window group released while their request ran
+            "window_blocks_released": dict(self.scheduler.blocks_released),
             "compiled_widths": sorted(self._steps),
             "outcomes": self.outcome_counts(),
         }

@@ -584,7 +584,7 @@ class FleetRouter:
     def all_free(self) -> bool:
         """Every replica's allocator — live AND dead — fully drained: the
         fleet-wide leak oracle the drills assert."""
-        return all(r.engine.allocator.all_free for r in self.replicas)
+        return all(r.engine.all_free for r in self.replicas)
 
     def outcome_counts(self) -> Dict[str, int]:
         counts: Dict[str, int] = {}

@@ -34,6 +34,24 @@ grow with its context, so the allocator's blocks never bind for such a
 family (the scheduler is told a token costs nothing) and ``max_model_len``
 costs no HBM.
 
+A model whose layers are of two kinds, some keeping every key and some
+only a WINDOW of them, declares its cache PER GROUP of layers
+(``{"full": {"planes": {...}, "layers": n}, "window": {"planes": {...},
+"layers": m, "window": W}}``, :func:`cache_groups`) and gets a cache of
+BLOCK GROUPS: each group its own pools ``{name: {"k"|"v": [L_group,
+NB_group, BS, Hk, D]}}``, its own :class:`BlockAllocator` and its own block
+table per request (:class:`BlockGroup`); the view addresses the group of
+the layer it stands at (``at_layer(state, layer, group=(name, index))``).
+A window group's table is indexed by position like any other, but the
+scheduler RELEASES the blocks that lie wholly behind the window while the
+request runs and leaves the null page in their place, so a row never holds
+more than :func:`~automodel_tpu.ops.paged_attention.window_span_blocks` of
+them, whatever its context; the kernel starts its walk at the first block
+the window touches and never visits the released ones.  A cache declared
+flat (every family before) is a cache of ONE group and goes through the
+same code: its pools, tables and slot mappings are held bare instead of
+under a group's name.
+
 Block 0 is the reserved **null page**: pad tokens write into it and pad
 block-table entries point at it, so scatter/gather shapes stay static and
 garbage is never read (context-length masks exclude it).
@@ -246,6 +264,43 @@ class BlockAllocator:
             if not (self.prefix_index is not None
                     and self.prefix_index.retain_freed(b)):
                 self._free.append(b)
+
+
+@dataclasses.dataclass(frozen=True)
+class CacheGroup:
+    """One group of layers that share pools, an allocator and a block table
+    a request, as the model declares it.  ``name`` None: the cache's one
+    group, declared flat.  ``window``: keys behind a query that the
+    group's layers may still see (None: all of them)."""
+
+    name: Optional[str]
+    planes: Dict[str, Tuple]
+    layers: int
+    window: Optional[int] = None
+
+
+def cache_groups(planes: Dict[str, Any], num_layers: int) -> List[CacheGroup]:
+    """``model.paged_cache_planes()`` as a list of groups: a flat
+    declaration (``{plane: per-slot shape}``) is one unnamed group over all
+    layers; a grouped one is ``{name: {"planes", "layers", "window"}}``."""
+    if not all(isinstance(v, dict) for v in planes.values()):
+        return [CacheGroup(None, dict(planes), num_layers)]
+    groups = [CacheGroup(name, dict(g["planes"]), int(g["layers"]),
+                         g.get("window")) for name, g in planes.items()]
+    if sum(g.layers for g in groups) != num_layers:
+        raise ValueError(
+            f"cache groups {[(g.name, g.layers) for g in groups]} do not "
+            f"cover the model's {num_layers} layers")
+    return groups
+
+
+@dataclasses.dataclass
+class BlockGroup:
+    """The host's side of one cache group: its allocator and its window."""
+
+    name: Optional[str]
+    allocator: BlockAllocator
+    window: Optional[int] = None
 
 
 class PrefixIndex:
@@ -477,8 +532,8 @@ def init_state_planes(*, num_layers: int, rows: int,
             for name, per_row in planes.items()}
 
 
-def pool_bytes(pools: Dict[str, jnp.ndarray]) -> int:
-    return sum(int(x.size) * x.dtype.itemsize for x in pools.values())
+def pool_bytes(pools: Dict[str, Any]) -> int:
+    return sum(int(x.size) * x.dtype.itemsize for x in jax.tree.leaves(pools))
 
 
 @jax.tree_util.register_pytree_node_class
@@ -497,30 +552,45 @@ class PagedKVView:
     with the layer's index.  :meth:`write` and :meth:`attend` then address
     the stacked pools AT that layer, so the donated buffers are updated
     and read in place.
+
+    A cache of several block groups holds ``pools``, ``block_tables`` and
+    ``slot_mapping`` as dicts by group name and stands at a layer OF A
+    GROUP (``group``, static); a cache of one group holds them bare.
     """
 
-    pools: Dict[str, jnp.ndarray]
-    block_tables: jnp.ndarray     # [B, MB] int32
-    slot_mapping: jnp.ndarray     # [B, S] int32 flat slot per written token
+    pools: Dict[str, Any]
+    block_tables: Any             # [B, MB] int32 (by group name: a dict)
+    slot_mapping: Any             # [B, S] int32 flat slot per written token
     context_lens: jnp.ndarray     # [B] int32, INCLUDING this step's writes
     positions: jnp.ndarray        # [B, S] int32 absolute query positions
     layer: Any = None             # int32 scalar (traced in the layer scan)
     block_size: int = 16
     quantized: bool = False
+    group: Optional[str] = None   # the group the view stands in
 
     def tree_flatten(self):
         children = (self.pools, self.block_tables, self.slot_mapping,
                     self.context_lens, self.positions, self.layer)
-        return children, (self.block_size, self.quantized)
+        return children, (self.block_size, self.quantized, self.group)
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        return cls(*children, block_size=aux[0], quantized=aux[1])
+        return cls(*children, block_size=aux[0], quantized=aux[1],
+                   group=aux[2])
 
-    def at_layer(self, pools: Dict[str, jnp.ndarray], layer) -> "PagedKVView":
+    def at_layer(self, pools: Dict[str, Any], layer,
+                 group: Optional[Tuple[str, Any]] = None) -> "PagedKVView":
         """The view over ``pools`` (the stacked pools as the layer scan
-        carries them) standing at ``layer``."""
+        carries them) standing at ``layer``; in a cache of block groups,
+        at layer ``group[1]`` of the group named ``group[0]``."""
+        if group is not None:
+            return dataclasses.replace(self, pools=pools, layer=group[1],
+                                       group=group[0])
         return dataclasses.replace(self, pools=pools, layer=layer)
+
+    def _mine(self, x):
+        """``x`` (pools, tables, slots) of the group the view stands in."""
+        return x if self.group is None else x[self.group]
 
     # -- the model-facing seam (the cache protocol of models/layer_scan.py) --
     def write(self, k: jnp.ndarray, v: jnp.ndarray) -> Dict[str, jnp.ndarray]:
@@ -534,10 +604,10 @@ class PagedKVView:
         TPU keeps it NB-minor, and a reshape to rows would have every
         layer relay out the whole plane."""
         B, S, Hk, D = k.shape
-        pools = dict(self.pools)
+        pools = dict(self._mine(self.pools))
         NB, BS = pools["k"].shape[1:3]
         layer = jnp.asarray(self.layer, jnp.int32)
-        slot = self.slot_mapping.reshape(-1)
+        slot = self._mine(self.slot_mapping).reshape(-1)
         slots = layer * (NB * BS) + slot
         for name, x in (("k", k), ("v", v)):
             pool = pools[name]
@@ -554,6 +624,8 @@ class PagedKVView:
                 flat = flat.astype(pool.dtype)
             pools[name] = pool.reshape(-1, Hk, D).at[slots].set(
                 flat).reshape(pool.shape)
+        if self.group is not None:
+            return {**self.pools, self.group: pools}
         return pools
 
     def attend(self, q: jnp.ndarray, pools: Dict[str, jnp.ndarray], *,
@@ -564,24 +636,31 @@ class PagedKVView:
         ``attention.paged_decode`` chain."""
         from automodel_tpu.ops.paged_attention import paged_attention
 
+        pools = self._mine(pools)
         return paged_attention(
             q, pools["k"], pools["v"],
             k_scale=pools.get("k_scale"), v_scale=pools.get("v_scale"),
             layer=self.layer,
-            block_tables=self.block_tables, context_lens=self.context_lens,
+            block_tables=self._mine(self.block_tables),
+            context_lens=self.context_lens,
             positions=self.positions, scale=scale,
             logits_soft_cap=logits_soft_cap,
-            local_window_size=local_window_size)
+            local_window_size=local_window_size,
+            kernel_name=(None if self.group is None
+                         else f"paged_decode_{self.group}"))
 
     def valid_tokens(self) -> jnp.ndarray:
         """``[B, S]`` bool: the step buffer's columns that hold a token.
         The engine writes a row's tokens at consecutive positions and pads
         by repeating the last one, so a row holds ``last - first + 1``; an
         idle row's table is all null page (block 0 is never a request's)
-        and it holds none."""
+        and it holds none.  The entry asked is the one of the row's LAST
+        position: it is live in every group, a window group's too."""
         pos = self.positions
         held = pos[:, -1:] - pos[:, :1] + 1
-        busy = self.block_tables[:, :1] != 0
+        tables = jax.tree.leaves(self.block_tables)[0]    # any group's
+        last = (self.context_lens - 1) // self.block_size
+        busy = jnp.take_along_axis(tables, last[:, None], axis=1) != 0
         return (jnp.arange(pos.shape[1], dtype=pos.dtype)[None, :] < held) & busy
 
     # -- the latent plane's pair (deepseek_v3._mla_attention, absorbed form) --

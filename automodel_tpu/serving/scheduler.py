@@ -115,6 +115,19 @@ block survives any one holder's death.  Concurrent identical prompts
 uncached block is already being computed by an admitted twin waits one
 tick instead of paying a duplicate prefill — the group converges to ~1
 prompt prefill (the group-level rollout fork).
+
+A cache of several BLOCK GROUPS (``serving/kv_cache.BlockGroup``: a model
+with window and full layers in one stack): a request holds one block table
+a group — ``Request.blocks`` in the first, ``Request.group_blocks[name]``
+in each further one — and is admitted or grown only if EVERY group can
+serve it; what one group lacks is ``OutOfBlocks`` for the request, and
+preemption frees the victim's tables in all groups.  A group with a window
+RELEASES the leading blocks of a row that lie wholly behind the window of
+the row's first query of the step about to run (``Request.released``
+counts them; the null page takes their place in the table), before the
+step's own growth is allocated, so a row holds at most
+``window_span_blocks`` of them and a released block serves another row at
+once.  A cache of one group is the same code over a list of one.
 """
 
 from __future__ import annotations
@@ -125,8 +138,13 @@ import math
 import time
 from typing import Callable, Dict, List, Optional, Sequence
 
+from automodel_tpu.ops.paged_attention import (
+    window_first_block,
+    window_span_blocks,
+)
 from automodel_tpu.serving.kv_cache import (
     BlockAllocator,
+    BlockGroup,
     OutOfBlocks,
     PrefixIndex,
     blocks_needed,
@@ -209,6 +227,13 @@ class Request:
     state: RequestState = RequestState.WAITING
     out_tokens: List[int] = dataclasses.field(default_factory=list)
     blocks: List[int] = dataclasses.field(default_factory=list)
+    # a cache of several block groups: the tables of the groups after the
+    # first, by group name, and per group the leading blocks a window has
+    # released (their table entries hold the null page)
+    group_blocks: Dict[str, List[int]] = dataclasses.field(
+        default_factory=dict)
+    released: Dict[Optional[str], int] = dataclasses.field(
+        default_factory=dict)
     num_computed: int = 0          # tokens written to the KV cache
     slot: Optional[int] = None     # step-buffer row while active
     arrival: int = 0               # admission-order tiebreak
@@ -358,11 +383,21 @@ class Scheduler:
                  tenant_quota: Optional[int] = None,
                  multi_tenant: bool = False,
                  clock: Callable[[], float] = time.monotonic,
-                 event: Optional[Callable[..., None]] = None):
+                 event: Optional[Callable[..., None]] = None,
+                 groups: Optional[Sequence[BlockGroup]] = None):
         policy = validate_scheduler_policy(normalize_scheduler_policy(policy))
         shed_policy = validate_shed_policy(
             normalize_shed_policy(shed_policy))
         self.allocator = allocator
+        # the cache's block groups; ``allocator`` is the first group's
+        self.groups: List[BlockGroup] = (
+            list(groups) if groups else [BlockGroup(None, allocator)])
+        if self.groups[0].allocator is not allocator:
+            raise ValueError("the first block group's allocator must be "
+                             "the scheduler's own")
+        # blocks a window released while their request ran, by group name
+        self.blocks_released: Dict[Optional[str], int] = {
+            g.name: 0 for g in self.groups if g.window is not None}
         self.max_num_seqs = max_num_seqs
         self.prefill_chunk = prefill_chunk
         # None: the cache keeps per-SEQUENCE state (``kv_cache.
@@ -446,7 +481,14 @@ class Scheduler:
                 f"request {req.rid}: prompt {len(req.prompt)} + "
                 f"max_new_tokens {req.max_new_tokens} exceeds "
                 f"serving.max_model_len {self.max_model_len}")
-        worst = self._blocks_for(total)
+        for g in self.groups[1:]:
+            if self._most_blocks(g, total) > g.allocator.num_blocks - 1:
+                raise ValueError(
+                    f"request {req.rid} needs {self._most_blocks(g, total)} "
+                    f"KV blocks of group {g.name!r} but its pool has "
+                    f"{g.allocator.num_blocks - 1} — raise "
+                    "serving.num_kv_blocks / max_model_len")
+        worst = self._most_blocks(self.groups[0], total)
         if self.prefix_index is not None:
             # A prefix hit means the leading cached blocks are SHARED, not
             # consumed: discount them from the worst case (keeping a
@@ -559,9 +601,7 @@ class Scheduler:
             self.slots[req.slot] = None
             req.slot = None
         self._drop_chain_state(req)
-        if req.blocks:
-            self.allocator.free(req.blocks)
-            req.blocks = []
+        self._free_tables(req)
 
     def _drop_chain_state(self, req: Request) -> None:
         """Forget a request's prefix-chain bookkeeping: release the held
@@ -608,7 +648,7 @@ class Scheduler:
         self._arrivals += 1
         req.submit_tick = self._ticks
         req.slot = None
-        req.blocks = []
+        req.blocks, req.group_blocks, req.released = [], {}, {}
         req.num_computed = 0
         req.in_flight = 0
         # the dead engine's chain state died with its pools: the refs were
@@ -675,21 +715,93 @@ class Scheduler:
             return 0
         return blocks_needed(tokens, self.block_size)
 
-    def _allocate(self, n: int) -> List[int]:
+    def _most_blocks(self, group: BlockGroup, tokens: int) -> int:
+        """The most blocks of ``group`` a request of ``tokens`` positions
+        holds at once: a window keeps what a step's queries can see."""
+        n = self._blocks_for(tokens)
+        if group.window is None or self.block_size is None:
+            return n
+        return min(n, window_span_blocks(group.window, self.prefill_chunk,
+                                         self.block_size))
+
+    def _tables(self, req: Request):
+        """``(group, table)`` of every block group: ``req.blocks`` in the
+        first, ``req.group_blocks[name]`` in each further one."""
+        first = (self.groups[0], req.blocks)
+        if len(self.groups) == 1:       # every step asks, of every row
+            return (first,)
+        return [first] + [(g, req.group_blocks.setdefault(g.name, []))
+                          for g in self.groups[1:]]
+
+    def _free_tables(self, req: Request) -> None:
+        """Decref every live block of ``req`` in every group (an entry a
+        window released holds the null page and is nobody's)."""
+        for g, table in self._tables(req):
+            if g.window is not None:
+                table = [b for b in table if b]
+            if table:
+                g.allocator.free(table)
+        req.blocks = []
+        req.group_blocks = {}
+        req.released = {}
+
+    def _release_behind_window(self, req: Request) -> None:
+        """Release, in every group with a window, the leading blocks that
+        lie wholly behind the window of the row's next query (position
+        ``num_computed``) and so of every later one."""
+        if not self.blocks_released:
+            return                      # no group has a window
+        for g, table in self._tables(req):
+            if g.window is None:
+                continue
+            done = req.released.get(g.name, 0)
+            dead = min(window_first_block(req.num_computed, g.window,
+                                          self.block_size), len(table))
+            if dead > done:
+                g.allocator.free(table[done:dead])
+                table[done:dead] = [0] * (dead - done)
+                req.released[g.name] = dead
+                self.blocks_released[g.name] += dead - done
+
+    @property
+    def all_free(self) -> bool:
+        """The leak oracle over every block group."""
+        return all(g.allocator.all_free for g in self.groups)
+
+    def keys_read(self, plan: "StepPlan") -> Dict[Optional[str], int]:
+        """Per block group, the keys one of its layers must read in
+        ``plan``'s step: over the active rows, the context, or what of it
+        the group's window still shows."""
+        ctx = [w.start_pos + len(w.tokens) for w in plan.active]
+        return {g.name: sum(c if g.window is None else min(c, g.window)
+                            for c in ctx) for g in self.groups}
+
+    def _allocate(self, req: Request, new_total: int) -> None:
+        """Grow every group's table of ``req`` to ``new_total`` positions,
+        all groups or none (:class:`OutOfBlocks` from the group that
+        lacks, nothing handed out)."""
+        blocks = self._blocks_for(new_total)
+        wants = [(g, table, blocks - len(table))
+                 for g, table in self._tables(req)]
+        if not any(need > 0 for _, _, need in wants):
+            return
         # The drilled KV-exhaustion site: an armed ``serve_block_alloc``
         # fires here exactly like a genuinely empty free list, and the
         # caller's preemption path must absorb both identically.
         fault_point("serve_block_alloc")
-        return self.allocator.allocate(n)
+        for g, _, need in wants[1:]:
+            if need > g.allocator.free_blocks:
+                g.allocator.allocate(need)      # raises, and counts it
+        for g, table, need in wants:
+            if need > 0:
+                table.extend(g.allocator.allocate(need))
 
     def _preempt(self, victim: Request) -> None:
         assert victim.slot is not None
         self.slots[victim.slot] = None
         victim.slot = None
         self._drop_chain_state(victim)
-        if victim.blocks:
-            self.allocator.free(victim.blocks)
-            victim.blocks = []
+        self._free_tables(victim)
         victim.num_computed = 0          # recompute policy (see docstring)
         victim.in_flight = 0             # its delivery will find it stale
         victim.state = RequestState.WAITING
@@ -707,12 +819,14 @@ class Scheduler:
         """Grow ``req``'s block table to cover ``new_total`` positions,
         preempting strictly-younger UNPINNED active requests (youngest
         first) while the pool is exhausted; parks ``req`` itself when no
-        victim remains.  Returns False when ``req`` was preempted."""
+        victim remains.  Returns False when ``req`` was preempted.  What a
+        window no longer shows is released first: it may be all the step
+        needs."""
+        self._release_behind_window(req)
         need = self._blocks_for(new_total) - len(req.blocks)
         while True:
             try:
-                if need > 0:
-                    req.blocks.extend(self._allocate(need))
+                self._allocate(req, new_total)
                 return True
             except (OutOfBlocks, InjectedFault) as e:
                 younger = [r for r in self.active
@@ -722,6 +836,7 @@ class Scheduler:
                     self._preempt(max(younger, key=lambda r: r.arrival))
                     continue
                 if (len(self.active) > 1 or req.blocks
+                        or any(req.group_blocks.values())
                         or isinstance(e, InjectedFault)):
                     # an injected alloc failure is always absorbed as a
                     # preemption (the drilled contract: never a crash);
@@ -845,9 +960,7 @@ class Scheduler:
         """Back out a prefix seed when admission bounced AFTER seeding:
         refs return to the allocator and the request is cold again."""
         self._drop_chain_state(req)
-        if req.blocks:
-            self.allocator.free(req.blocks)
-            req.blocks = []
+        self._free_tables(req)
         req.num_computed = 0
 
     def _register_inflight(self, req: Request) -> None:
@@ -929,8 +1042,9 @@ class Scheduler:
                 self.expire(req, reason="budget")
                 continue
             first_chunk = min(len(req.pending), self.prefill_chunk)
-            if (self.block_size is not None and self.allocator.free_blocks
-                    * self.block_size < first_chunk):
+            if self.block_size is not None and any(
+                    g.allocator.free_blocks * self.block_size < first_chunk
+                    for g in self.groups):
                 self._unseed(req)
                 continue         # in-flight admission waits for frees
             self.waiting.remove(req)
@@ -1149,9 +1263,7 @@ class Scheduler:
                 self.slots[req.slot] = None
                 req.slot = None
                 self._drop_chain_state(req)
-                if req.blocks:
-                    self.allocator.free(req.blocks)
-                    req.blocks = []
+                self._free_tables(req)
                 self._terminal(req, RequestState.FINISHED, finish_reason)
                 self._tenant(req)["finished"] += 1
                 done.append(req)
