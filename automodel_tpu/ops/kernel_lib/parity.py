@@ -44,6 +44,7 @@ CPU_EXECUTABLE = {
     "attention.retention_chunk", "attention.retention_chunk_xla",
     "linear_ce.pallas", "linear_ce.chunked",
     "gmm.pallas", "gmm.xla_blocked", "gmm.ragged",
+    "moe_decode.pallas", "moe_decode.loop",
     "qdot.pallas", "qdot.xla",
     "gmm_quant.pallas", "gmm_quant.xla_blocked", "gmm_quant.dense",
 }
@@ -52,6 +53,7 @@ _INTERPRET_MODULES = (
     "automodel_tpu.ops.splash_attention",
     "automodel_tpu.ops.linear_ce_kernel",
     "automodel_tpu.ops.gmm_kernel",
+    "automodel_tpu.ops.moe_decode_kernel",
     "automodel_tpu.ops.qdot_kernel",
     "automodel_tpu.ops.paged_attention_kernel",
     "automodel_tpu.ops.mla_paged_attention_kernel",
@@ -639,6 +641,111 @@ def run_gmm_parity(spec_name: str, case: Dict, native: bool = False,
 
 
 # ---------------------------------------------------------------------------
+# moe_decode family (the serving step's routed experts)
+# ---------------------------------------------------------------------------
+def moe_decode_cases() -> List[Dict]:
+    """One layer of ``E`` experts out of a stack of ``L`` on ``T`` tokens
+    that each choose ``k`` of ``total`` experts, the first ``E`` of them held
+    (an assignment to another goes to the sentinel, as
+    ``ops/moe.held_experts_local`` sends it).  ``nobody``: experts whose
+    assignments are taken away; ``pad_rows``: rows that choose nothing;
+    ``everyone``: every token chooses every expert; ``tiles``: ``(tH, tI)``
+    forced on the Pallas rung, so that an expert spans several grid steps
+    at a toy's widths."""
+    base = dict(T=24, H=256, I=256, E=8, k=3, L=3, layer=1,
+                tiles=(128, 128))
+    return [
+        dict(base, name="relu", activation="relu"),
+        dict(base, name="silu_bf16", dtype="bfloat16"),
+        dict(base, name="relu_bf16_whole_matrices", dtype="bfloat16",
+             activation="relu", tiles=None),
+        dict(base, name="experts_nobody_chose", nobody=(0, 5, 7)),
+        dict(base, name="every_expert_chosen", E=4, k=4, everyone=True),
+        dict(base, name="one_token", T=1, k=2),
+        dict(base, name="padded_rows", pad_rows=(0, 7, 23)),
+        dict(base, name="experts_held_elsewhere", total=24, k=6),
+        dict(base, name="nothing_routed_here", total=24, k=2, E=2,
+             nobody=(0, 1)),
+        dict(base, name="rows_48", T=48, tiles=(256, 128)),
+        dict(base, name="rows_64_last_layer", T=64, layer=2,
+             tiles=(128, 256)),
+        dict(base, name="rows_21_first_layer", T=21, layer=0),
+    ]
+
+
+def build_moe_decode_case(case: Dict, seed: int = 5):
+    """``(args, kwargs, request)`` of a ``moe_decode`` rung: ``x, weights,
+    idx, w_gate, w_up, w_down, layer`` with every layer of the stacks
+    holding other values, so that an expert read from another layer
+    shows."""
+    T, H, I, E, k, L = (case[n] for n in "T H I E k L".split())
+    total = case.get("total", E)
+    dtype = jnp.dtype(case.get("dtype", "float32"))
+    keys = iter(jax.random.split(jax.random.key(seed), 8))
+    x = jax.random.normal(next(keys), (T, H), jnp.float32).astype(dtype)
+    w_gate, w_up = (
+        (jax.random.normal(next(keys), (L, E, H, I), jnp.float32)
+         * H ** -0.5).astype(dtype) for _ in range(2))
+    w_down = (jax.random.normal(next(keys), (L, E, I, H), jnp.float32)
+              * I ** -0.5).astype(dtype)
+    scores = jax.nn.softmax(
+        jax.random.normal(next(keys), (T, total), jnp.float32))
+    if case.get("everyone"):
+        weights = scores[:, :k]
+        idx = jnp.broadcast_to(jnp.arange(k, dtype=jnp.int32), (T, k))
+    else:
+        weights, idx = jax.lax.top_k(scores, k)
+    gone = ((idx >= E) | jnp.isin(idx, jnp.asarray(case.get("nobody", ()),
+                                                   jnp.int32))
+            | jnp.isin(jnp.arange(T), jnp.asarray(case.get("pad_rows", ()),
+                                                  jnp.int32))[:, None])
+    idx = jnp.where(gone, E, idx).astype(jnp.int32)
+    weights = jnp.where(gone, 0.0, weights)
+    request = {"kind": "moe_decode", "rows": T, "hidden": H, "inter": I,
+               "experts": E, "quantized": False, "devices": 1,
+               "dtype": str(dtype)}
+    kwargs = dict(compute_dtype=dtype,
+                  activation=case.get("activation", "silu"))
+    return ((x, weights, idx, w_gate, w_up, w_down,
+             jnp.int32(case["layer"])), kwargs, request)
+
+
+def run_moe_decode_parity(spec_name: str, case: Dict,
+                          native: bool = False) -> float:
+    """The rung under ``jit`` (``layer`` is traced, as a layer scan hands
+    it) against the dense float32 oracle, and its ``tokens_per_expert``
+    equal to the oracle's count."""
+    from automodel_tpu.ops.kernel_lib import autotune
+
+    spec = registry.get_kernel(spec_name)
+    args, kwargs, request = build_moe_decode_case(case)
+    tiles = case.get("tiles")
+    with contextlib.ExitStack() as stack:
+        if tiles:
+            stack.enter_context(autotune.forced("moe_decode", tiles))
+        if native:
+            assert not interpret_flags_on()
+        else:
+            stack.enter_context(interpret_mode())
+        out, counts = jax.jit(
+            lambda *a: spec.impl(request, *a, **kwargs))(*args)
+    with jax.default_matmul_precision("highest"):
+        ref, ref_counts = jax.jit(
+            lambda *a: spec.reference(request, *a, **kwargs))(*args)
+    what = f"{spec_name} on {case['name']}"
+    np.testing.assert_array_equal(
+        np.asarray(counts), np.asarray(ref_counts),
+        err_msg=f"{what}: tokens_per_expert")
+    assert out.shape == ref.shape and out.dtype == ref.dtype, what
+    if not np.any(np.asarray(ref_counts)):
+        assert not np.any(np.asarray(out, np.float32)), what
+        return 0.0
+    tol = _tol(str(args[0].dtype), native,
+               2e-2 if args[0].dtype == jnp.bfloat16 else 2e-5)
+    return _compare(out, ref, tol, native, what)
+
+
+# ---------------------------------------------------------------------------
 # qdot family (quantized matmul)
 # ---------------------------------------------------------------------------
 def qdot_cases() -> List[Dict]:
@@ -813,6 +920,18 @@ def chip_cases() -> Dict[str, List[Dict]]:
             dict(name="mixtral_8x7b_up", dtype="bfloat16", **mixtral_up),
             dict(name="mixtral_8x7b_down", dtype="bfloat16", **mixtral_down),
             dict(name="moonlight_16b_up", dtype="bfloat16", **moonlight_up),
+        ],
+        # the routed experts of a decode step as the two serving cells hold
+        # them: Kimi-K2's 12 held experts of 88 MB on 64 rows (8 of 384
+        # chosen: most assignments go elsewhere), SmallThinker's 64 of
+        # 11.8 MB on 48 rows (6 of 64); two layers of the stack, the second
+        # addressed
+        "moe_decode.pallas": [
+            dict(name="kimi_k2_12_held_64rows", T=64, H=7168, I=2048, E=12,
+                 k=8, total=384, L=2, layer=1, dtype="bfloat16"),
+            dict(name="smallthinker_64_experts_48rows", T=48, H=2560, I=768,
+                 E=64, k=6, L=2, layer=1, dtype="bfloat16",
+                 activation="relu", pad_rows=(5, 40)),
         ],
         "qdot.pallas": [
             dict(name="llama3_1_8b_up_int8_tensorwise", m=4096, k=4096,

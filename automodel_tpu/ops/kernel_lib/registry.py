@@ -161,6 +161,8 @@ _DEFAULT_KERNEL_MODULES = (
     ("automodel_tpu.ops.linear_ce_kernel", "linear_ce.pallas"),
     ("automodel_tpu.loss.linear_ce", "linear_ce.chunked"),
     ("automodel_tpu.ops.gmm_kernel", "gmm.pallas"),
+    ("automodel_tpu.ops.moe_decode_kernel", "moe_decode.pallas"),
+    ("automodel_tpu.ops.moe", "moe_decode.loop"),
     ("automodel_tpu.ops.qdot_kernel", "qdot.pallas"),
     ("automodel_tpu.ops.quant", "qdot.xla"),
     ("automodel_tpu.ops.gmm_quant_kernel", "gmm_quant.pallas"),

@@ -38,6 +38,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from automodel_tpu.distributed.shardings import constrain
+from automodel_tpu.ops.kernel_lib import registry
+from automodel_tpu.ops.moe_decode_kernel import moe_decode_reference
 
 # ``moe.dispatch`` knob (config-load enum-validated like cp_layout; null
 # spellings mean "use the default").
@@ -474,6 +476,19 @@ def held_experts_local(weights: jnp.ndarray, idx: jnp.ndarray, first: int,
 DECODE_CHUNK = 256      # rows of one expert's segment multiplied at a time
 
 
+def _devices_spanned(stack) -> int:
+    """The devices an expert stack lies on, as far as the call can see: an
+    array says so itself; a traced one lies on the mesh of the sharding
+    context its forward is built under, on one device where none is
+    active."""
+    if isinstance(stack, jax.Array) and not isinstance(stack, jax.core.Tracer):
+        return len(stack.sharding.device_set)
+    from automodel_tpu.distributed.shardings import current_sharding
+
+    ctx = current_sharding()
+    return 1 if ctx is None else ctx[0].size
+
+
 def decode_expert_ffn(
     x: jnp.ndarray,           # [T, H] tokens
     weights: jnp.ndarray,     # [T, k] combine weights
@@ -490,26 +505,49 @@ def decode_expert_ffn(
     """The serving step's expert FFN: dropless, no capacity tiles, work in
     proportion to the assignments there ARE.  A decode step routes a few
     dozen tokens, so most experts see one or two and some none; what an
-    expert costs is the read of its weights.  The assignments are sorted by
-    expert; each expert then takes its own segment in ``DECODE_CHUNK``-row pieces
-    (one piece under a ``cond`` when the step's tokens fit one, else a loop
-    whose trip count is the segment's: an expert nobody chose runs nothing
-    and its weights are never read), gathers the tokens, runs its gated FFN
-    (``activation``) and scatter-adds the weighted result.  Forward only (a ``while`` loop
-    has no transpose): training keeps :func:`expert_ffn`.
+    expert costs is the read of its weights, and an expert nobody chose is
+    never read.  Forward only: training keeps :func:`expert_ffn`.
+
+    Two forms behind one registry chain, chosen by what the call sees:
+
+    * ``moe_decode.pallas`` (``ops/moe_decode_kernel.py``): ONE Mosaic call
+      a layer that walks the hit experts and streams their weights back to
+      back.  It takes decode-width steps (every hit expert multiplies all
+      ``T`` rows, so ``T`` is bounded there) of unquantized, lane-aligned
+      experts on a TPU whose stacks lie whole on the device of the step;
+    * ``moe_decode.loop``: the assignments sorted by expert, each expert
+      taking its own segment in ``DECODE_CHUNK``-row pieces under a loop
+      whose trip count is the segment's (none for an expert nobody chose):
+      a gather, the gated FFN (``activation``), a scatter-add.  The wide
+      (mixed) step, where an expert's rows are a small share of ``T``; a
+      CPU; an enabled ``quant``
+      (:class:`~automodel_tpu.ops.quant.QuantConfig`), whose three products
+      go through ``maybe_qdot`` as the model's projections do.
 
     ``layer`` may be traced (a layer scan's index): the weights are the
-    stacks of ALL layers and an expert's matrices are sliced at ``(layer,
-    expert)`` right where they are multiplied.  Handed one
-    layer's ``[E, H, I]`` slice, the loops below would take it as an
-    operand, and the scan would first copy the layer's whole stack out of
-    its ``xs`` to make it one (a gigabyte a layer at Kimi-K2's widths).
-
-    ``quant``: an enabled :class:`~automodel_tpu.ops.quant.QuantConfig`
-    runs an expert's three products through ``maybe_qdot`` (the model's
-    quantized-compute path, as its projections take it).
+    stacks of ALL layers and an expert's matrices are addressed at ``(layer,
+    expert)`` right where they are multiplied.  Handed one layer's ``[E, H,
+    I]`` slice, either form would take it as an operand, and the scan would
+    first copy the layer's whole stack out of its ``xs`` to make it one (a
+    gigabyte a layer at Kimi-K2's widths).
 
     Returns ``(out [T, H], tokens_per_expert [E] int32)``."""
+    request = {
+        "kind": "moe_decode", "rows": x.shape[0], "hidden": x.shape[1],
+        "inter": w_gate.shape[-1], "experts": w_gate.shape[1],
+        "quantized": bool(quant is not None
+                          and getattr(quant, "enabled", False)),
+        "devices": _devices_spanned(w_gate),
+        "dtype": str(jnp.dtype(compute_dtype))}
+    return registry.dispatch(
+        "moe_decode.pallas", request, x, weights, idx, w_gate, w_up, w_down,
+        jnp.asarray(layer, jnp.int32), compute_dtype=compute_dtype,
+        activation=activation, quant=quant)
+
+
+def _decode_loop_impl(request, x, weights, idx, w_gate, w_up, w_down, layer,
+                      *, compute_dtype, activation, quant=None):
+    """The ``moe_decode.loop`` rung (:func:`decode_expert_ffn`)."""
     from automodel_tpu.ops.quant import maybe_qdot
 
     T, H = x.shape
@@ -525,7 +563,6 @@ def decode_expert_ffn(
     tok_sorted = (order // k).astype(jnp.int32)
     w_sorted = jnp.take(weights.reshape(N), order).astype(jnp.float32)
     lane = jnp.arange(chunk, dtype=jnp.int32)
-    layer = jnp.asarray(layer, jnp.int32)
     act = ACTIVATIONS[activation]
 
     def piece(e, c, out):
@@ -542,14 +579,16 @@ def decode_expert_ffn(
         return out.at[tok].add(y.astype(jnp.float32) * w[:, None])
 
     def expert(e, out):
-        if T <= chunk:
-            return lax.cond(sizes[e] > 0, lambda o: piece(e, 0, o),
-                            lambda o: o, out)
         return lax.fori_loop(0, (sizes[e] + chunk - 1) // chunk,
                              lambda c, o: piece(e, c, o), out)
 
     out = lax.fori_loop(0, E, expert, jnp.zeros((T, H), jnp.float32))
     return out.astype(cd), sizes
+
+
+registry.register_kernel(
+    "moe_decode.loop", probe=lambda request: True, impl=_decode_loop_impl,
+    fallback=None, reference=moe_decode_reference)
 
 
 def noaux_topk_routing(

@@ -449,6 +449,66 @@ def test_gmm_kernel_parity(spec, case):
     parity.run_gmm_parity(spec, case)
 
 
+@pytest.mark.parametrize("case", parity.moe_decode_cases(),
+                         ids=lambda c: c["name"])
+@pytest.mark.parametrize("spec", ["moe_decode.pallas", "moe_decode.loop"])
+def test_moe_decode_parity(spec, case):
+    """The decode step's routed experts, kernel (interpret mode) and loop,
+    against every expert on every token in float32: relu and silu, experts
+    nobody chose, all chosen, one token, padded rows and experts held
+    elsewhere (the sentinel), 48 / 64 / 21 rows, a traced layer of a stack
+    of three, both dtypes; ``tokens_per_expert`` equal to the oracle's
+    count in both, so equal to each other's."""
+    parity.run_moe_decode_parity(spec, case)
+
+
+def test_moe_decode_probe_reads_the_call_not_a_knob(monkeypatch):
+    """Which form runs is decided by what the call shows: decode-width
+    rows, lane-aligned widths, no quantized compute, the stacks on one
+    device, a TPU (or interpret mode)."""
+    import automodel_tpu.ops.moe_decode_kernel as mk
+
+    ok = {"rows": 48, "hidden": 2560, "inter": 768, "experts": 64,
+          "quantized": False, "devices": 1}
+    resolve = lambda **kw: registry.resolve(
+        "moe_decode.pallas", dict(ok, **kw)).name
+    assert registry.fallback_chain("moe_decode.pallas") == [
+        "moe_decode.pallas", "moe_decode.loop"]
+    assert resolve() == "moe_decode.loop"           # a CPU
+    monkeypatch.setattr(registry, "on_tpu", lambda: True)
+    assert resolve() == "moe_decode.pallas"
+    assert resolve(rows=64, hidden=7168, inter=2048) == "moe_decode.pallas"
+    assert resolve(rows=mk.MAX_ROWS + 1) == "moe_decode.loop"   # mixed step
+    assert resolve(rows=48 * 64) == "moe_decode.loop"
+    assert resolve(hidden=2560 + 64) == "moe_decode.loop"
+    assert resolve(inter=96) == "moe_decode.loop"
+    assert resolve(quantized=True) == "moe_decode.loop"
+    assert resolve(devices=4) == "moe_decode.loop"
+
+
+def test_moe_decode_sees_the_devices_its_stacks_span():
+    """A stack that is an array says where it lies; a traced one lies on the
+    mesh of the sharding context its forward is built under."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from automodel_tpu.distributed.mesh import MeshManager
+    from automodel_tpu.distributed.shardings import sharding_context
+    from automodel_tpu.ops import moe
+
+    mesh = MeshManager(dp_size=4, tp_size=2).mesh
+    stack = jnp.zeros((1, 8, 128, 128), jnp.bfloat16)
+    spread = jax.device_put(stack, NamedSharding(
+        mesh, P(None, tuple(mesh.axis_names))))
+    assert moe._devices_spanned(stack) == 1
+    assert moe._devices_spanned(spread) == 8
+    seen = []
+    trace = jax.jit(lambda w: seen.append(moe._devices_spanned(w)) or w)
+    trace(stack)
+    with sharding_context(mesh):
+        jax.jit(lambda w: seen.append(moe._devices_spanned(w)) or w)(stack)
+    assert seen == [1, 8]
+
+
 def test_every_registered_kernel_has_parity_coverage():
     """New kernels must either carry an XLA reference (and land in the
     harness) or be consciously listed as TPU-only — silent gaps fail."""

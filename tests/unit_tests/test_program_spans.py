@@ -15,6 +15,7 @@ import pytest
 from automodel_tpu.generation import GenerationConfig
 from automodel_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 from automodel_tpu.serving import DecodeEngine, ServingConfig
+from automodel_tpu.serving.scheduler import StepPlan
 from automodel_tpu.training import timers as timers_mod
 from automodel_tpu.training.timers import SPAN_PREFIX, Timers
 
@@ -465,6 +466,21 @@ def test_paged_step_updates_its_pools_in_place(
     assert temp < pool_bytes(pools) / 4, (temp, pool_bytes(pools))
 
 
+def _one_expert_kernel(names, kernels, text, layers=1):
+    """A layer's routed experts are ONE Mosaic call named ``moe_decode``
+    under ``moe_experts`` (``benchmark/metrics/experts_kernel_share.serve.py``
+    finds it by that name, the two expert rooflines by that scope), ``layers``
+    of them in a scan body that holds as many layers, and no expert is run
+    under a ``cond`` or a loop of its own beside it."""
+    assert len(names) == layers, kernels
+    for name in names:
+        assert re.match(r"^moe_decode(\.\d+)?$", name), kernels
+        assert "/mlp/moe_experts/moe_decode/" in kernels[name], kernels
+    for line in text.splitlines():
+        if re.search(r" (conditional|while)\(", line):
+            assert "/moe_experts/" not in line, line
+
+
 # -- the latent (MLA) step: one plane, two layer stacks, expert stacks --------
 # Kimi-K2's serving step carries ONE latent plane through both layer scans
 # and slices an expert's matrices at (layer, expert) where it multiplies
@@ -521,10 +537,12 @@ def test_latent_step_updates_its_plane_in_place(one_chip, monkeypatch, width):
     text = compiled.as_text()
 
     kernels = _kernels(text)
-    assert len(kernels) == 2, kernels       # the dense stack's, the experts'
-    for name, scope in kernels.items():
+    mla = {n: s for n, s in kernels.items() if n.startswith("mla_decode")}
+    assert len(mla) == 2, kernels           # the dense stack's, the experts'
+    for name, scope in mla.items():
         assert re.match(r"^mla_decode(\.\d+)?$", name), kernels
         assert "/attn/attn_core/mla_decode/" in scope, kernels
+    _one_expert_kernel(set(kernels) - set(mla), kernels, text)
     for scope_name in ("mla_latent_write", "mla_absorb_q", "mla_out",
                        "moe_router", "moe_experts", "moe_shared",
                        "dense_mlp"):
@@ -538,6 +556,70 @@ def test_latent_step_updates_its_plane_in_place(one_chip, monkeypatch, width):
     assert not _pool_sized_moves(text, stacks), _pool_sized_moves(text, stacks)
     assert compiled.memory_analysis().temp_size_in_bytes \
         < pool_bytes(pools) / 4
+
+
+# -- the window/full step: two block groups, 8 small experts a layer ---------
+# SmallThinker's serving step walks two pools under one period scan and runs
+# a layer's routed experts as one kernel that addresses the stacks of all
+# layers at (layer, expert): the same questions, put to its program.
+def _window_full_model():
+    from automodel_tpu.models.smallthinker import (
+        SmallThinkerConfig,
+        SmallThinkerForCausalLM,
+    )
+
+    cfg = SmallThinkerConfig(
+        vocab_size=256, hidden_size=256, num_hidden_layers=8,
+        num_attention_heads=4, num_key_value_heads=2, head_dim=128,
+        moe_num_primary_experts=8, moe_num_active_primary_experts=2,
+        moe_ffn_hidden_size=384, rope_layout=(0, 1, 1, 1) * 2,
+        sliding_window_layout=(0, 1, 1, 1) * 2, sliding_window_size=32,
+        max_position_embeddings=256)
+    return SmallThinkerForCausalLM(cfg, param_dtype=jnp.bfloat16,
+                                   compute_dtype=jnp.bfloat16, remat=False)
+
+
+@pytest.mark.parametrize("width", [1, 8], ids=["w1", "w8"])
+def test_window_full_step_runs_its_experts_as_one_kernel(
+        one_chip, monkeypatch, width):
+    from automodel_tpu.ops.kernel_lib import registry
+    from automodel_tpu.serving.kv_cache import pool_bytes
+
+    monkeypatch.setattr(registry, "on_tpu", lambda: True)
+    model = _window_full_model()
+    params = model.abstract_params()
+    eng = DecodeEngine(
+        model, params,
+        ServingConfig(kv_block_size=16, max_num_seqs=8, max_model_len=128,
+                      prefill_chunk=8,
+                      num_kv_blocks={"full": 16, "window": 16}))
+    assert sorted(eng.pools) == ["full", "window"]
+
+    def spec(a, shape=None):
+        return jax.ShapeDtypeStruct(shape or a.shape, a.dtype,
+                                    sharding=one_chip)
+
+    pools = {g: {name: spec(p, (p.shape[0], _POOL_BLOCKS, *p.shape[2:]))
+                 for name, p in planes.items()}
+             for g, planes in eng.pools.items()}
+    compiled = eng.step_fn(width).lower(
+        jax.tree.map(spec, params), pools,
+        *jax.tree.map(lambda a: spec(jnp.asarray(a)), eng._assemble(
+            StepPlan(rows=[None] * 8, step_width=width)))).compile()
+    text = compiled.as_text()
+
+    kernels = _kernels(text)
+    paged = {n for n in kernels if n.startswith("paged_decode")}
+    assert len(paged) == 4, kernels         # a period: one full, three window
+    _one_expert_kernel(set(kernels) - paged, kernels, text, layers=4)
+    flat = {f"{g}.{name}": p for g, planes in pools.items()
+            for name, p in planes.items()}
+    assert not _pool_sized_moves(text, flat), _pool_sized_moves(text, flat)
+    # the expert stacks [8, 8, 256, 384] stay where they are
+    stacks = {"e": jax.ShapeDtypeStruct((8, 8 * 256 * 384), jnp.bfloat16)}
+    assert not _pool_sized_moves(text, stacks), _pool_sized_moves(text, stacks)
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < sum(pool_bytes(p) for p in pools.values()) / 4
 
 
 # -- the retention step: per-sequence state planes, no block pool ------------

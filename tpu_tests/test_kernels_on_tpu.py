@@ -7,6 +7,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from automodel_tpu.ops.kernel_lib import parity
+
 
 def test_linear_ce_kernel_matches_xla_reference():
     from automodel_tpu.ops.linear_ce_kernel import (
@@ -113,6 +115,43 @@ def test_paged_decode_at_the_serving_cells_shapes(width, record_property):
     record_property("max_err", parity._compare(
         out, ref, parity.NATIVE_TOL["bfloat16"], True,
         f"paged_decode at the cell's shapes, width {width}"))
+
+
+@pytest.mark.parametrize("case", parity.chip_cases()["moe_decode.pallas"],
+                         ids=lambda c: c["name"])
+def test_decode_experts_at_the_serving_cells_widths(case, record_property):
+    """``decode_expert_ffn`` itself (the dispatch, not the harness) at the
+    two expert cells' published widths: the Pallas rung resolves, its call
+    is named ``moe_decode`` under the caller's ``moe_experts`` scope, and it
+    reads the addressed layer's experts: against every expert on every
+    token in float32, ``tokens_per_expert`` equal to the count."""
+    import re
+
+    from automodel_tpu.ops import moe
+    from automodel_tpu.ops.kernel_lib import registry
+
+    args, kwargs, request = parity.build_moe_decode_case(case)
+
+    def experts(*a):
+        with jax.named_scope("moe_experts"):
+            return moe.decode_expert_ffn(*a[:-1], layer=a[-1], **kwargs)
+
+    before = registry.resolved_rungs().get("moe_decode.pallas", 0)
+    compiled = jax.jit(experts).lower(*args).compile()
+    assert registry.resolved_rungs()["moe_decode.pallas"] == before + 1
+    calls = [re.search(r'op_name="([^"]*)"', line).group(1)
+             for line in compiled.as_text().splitlines()
+             if re.search(r"%?moe_decode(\.\d+)? = .*tpu_custom_call", line)]
+    assert len(calls) == 1 and "/moe_experts/moe_decode/" in calls[0], calls
+    out, counts = compiled(*args)
+    with jax.default_matmul_precision("highest"):
+        ref, ref_counts = jax.jit(lambda *a: registry.get_kernel(
+            "moe_decode.pallas").reference(request, *a, **kwargs))(*args)
+    np.testing.assert_array_equal(np.asarray(counts), np.asarray(ref_counts))
+    assert 0 < int(np.sum(np.asarray(counts) > 0)) <= case["E"]
+    record_property("max_err", parity._compare(
+        out, ref, parity.NATIVE_TOL["bfloat16"], True,
+        f"decode_expert_ffn on {case['name']}"))
 
 
 def test_kernel_scopes_and_instruction_names():

@@ -19,6 +19,7 @@ _RUNNERS = {
     "linear_ce.pallas": parity.run_linear_ce_parity,
     # grads=True: dlhs is a second gmm, drhs the transposed kernel (tgmm)
     "gmm.pallas": functools.partial(parity.run_gmm_parity, grads=True),
+    "moe_decode.pallas": parity.run_moe_decode_parity,
     "qdot.pallas": parity.run_qdot_parity,
     "gmm_quant.pallas": parity.run_gmm_quant_parity,
 }
@@ -54,6 +55,10 @@ def test_probes_accept_published_widths_on_the_chip():
             ("attention.retention_chunk", dict(brumby, q_seq=64)),
             ("linear_ce.pallas", {"t": 16384, "h": 2048, "v": 128256}),
             ("gmm.pallas", {"m": 4096, "k": 4096, "n": 14336}),
+            ("moe_decode.pallas", {"rows": 64, "hidden": 7168, "inter": 2048,
+                                   "experts": 12}),
+            ("moe_decode.pallas", {"rows": 48, "hidden": 2560, "inter": 768,
+                                   "experts": 64}),
             ("qdot.pallas", {"m": 4096, "k": 14336, "n": 4096}),
             ("gmm_quant.pallas", {"m": 4096, "k": 4096, "n": 14336})):
         assert registry.resolve(head, request).name == head
