@@ -1041,9 +1041,11 @@ class DecodeEngine:
         # surfacing a timeout/cancellation) — the watchdog recovery
         # path must absorb it without crashing the engine loop.
         fault_point("serve_watchdog_stall")
+        # a step annotation: the profiler marks step ``step_num`` on the
+        # device's timeline too, so its execution can be found by number
         step = self.steps_run
         with timers.record(
-                "serve_dispatch", step=step, width=width,
+                "serve_dispatch", step_num=step, width=width,
                 rows=len(active), positions=positions, slots=n_slots,
                 prefill_rows=prefill_rows,
                 sampled=sum(1 for w in active if w.samples_next),

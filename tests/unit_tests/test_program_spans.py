@@ -114,8 +114,10 @@ def test_engine_step_spans_and_request_events(engine_parts, tmp_path):
     assert stats["ahead_steps"] - base["ahead_steps"] == ran - 1
     assert all(0 <= d["fed_rows"] <= d["rows"] for d in dispatched)
     assert sum(d["fed_rows"] for d in dispatched) > 0
-    assert [d["step"] for d in dispatched] == list(
+    # the dispatch is a step annotation: its number is ``step_num``, once
+    assert [d["step_num"] for d in dispatched] == list(
         range(base["steps"], stats["steps"]))
+    assert all(d["_r"] == 1 and "step" not in d for d in dispatched)
     for key in ("rows", "positions", "slots"):
         assert (sum(d[key] for d in dispatched)
                 == stats[key + "_sum"] - base[key + "_sum"])
@@ -147,6 +149,55 @@ def test_engine_step_spans_and_request_events(engine_parts, tmp_path):
                 <= req.finish_time)
     for k in events:
         assert set(events[k]) == set(rids)
+
+
+def test_dispatch_step_numbers_and_kinds_from_the_first_step(engine_parts,
+                                                             tmp_path):
+    """A fresh engine stepped under a session: one ``serve_dispatch`` per
+    dispatched step, numbered 0, 1, 2... by ``step_num``, with
+    ``prefill_rows`` > 0 exactly on the mixed steps (the wide program)."""
+    eng = _engine(engine_parts)
+    rng = np.random.default_rng(1)
+
+    def drive():
+        for n in (19, 3):
+            eng.submit(rng.integers(1, 255, n).tolist())
+        eng.run()
+        eng.submit(rng.integers(1, 255, 11).tolist())
+        eng.run()
+
+    spans = _traced(tmp_path, drive)
+    stats = eng.stats()
+    dispatched = [s[4] for s in spans if s[0] == "serve_dispatch"]
+    assert [d["step_num"] for d in dispatched] == list(range(stats["steps"]))
+    mixed = [d["prefill_rows"] > 0 for d in dispatched]
+    assert [d["width"] > 1 for d in dispatched] == mixed
+    assert sum(mixed) == stats["mixed_steps"] >= 3
+    assert len(mixed) - sum(mixed) == stats["decode_steps"] > 0
+
+
+def test_step_annotation_carries_its_number(tmp_path):
+    """``record(..., step_num=n)`` opens a step annotation: the same
+    ``automodel/`` span with its stats and ``step_num``; without it, a plain
+    one; both on the host clock."""
+    t = Timers()
+
+    def drive():
+        with t.record("serve_dispatch", step_num=7, width=32, rows=3):
+            pass
+        t("dispatch").start(step_num=8)
+        t("dispatch").stop()
+        with t.record("serve_fetch", rows=3):
+            pass
+
+    spans = _traced(tmp_path, drive)
+    got = {(k, tuple(sorted(st.items()))) for k, _, _, _, st in spans}
+    assert got == {
+        ("serve_dispatch", (("_r", 1), ("rows", 3), ("step_num", 7),
+                            ("width", 32))),
+        ("dispatch", (("_r", 1), ("step_num", 8))),
+        ("serve_fetch", (("rows", 3),))}
+    assert all(v > 0 for v in t.get_elapsed(reset=False).values())
 
 
 def test_train_loop_spans(tmp_path):
