@@ -250,7 +250,10 @@ def paged_attention_cases() -> List[Dict]:
     rung.  Every case stacks ``L`` layers of pool with contents of their
     own and attends ``layer`` (default: the middle of three), so a rung
     that addresses another layer fails; two cases stand at the first and
-    the last.  Shape keys: ``B Hq Hk D BS MB L layer``."""
+    the last.  Shape keys: ``B Hq Hk D BS MB L layer``; ``rows`` stores
+    the pools as rows ``[L, NB, BS, Hk * D]``, ``idle`` makes the last row
+    an idle one as the engine assembles it (context 1, positions 0, every
+    entry the null page)."""
     return [
         dict(name="decode_gqa", q_seq=1, dtype="float32"),
         dict(name="decode_bf16", q_seq=1, dtype="bfloat16"),
@@ -281,6 +284,24 @@ def paged_attention_cases() -> List[Dict]:
              dtype="float32", Hq=14, Hk=2, MB=8, window=24, released=True),
         dict(name="decode_bf16_g7_window_released", q_seq=1,
              dtype="bfloat16", Hq=7, Hk=1, MB=8, window=40, released=True),
+        # pools stored as rows [L, NB, BS, Hk * D] (fewer kv heads than the
+        # dtype's sublane packing): SmallThinker's geometry (Hk = 4, G = 7,
+        # blocks of 128, contexts on both sides of a 4,096 window, an idle
+        # row), decode and the width-64 step, full and windowed; then
+        # Hk = 8, G = 4 in blocks of 16, and a float32 pool
+        *(dict(name=f"rows_g7_{kind}{'_window' if window else '_full'}",
+               q_seq=q_seq, dtype="bfloat16", rows=True, idle=True, B=4,
+               Hq=28, Hk=4, BS=128, MB=48, L=2, window=window,
+               released=bool(window))
+          for kind, q_seq in (("decode", 1), ("chunk64", 64))
+          for window in (None, 4096)),
+        dict(name="rows_hk8_g4_decode", q_seq=1, dtype="bfloat16",
+             rows=True, idle=True, B=3, Hq=32, Hk=8, BS=16, MB=6),
+        dict(name="rows_hk8_g4_verify_window_released", q_seq=3,
+             dtype="bfloat16", rows=True, B=3, Hq=32, Hk=8, BS=16, MB=6,
+             window=24, released=True),
+        dict(name="rows_f32_soft_cap", q_seq=1, dtype="float32", rows=True,
+             soft_cap=30.0),
     ]
 
 
@@ -317,6 +338,8 @@ def build_paged_attention_case(case: Dict, *, B=2, Hq=4, Hk=2, D=128,
     ctx = np.asarray([MB * BS - 7 - 3 * (b // 2) if b % 2 == 0
                       else 2 * BS + 3 + b // 2 for b in range(B)], np.int32)
     ctx = np.maximum(ctx, S)
+    if case.get("idle"):
+        ctx[-1], perm[-1] = 1, 0
     if case.get("released"):
         from automodel_tpu.ops.paged_attention import window_first_block
 
@@ -324,8 +347,11 @@ def build_paged_attention_case(case: Dict, *, B=2, Hq=4, Hk=2, D=128,
             perm[b, :window_first_block(int(ctx[b]) - S, int(case["window"]),
                                         BS)] = 0
     block_tables = jnp.asarray(perm, jnp.int32)
-    positions = jnp.asarray(
-        ctx[:, None] - S + np.arange(S)[None, :], jnp.int32)
+    positions = np.maximum(ctx[:, None] - S + np.arange(S)[None, :], 0)
+    positions = jnp.asarray(positions, jnp.int32)
+    if case.get("rows"):
+        k_pool, v_pool = (p.reshape(L, NB, BS, Hk * D)
+                          for p in (k_pool, v_pool))
     kwargs: Dict = {}
     if case.get("soft_cap"):
         kwargs["logits_soft_cap"] = float(case["soft_cap"])
@@ -850,8 +876,9 @@ def chip_cases() -> Dict[str, List[Dict]]:
     # SmallThinker-21B-A3B's cache as the serving cell holds it: seven query
     # heads a kv head, blocks of 128, tables of 128 blocks (16,384
     # positions), contexts to 16k; a window layer's walk starts at the first
-    # block the window (4,096) touches, the entries before it released
-    small = dict(B=8, Hq=28, Hk=4, D=128, BS=128, MB=128, L=2)
+    # block the window (4,096) touches, the entries before it released; the
+    # engine stores its pools as rows (four kv heads fill a quarter tile)
+    small = dict(B=8, Hq=28, Hk=4, D=128, BS=128, MB=128, L=2, rows=True)
     brumby = dict(B=16, Hq=40, Hk=8, L=2, layer=1, ctx=64)
     mixtral_up = dict(m=4096, k=4096, n=14336, sizes=_ragged_sizes(4096, 8))
     mixtral_down = dict(m=4096, k=14336, n=4096, sizes=_ragged_sizes(4096, 8))

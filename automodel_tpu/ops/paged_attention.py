@@ -32,11 +32,16 @@ Both rungs speak one request/operand contract (:func:`paged_attention`):
   ALL layers, stacked, optionally int8 with per-slot-per-head scale planes
   ``[L, NB, BS, Hk]`` (the quantized KV cache, see
   ``serving/kv_cache.py``), and ``layer`` — an int32 scalar, traced or
-  not, naming the layer to attend.  A rung addresses the stacked pool AT
-  the layer (page ``layer * NB + block``) and never takes ``pool[layer]``
-  first: inside the engine's layer scan the pools are the loop's carry,
-  and a slice of them would be a copy of one layer per layer per step.  A
-  caller with one layer's pools passes ``L = 1, layer = 0``;
+  not, naming the layer to attend.  A pool of fewer kv heads than its
+  dtype's sublane packing is stored as ROWS, ``[L, NB, BS, Hk * D]``: the
+  same bytes in the same order, whose ``(BS, Hk * D)`` page fills the
+  chip's tiles where a ``(Hk, D)`` slice would fill a fraction of one
+  (:func:`stores_rows` decides; :func:`kv_heads` reads ``Hk`` from either
+  form).  A rung addresses the stacked pool AT the layer (page ``layer *
+  NB + block``) and never takes ``pool[layer]`` first: inside the engine's
+  layer scan the pools are the loop's carry, and a slice of them would be
+  a copy of one layer per layer per step.  A caller with one layer's pools
+  passes ``L = 1, layer = 0``;
 * ``block_tables [B, MB]`` int32, ``context_lens [B]`` int32 (valid
   positions INCLUDING tokens written this step).  Under a static
   ``local_window_size`` the entries before :func:`window_first_block` of a
@@ -78,6 +83,22 @@ def window_first_block(first_position, window: int, block_size: int):
     return behind * (behind > 0) // block_size
 
 
+def stores_rows(num_kv_heads: int, dtype, quantized: bool) -> bool:
+    """Whether a per-head pool of ``num_kv_heads`` heads in ``dtype`` is
+    stored as rows ``[.., BS, Hk * D]``: an unquantized pool whose heads are
+    fewer than the dtype's sublane packing (16 for bfloat16, 8 for
+    float32).  Such a pool's ``(Hk, D)`` slice of one slot would fill a
+    fraction of a ``(packing, 128)`` tile; its ``(BS, Hk * D)`` page fills
+    whole ones.  An int8 pool keeps its heads: its scale planes are
+    indexed by head."""
+    return not quantized and num_kv_heads < 4 // jnp.dtype(dtype).itemsize * 8
+
+
+def kv_heads(pool: jnp.ndarray, head_dim: int) -> int:
+    """``Hk`` of a per-head pool, ``[.., Hk, D]`` or rows ``[.., Hk * D]``."""
+    return pool.shape[3] if pool.ndim == 5 else pool.shape[3] // head_dim
+
+
 def dequantize_pool(pool: jnp.ndarray, scale: Optional[jnp.ndarray],
                     dtype=jnp.float32) -> jnp.ndarray:
     """int8 pool [..., Hk, D] * per-slot scale [..., Hk] -> compute dtype;
@@ -88,7 +109,8 @@ def dequantize_pool(pool: jnp.ndarray, scale: Optional[jnp.ndarray],
 
 
 def gathered_cache(pool: jnp.ndarray, scale: Optional[jnp.ndarray], layer,
-                   block_tables: jnp.ndarray, dtype=jnp.float32):
+                   block_tables: jnp.ndarray, dtype=jnp.float32,
+                   head_dim: Optional[int] = None):
     """Linearize a row's blocks of layer ``layer`` of the stacked pool by
     position: ``[B, MB*BS, Hk, D]``.
 
@@ -96,11 +118,14 @@ def gathered_cache(pool: jnp.ndarray, scale: Optional[jnp.ndarray], layer,
     BS`` of ``table[p // BS]``), gathering blocks in table order IS the
     dense per-row cache reconstruction.  The gather runs over the pool's
     ``[L*NB, ...]`` pages at ``layer * NB + table``, so no layer of the
-    pool is ever materialised.
+    pool is ever materialised.  A pool stored as rows (``head_dim``
+    given, a 4-D pool) is split into its heads after the gather.
     """
     L, NB = pool.shape[:2]
     pages = jnp.asarray(layer, jnp.int32) * NB + block_tables
     g = pool.reshape(L * NB, *pool.shape[2:])[pages]   # [B, MB, BS, Hk, D]
+    if head_dim is not None and g.ndim == 4:
+        g = g.reshape(*g.shape[:3], -1, head_dim)
     gs = None if scale is None else scale.reshape(
         L * NB, *scale.shape[2:])[pages]
     B, MB, BS = g.shape[:3]
@@ -114,13 +139,14 @@ def _paged_gather_impl(request, q, k_pool, v_pool, k_scale, v_scale, layer,
                        local_window_size=None, kernel_name=None):
     """XLA anchor: gather-by-table + masked SDPA, any query length."""
     B, S, Hq, D = q.shape
-    Hk = k_pool.shape[3]
+    Hk = kv_heads(k_pool, D)
     assert Hq % Hk == 0, f"query heads {Hq} not a multiple of kv heads {Hk}"
     G = Hq // Hk
     scale = D ** -0.5 if scale is None else scale
 
-    keys = gathered_cache(k_pool, k_scale, layer, block_tables)  # [B,K,Hk,D]
-    vals = gathered_cache(v_pool, v_scale, layer, block_tables)
+    keys = gathered_cache(k_pool, k_scale, layer, block_tables,
+                          head_dim=D)                       # [B,K,Hk,D]
+    vals = gathered_cache(v_pool, v_scale, layer, block_tables, head_dim=D)
     K = keys.shape[1]
 
     qg = q.reshape(B, S, Hk, G, D)
@@ -155,8 +181,9 @@ def paged_reference(request, q, k_pool, v_pool, k_scale, v_scale, layer,
     the same numbers."""
     from automodel_tpu.ops.attention import dot_product_attention
 
-    keys = gathered_cache(k_pool, k_scale, layer, block_tables)
-    vals = gathered_cache(v_pool, v_scale, layer, block_tables)
+    D = q.shape[-1]
+    keys = gathered_cache(k_pool, k_scale, layer, block_tables, head_dim=D)
+    vals = gathered_cache(v_pool, v_scale, layer, block_tables, head_dim=D)
     K = keys.shape[1]
 
     def row(qb, kb, vb, ctx, pos0):
@@ -180,10 +207,12 @@ def build_paged_request(q, k_pool, *, quantized: bool,
     return {
         "kind": "paged_attention",
         "q_seq": q.shape[1], "head_dim": q.shape[3],
-        "num_q_heads": q.shape[2], "num_kv_heads": k_pool.shape[3],
+        "num_q_heads": q.shape[2],
+        "num_kv_heads": kv_heads(k_pool, q.shape[3]),
         "num_blocks": k_pool.shape[1], "block_size": k_pool.shape[2],
         "dtype": str(q.dtype), "quantized": bool(quantized),
         "soft_cap": bool(soft_cap), "window": bool(window),
+        "rows": k_pool.ndim == 4,
     }
 
 

@@ -31,6 +31,19 @@ so the consecutive assumption only over-attends garbage columns).  At
 S=1 the mask degenerates to the classic ``kv_pos < ctx`` decode mask
 bit-exactly.
 
+**Two bodies, chosen by the pool's rank.**  A pool of heads ``[L, NB, BS,
+Hk, D]`` (OLMo's 16 kv heads, every int8 pool) reaches the body as a
+``(BS, kt, D)`` block, upcast to float32 and transposed to ``(kt, BS, D)``
+for one batched product a page.  A pool stored as ROWS ``[L, NB, BS, Hk *
+D]`` (``paged_attention.stores_rows``: fewer kv heads than the dtype's
+sublane packing, where a ``(kt, D)`` slice fills a fraction of a tile)
+reaches it as a dense ``(BS, kt * D)`` block, and the body works on the
+page as it lies: per kv head the lane-aligned slice ``[:, h*D:(h+1)*D]``,
+``q . k^T`` as one NT product on the stored dtype with float32
+accumulation, and ``p . v`` as two such products of ``p``'s bfloat16 head
+and remainder, so ``p`` keeps float32 accuracy; no float32 page and no
+transpose.
+
 Quantized (int8) pools dequantize IN VMEM with the per-slot scale planes
 (PR-10's ``quant_cast`` contract inverted), so the HBM traffic — the thing
 decode is bound by — is 1 byte per cached element instead of 2.
@@ -51,6 +64,7 @@ import jax.numpy as jnp
 
 from automodel_tpu.ops.kernel_lib import autotune, registry, tiling
 from automodel_tpu.ops.paged_attention import (
+    kv_heads,
     paged_reference,
     window_first_block,
     window_span_blocks,
@@ -99,7 +113,7 @@ def _tile_bytes(kt: int, ge: int, bs: int, d: int, kv_itemsize: int,
 
 
 def _head_tile(hk: int, g: int, s: int, bs: int, d: int, kv_itemsize: int,
-               quantized: bool, pages: int, dtype: str) -> int:
+               quantized: bool, pages: int, dtype: str, rows: bool) -> int:
     """kv-head tile via divisor search under the VMEM budget, overridden
     by a persisted autotune winner (kernel key ``"paged_decode"``)."""
     budget = tiling.DEFAULT_TILE_BUDGET_BYTES
@@ -111,7 +125,7 @@ def _head_tile(hk: int, g: int, s: int, bs: int, d: int, kv_itemsize: int,
     default = next((kt for kt in divisors if fits(kt)), 1)
     fields = {"hk": hk, "g": g, "s": s, "bs": bs, "d": d,
               "pages": autotune.shape_bucket(pages), "dtype": dtype,
-              "quant": quantized}
+              "quant": quantized, "rows": rows}
     choice = autotune.lookup(
         "paged_decode", fields, (default,),
         validate=lambda c: (len(c) == 1 and c[0] >= 1 and hk % c[0] == 0
@@ -119,8 +133,60 @@ def _head_tile(hk: int, g: int, s: int, bs: int, d: int, kv_itemsize: int,
     return int(choice[0])
 
 
+def _rows_page(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, *, j, ctx, pos0,
+               bs, kt, g, scale, soft_cap, window):
+    """One page of a pool stored as rows: ``k_ref``/``v_ref`` hold the
+    page's ``(BS, kt * D)`` block, ``q_ref`` the tile's ``(kt, S*G, D)``
+    queries, the scratch ``(kt, S*G, .)`` rows a kv head.  Per kv head the
+    page's lane-aligned ``(BS, D)`` slice enters both products as stored."""
+    _, ge, d = q_ref.shape[1:]
+    kv_pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (ge, bs), 1)
+    qpos = pos0 + jax.lax.broadcasted_iota(jnp.int32, (ge, bs), 0) // g
+    valid = (kv_pos < ctx) & (kv_pos <= qpos)
+    if window is not None:
+        valid &= kv_pos > qpos - window
+    dt = jnp.promote_types(q_ref.dtype, k_ref.dtype)
+    for h in range(kt):
+        lanes = slice(h * d, (h + 1) * d)
+        k = k_ref[0, :, lanes].astype(dt)                  # (BS, D)
+        # (S*G, D) x (BS, D)^T -> (S*G, BS): NT, the page as it lies
+        s = jax.lax.dot_general(
+            q_ref[0, h].astype(dt), k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        if soft_cap is not None:
+            s = soft_cap * jnp.tanh(s / soft_cap)
+        s = jnp.where(valid, s, _NEG_INF)
+        m_prev = m_ref[h][:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_new = l_ref[h][:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        v = v_ref[0, :, lanes].astype(dt)                  # (BS, D)
+        o = _probs_times(p, v)
+        acc_ref[h] = acc_ref[h] * alpha + o
+        m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+        l_ref[h] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+
+
+def _probs_times(p, v):
+    """``p (S*G, BS) float32 . v (BS, D)`` with float32 accumulation.  A
+    float32 page multiplies ``p`` as it is; a narrower one takes ``p`` as
+    its head in ``v``'s dtype plus the remainder, two products on the
+    stored page, so ``p`` is never rounded once to ``v``'s dtype."""
+    dims = (((1,), (0,)), ((), ()))
+    if v.dtype == jnp.float32:
+        return jax.lax.dot_general(p, v, dims,
+                                   preferred_element_type=jnp.float32)
+    hi = p.astype(v.dtype)
+    lo = (p - hi.astype(jnp.float32)).astype(v.dtype)
+    return (jax.lax.dot_general(hi, v, dims,
+                                preferred_element_type=jnp.float32)
+            + jax.lax.dot_general(lo, v, dims,
+                                  preferred_element_type=jnp.float32))
+
+
 def _decode_kernel(bt_ref, cl_ref, p0_ref, ly_ref, *refs, bs, kt, g, s_q,
-                   scale, soft_cap, window, quantized):
+                   scale, soft_cap, window, quantized, rows):
     from jax.experimental import pallas as pl
 
     b, i = pl.program_id(0), pl.program_id(2)
@@ -145,6 +211,20 @@ def _decode_kernel(bt_ref, cl_ref, p0_ref, ly_ref, *refs, bs, kt, g, s_q,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     ctx = cl_ref[b]
+
+    if rows:
+        @pl.when(j * bs < ctx)
+        def _compute_rows():
+            _rows_page(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, j=j,
+                       ctx=ctx, pos0=p0_ref[b], bs=bs, kt=kt, g=g,
+                       scale=scale, soft_cap=soft_cap, window=window)
+
+        @pl.when(i == nj - 1)
+        def _finish_rows():
+            l = l_ref[..., :1]
+            l = jnp.where(l == 0.0, 1.0, l)
+            o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        return
 
     @pl.when(j * bs < ctx)
     def _compute():
@@ -203,10 +283,12 @@ def paged_decode_pallas(q, k_pool, v_pool, k_scale, v_scale, layer,
     """``q [B, S, Hq, D]`` (small S — decode 1, verify spec_k+1, chunked
     prefill) over layer ``layer`` (int32 scalar, traced or not) of the
     stacked position-major pools ``[L, NB, BS, Hk, D]`` (+ optional int8
-    scale planes ``[L, NB, BS, Hk]``) -> ``[B, S, Hq, D]``.  The layer
-    index rides scalar prefetch beside the block tables and leads every
-    page's index map, so the kernel's operand is the whole stacked pool
-    and only the pages a row owns in that layer are ever read.
+    scale planes ``[L, NB, BS, Hk]``), or pools stored as rows ``[L, NB,
+    BS, Hk * D]``, -> ``[B, S, Hq, D]``; the pool's rank picks the body
+    (module docstring).  The layer index rides scalar prefetch beside the
+    block tables and leads every page's index map, so the kernel's operand
+    is the whole stacked pool and only the pages a row owns in that layer
+    are ever read.
 
     ``positions [B, S]``: each query token's absolute position.  The
     kernel prefetches only column 0 and derives the rest as ``pos0 + s``
@@ -217,7 +299,8 @@ def paged_decode_pallas(q, k_pool, v_pool, k_scale, v_scale, layer,
     from jax.experimental import pallas as pl
 
     B, S, Hq, D = q.shape
-    _, _, BS, Hk, _ = k_pool.shape
+    rows = k_pool.ndim == 4
+    BS, Hk = k_pool.shape[2], kv_heads(k_pool, D)
     MB = block_tables.shape[1]
     assert S <= _MAX_CHUNKED_Q, "paged_decode is the small-q rung"
     G = Hq // Hk
@@ -225,7 +308,7 @@ def paged_decode_pallas(q, k_pool, v_pool, k_scale, v_scale, layer,
     scale = D ** -0.5 if scale is None else scale
     quantized = k_scale is not None
     kt = _head_tile(Hk, G, S, BS, D, k_pool.dtype.itemsize, quantized, MB,
-                    str(q.dtype))
+                    str(q.dtype), rows)
     if positions is None:
         assert S == 1, "q_seq > 1 requires explicit positions"
         pos0 = context_lens.astype(jnp.int32) - 1
@@ -263,6 +346,8 @@ def paged_decode_pallas(q, k_pool, v_pool, k_scale, v_scale, layer,
         return bt[b, jnp.minimum(st[0][b] + i, MB - 1)]
 
     def page_index(b, h, i, bt, cl, p0, ly, *st):
+        if rows:
+            return (ly[0], entry(b, i, bt, st), 0, h)
         return (ly[0], entry(b, i, bt, st), 0, h, 0)
 
     def scale_index(b, h, i, bt, cl, p0, ly, *st):
@@ -271,27 +356,31 @@ def paged_decode_pallas(q, k_pool, v_pool, k_scale, v_scale, layer,
     def q_index(b, h, i, bt, cl, p0, ly, *st):
         return (b, h, 0, 0)
 
+    page = (None, 1, BS, kt * D) if rows else (None, 1, BS, kt, D)
+    tile = (kt, GE) if rows else (kt * GE,)
+
     out = pl.pallas_call(
         functools.partial(
             _decode_kernel, bs=BS, kt=kt, g=G, s_q=S, scale=scale,
             soft_cap=logits_soft_cap, window=local_window_size,
-            quantized=quantized),
+            quantized=quantized, rows=rows),
         grid_spec=tiling.prefetch_grid_spec(
             num_scalar_prefetch=4 + windowed,
             grid=(B, Hk // kt, walk),
             in_specs=[
                 tiling.block_spec((1, kt, GE, D), q_index),
                 # the layer axis is squeezed: the body sees one page
-                tiling.block_spec((None, 1, BS, kt, D), page_index),
-                tiling.block_spec((None, 1, BS, kt, D), page_index),
+                tiling.block_spec(page, page_index),
+                tiling.block_spec(page, page_index),
                 tiling.block_spec((None, 1, BS, kt), scale_index),
                 tiling.block_spec((None, 1, BS, kt), scale_index),
             ],
             out_specs=tiling.block_spec((1, kt, GE, D), q_index),
+            # m, l and the accumulator: a rows body indexes them by kv head
             scratch_shapes=[
-                _scratch((kt * GE, 128), jnp.float32),
-                _scratch((kt * GE, 128), jnp.float32),
-                _scratch((kt * GE, D), jnp.float32),
+                _scratch((*tile, 128), jnp.float32),
+                _scratch((*tile, 128), jnp.float32),
+                _scratch((*tile, D), jnp.float32),
             ]),
         out_shape=jax.ShapeDtypeStruct((B, Hk, GE, D), q.dtype),
         compiler_params=tiling.compiler_params(
@@ -349,7 +438,8 @@ def _sweep_key_fields(req):
             "bs": req["block_size"], "d": req["head_dim"],
             "pages": autotune.shape_bucket(req["pages_per_seq"]),
             "dtype": str(req.get("dtype", "bfloat16")),
-            "quant": bool(req.get("quantized"))}
+            "quant": bool(req.get("quantized")),
+            "rows": bool(req.get("rows"))}
 
 
 def _sweep_candidates(req):
@@ -378,7 +468,8 @@ def _sweep_run(req, choice) -> float:
         ks = jnp.full((1, nb, bs, hk), 0.01, jnp.float32)
         vs = ks
     else:
-        kp = jax.random.normal(key, (1, nb, bs, hk, d), jnp.float32).astype(
+        slot = (hk * d,) if req.get("rows") else (hk, d)
+        kp = jax.random.normal(key, (1, nb, bs, *slot), jnp.float32).astype(
             dtype)
         vp = kp
         ks = vs = None

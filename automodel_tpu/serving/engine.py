@@ -110,6 +110,7 @@ from automodel_tpu.serving.kv_cache import (
     normalize_kv_cache_dtype,
     normalize_prefix_caching,
     pool_bytes,
+    pool_layout,
     sequence_planes,
     slot_for,
     validate_kv_cache_dtype,
@@ -452,6 +453,7 @@ class DecodeEngine:
                           zip(self.cache_groups, makers)})
                 if self.grouped else makers[0])
         self.pools = self._new_pools()
+        logger.info("paged KV cache layout: %s", self.kv_layout())
         self.block_groups = [
             BlockGroup(g.name, BlockAllocator(n), g.window)
             for g, n in zip(self.cache_groups, num_blocks)]
@@ -579,6 +581,15 @@ class DecodeEngine:
     def all_free(self) -> bool:
         """The leak oracle: every block of every group is back."""
         return self.scheduler.all_free
+
+    def kv_layout(self):
+        """How the pools hold a token (``kv_cache.pool_layout``): ``"rows"``
+        or ``"heads"`` for per-head planes, ``"latent"``, None for state
+        planes; by group name in a cache of several block groups."""
+        if not self.grouped:
+            return pool_layout(self.pools)
+        return {g.name: pool_layout(self.pools[g.name])
+                for g in self.cache_groups}
 
     def _refuse_for_state_planes(self, planes) -> None:
         """A per-sequence state plane has no blocks to share, no way back
@@ -1336,6 +1347,7 @@ class DecodeEngine:
             "weight_syncs": self.weight_syncs,
             "kv_pool_bytes": pool_bytes(self.pools),
             # a cache of several block groups: by group name
+            "kv_layout": self.kv_layout(),
             "kv_blocks_peak": per_group(lambda a: a.peak_used),
             "kv_blocks_free": per_group(lambda a: a.free_blocks),
             "failed_allocs": sum(g.allocator.failed_allocs

@@ -10,6 +10,9 @@ layers into one buffer the step donates and updates in place,
 
     ``k/v: [L, num_blocks, block_size, Hk, D]``  (position-major),
 
+(``[L, num_blocks, block_size, Hk * D]``, the same bytes as ROWS, where the
+kv heads are fewer than the dtype's sublane packing: the paged kernel then
+reads a page as dense ``(block_size, Hk * D)`` tiles; :func:`pool_layout`)
 and a per-request *block table* mapping position ``p`` to slot ``p %
 block_size`` of block ``table[p // block_size]``.  Blocks are recycled
 through a free list as requests finish, so the pool sizes to the TOTAL
@@ -482,8 +485,14 @@ def init_paged_pools(*, num_layers: int, num_blocks: int, block_size: int,
     the model says it caches per token (``model.paged_cache_planes()``):
     ``{"k"|"v": (Hk, D)}`` for a per-head cache, ``{"kv": (R,)}`` for a
     latent cache (MLA: ONE plane whose leading values are key AND value).
-    A quantized per-head cache adds ``{"k_scale"|"v_scale": [L, NB, BS,
-    Hk]}``; a latent plane has no per-head scale to carry and is refused."""
+    A per-head plane whose heads are fewer than the dtype's sublane
+    packing is stored as rows, ``[L, NB, BS, Hk * D]``
+    (``ops/paged_attention.stores_rows``): the shape of the plane decides,
+    never a knob.  A quantized per-head cache adds ``{"k_scale"|"v_scale":
+    [L, NB, BS, Hk]}``; a latent plane has no per-head scale to carry and
+    is refused."""
+    from automodel_tpu.ops.paged_attention import stores_rows
+
     if sequence_planes(planes):
         raise ValueError(
             "per-sequence state planes are allocated by init_state_planes "
@@ -493,6 +502,8 @@ def init_paged_pools(*, num_layers: int, num_blocks: int, block_size: int,
     for name, per_slot in planes.items():
         if len(per_slot) == 1:
             per_slot = (latent_plane_width(per_slot[0]),)
+        elif stores_rows(per_slot[0], dtype, quantized):
+            per_slot = (per_slot[0] * per_slot[1],)
         shape = (num_layers, num_blocks, block_size, *per_slot)
         pools[name] = jnp.zeros(shape, dtype)
         if quantized:
@@ -536,13 +547,23 @@ def pool_bytes(pools: Dict[str, Any]) -> int:
     return sum(int(x.size) * x.dtype.itemsize for x in jax.tree.leaves(pools))
 
 
+def pool_layout(pools: Dict[str, Any]) -> Optional[str]:
+    """How one group's pools hold a token: ``"rows"`` (``k``/``v`` as
+    ``[.., BS, Hk * D]``), ``"heads"`` (``[.., BS, Hk, D]``), ``"latent"``
+    (one MLA plane), None (per-sequence state planes)."""
+    if "k" in pools:
+        return "rows" if pools["k"].ndim == 4 else "heads"
+    return "latent" if "kv" in pools else None
+
+
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
 class PagedKVView:
     """The paged cache as one model forward sees it — a pytree whose array
-    leaves are the STACKED pools ``[L, NB, BS, Hk, D]``, the per-step
-    addressing arrays and the layer the view stands at, with the layout
-    facts (block size, quantization) as static aux data.
+    leaves are the STACKED pools ``[L, NB, BS, Hk, D]`` (or rows ``[L, NB,
+    BS, Hk * D]``), the per-step addressing arrays and the layer the view
+    stands at, with the layout facts (block size, quantization) as static
+    aux data.
 
     ``models/layer_scan.scan_layers`` carries the pools through every
     layer scan (the loop's CARRY, never its ``xs``/``ys``: a scan slices its ``xs`` and
@@ -597,12 +618,13 @@ class PagedKVView:
         """Scatter this step's ``[B, S, Hk, D]`` k/v into the view's layer
         of the stacked pools — flat slot ``layer * NB * BS + slot_mapping``
         (pad tokens land in the layer's null page 0) — and return the
-        stacked pools dict.  int8 pools quantize per written slot per kv
-        head (PR-10's ``quant_cast``), storing the scale in the matching
-        scale plane — indexed ``[layer, block, slot]`` as it stands: a
-        plane's ``[.., BS, Hk]`` tiles are far under a lane tile, so the
-        TPU keeps it NB-minor, and a reshape to rows would have every
-        layer relay out the whole plane."""
+        stacked pools dict.  A pool stored as rows takes ``[B*S, Hk * D]``
+        rows, as :meth:`write_latent` does.  int8 pools quantize per written
+        slot per kv head (PR-10's ``quant_cast``), storing the scale in the
+        matching scale plane — indexed ``[layer, block, slot]`` as it
+        stands: a plane's ``[.., BS, Hk]`` tiles are far under a lane tile,
+        so the TPU keeps it NB-minor, and a reshape to rows would have
+        every layer relay out the whole plane."""
         B, S, Hk, D = k.shape
         pools = dict(self._mine(self.pools))
         NB, BS = pools["k"].shape[1:3]
@@ -611,6 +633,7 @@ class PagedKVView:
         slots = layer * (NB * BS) + slot
         for name, x in (("k", k), ("v", v)):
             pool = pools[name]
+            slot_shape = pool.shape[3:]             # (Hk, D) or (Hk * D,)
             flat = x.reshape(B * S, Hk, D)
             if self.quantized:
                 from automodel_tpu.ops.quant import INT8_MAX, quant_cast
@@ -622,8 +645,8 @@ class PagedKVView:
                     layer, slot // BS, slot % BS].set(sc)
             else:
                 flat = flat.astype(pool.dtype)
-            pools[name] = pool.reshape(-1, Hk, D).at[slots].set(
-                flat).reshape(pool.shape)
+            pools[name] = pool.reshape(-1, *slot_shape).at[slots].set(
+                flat.reshape(-1, *slot_shape)).reshape(pool.shape)
         if self.group is not None:
             return {**self.pools, self.group: pools}
         return pools

@@ -607,6 +607,156 @@ def test_paged_view_write_touches_its_layer_and_slots_only(quantized):
                                atol=0.04 if quantized else 0)
 
 
+@pytest.mark.parametrize("hk,dtype,quantized,slot", [
+    (4, "bfloat16", False, (4 * 128,)),
+    (8, "bfloat16", False, (8 * 128,)),
+    (16, "bfloat16", False, (16, 128)),
+    (4, "float32", False, (4 * 128,)),
+    (8, "float32", False, (8, 128)),
+    (4, "bfloat16", True, (4, 128)),
+    (16, "bfloat16", True, (16, 128)),
+], ids=["hk4-bf16", "hk8-bf16", "hk16-bf16", "hk4-f32", "hk8-f32",
+        "hk4-int8", "hk16-int8"])
+def test_pools_of_few_heads_are_stored_as_rows(hk, dtype, quantized, slot):
+    """A per-head plane whose kv heads are fewer than the dtype's sublane
+    packing (16 for bfloat16, 8 for float32) is stored as rows ``[L, NB,
+    BS, Hk * D]``; every other one, and every int8 pool (its scale planes
+    are indexed by head), as ``[L, NB, BS, Hk, D]``.  The latent plane is
+    untouched."""
+    from automodel_tpu.serving.kv_cache import init_paged_pools, pool_layout
+
+    pools = init_paged_pools(
+        num_layers=2, num_blocks=3, block_size=16, cache_dtype=dtype,
+        quantized=quantized, planes={"k": (hk, 128), "v": (hk, 128)})
+    assert pools["k"].shape == pools["v"].shape == (2, 3, 16, *slot)
+    assert pool_layout(pools) == ("rows" if len(slot) == 1 else "heads")
+    if quantized:
+        assert pools["k_scale"].shape == (2, 3, 16, hk)
+    latent = init_paged_pools(
+        num_layers=2, num_blocks=3, block_size=16, cache_dtype=dtype,
+        quantized=False, planes={"kv": (576,)})
+    assert latent["kv"].shape == (2, 3, 16, 640)
+    assert pool_layout(latent) == "latent"
+
+
+@pytest.mark.parametrize("rung", ["attention.paged_gather",
+                                  "attention.paged_decode"])
+@pytest.mark.parametrize("width", [1, 3])
+def test_a_pool_of_rows_writes_and_attends_as_a_pool_of_heads(
+        rung, width, monkeypatch):
+    """The same step written through ``PagedKVView`` into a pool stored as
+    rows and into one of heads: the rows pool holds the heads pool's bytes
+    in the same order, and attention over either (the XLA anchor, the
+    Pallas rung in interpret mode) gives the same output."""
+    from automodel_tpu.ops import paged_attention_kernel as pak
+    from automodel_tpu.ops.kernel_lib import registry
+    from automodel_tpu.serving.kv_cache import PagedKVView
+
+    monkeypatch.setattr(pak, "_INTERPRET", rung == "attention.paged_decode")
+    L, NB, BS, Hk, G, D, B, MB = 2, 9, 16, 4, 7, 128, 2, 4
+    rng = np.random.default_rng(3)
+    draw = lambda *s: jnp.asarray(rng.standard_normal(s, np.float32),
+                                  jnp.bfloat16)
+    heads = {n: draw(L, NB, BS, Hk, D) for n in "kv"}
+    rows = {n: p.reshape(L, NB, BS, Hk * D) for n, p in heads.items()}
+    tables = jnp.asarray([[3, 1, 4, 0], [2, 5, 6, 7]], jnp.int32)
+    ctx = np.asarray([2 * BS + 5, 3 * BS + 11], np.int32)
+    pos = jnp.asarray(ctx[:, None] - width + np.arange(width), jnp.int32)
+    slots = jnp.take_along_axis(tables, pos // BS, axis=1) * BS + pos % BS
+    q, k, v = draw(B, width, Hk * G, D), draw(B, width, Hk, D), draw(
+        B, width, Hk, D)
+
+    def step(pools):
+        view = PagedKVView(pools, tables, slots, jnp.asarray(ctx), pos,
+                           block_size=BS).at_layer(pools, jnp.int32(1))
+        written = view.write(k, v)
+        return written, view.attend(q, written)
+
+    before = registry.resolved_rungs().get(rung, 0)
+    (w_rows, o_rows), (w_heads, o_heads) = step(rows), step(heads)
+    assert registry.resolved_rungs()[rung] == before + 2
+    for n in "kv":
+        assert w_rows[n].shape == (L, NB, BS, Hk * D)
+        np.testing.assert_array_equal(
+            np.asarray(w_rows[n]).reshape(L, NB, BS, Hk, D),
+            np.asarray(w_heads[n]))
+    np.testing.assert_allclose(np.asarray(o_rows, np.float32),
+                               np.asarray(o_heads, np.float32),
+                               atol=2e-2, rtol=2e-2)
+
+
+@pytest.mark.parametrize("kv_heads,layout", [(16, "heads"), (2, "rows")])
+def test_the_engine_reports_its_pool_layout(kv_heads, layout):
+    """An OLMo toy with as many kv heads as a float32 tile has rows and
+    more keeps its pools as heads; with two it stores rows."""
+    from automodel_tpu.models.olmo2 import Olmo2Config, Olmo2ForCausalLM
+
+    cfg = Olmo2Config(vocab_size=256, hidden_size=128, intermediate_size=128,
+                      num_hidden_layers=2, num_attention_heads=16,
+                      num_key_value_heads=kv_heads, head_dim=8,
+                      max_position_embeddings=64)
+    model = Olmo2ForCausalLM(cfg, param_dtype=jnp.float32,
+                             compute_dtype=jnp.float32)
+    eng = DecodeEngine(model, model.abstract_params(),
+                       ServingConfig(kv_block_size=4, max_num_seqs=2,
+                                     max_model_len=16, num_kv_blocks=9))
+    assert eng.stats()["kv_layout"] == layout
+    assert eng.pools["k"].ndim == (5 if layout == "heads" else 4)
+
+
+def test_probabilities_keep_float32_accuracy_against_a_bfloat16_page():
+    """``p . v`` over a bfloat16 page takes ``p`` as its bfloat16 head plus
+    the remainder: within float32's reach of the exact product, where ``p``
+    rounded once to bfloat16 is a hundred times further off."""
+    from automodel_tpu.ops.paged_attention_kernel import _probs_times
+
+    rng = np.random.default_rng(5)
+    p = jnp.asarray(rng.uniform(0, 1, (7, 128)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((128, 128)), jnp.bfloat16)
+    exact = np.asarray(p, np.float64) @ np.asarray(v, np.float64)
+    err = lambda o: np.max(np.abs(np.asarray(o, np.float64) - exact)) \
+        / np.max(np.abs(exact))
+    once = jax.lax.dot_general(p.astype(jnp.bfloat16), v,
+                               (((1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+    assert err(_probs_times(p, v)) < 1e-5 < 1e-3 < err(once)
+
+
+# The kernel over a pool of heads (OLMo's 16 kv heads, every int8 pool) is
+# the one it was before pools of few heads were stored as rows: the digest
+# of its jaxpr, taken before that change, pins it instruction for
+# instruction, so the cells that serve such pools run the same program.
+_HEADS_KERNEL_JAXPR = {
+    (1, False): "db4f71db8ce4a68368c1d4cca9d111fd"
+                "b6f3d68fbbe0d627d77a7147e33b125e",
+    (32, False): "19d147372fd790848f22f5d7a72c107e"
+                 "465c22b6bfc54ae304d683d4ae50f405",
+    (1, True): "9b03823d3ea0a88d3b27ce0fdd59a544"
+               "5cc571f47f6bb60c063173ff6031e323",
+}
+
+
+@pytest.mark.parametrize("width,quantized", sorted(_HEADS_KERNEL_JAXPR),
+                         ids=["w1-bf16", "w1-int8", "w32-bf16"])
+def test_the_kernel_over_a_pool_of_heads_is_unchanged(width, quantized):
+    import hashlib
+    import re
+
+    from automodel_tpu.ops.paged_attention_kernel import paged_decode_pallas
+
+    B, Hq, Hk, D, BS, MB, L, NB = 4, 16, 16, 128, 16, 8, 2, 33
+    sd = jax.ShapeDtypeStruct
+    pool = sd((L, NB, BS, Hk, D), jnp.int8 if quantized else jnp.bfloat16)
+    scale = sd((L, NB, BS, Hk), jnp.float32) if quantized else None
+    text = str(jax.make_jaxpr(paged_decode_pallas)(
+        sd((B, width, Hq, D), jnp.bfloat16), pool, pool, scale, scale,
+        sd((), jnp.int32), sd((B, MB), jnp.int32), sd((B,), jnp.int32),
+        sd((B, width), jnp.int32)))
+    text = re.sub(r"name_and_src_info=\S+( at \S+)?", "", text)
+    assert hashlib.sha256(text.encode()).hexdigest() \
+        == _HEADS_KERNEL_JAXPR[(width, quantized)]
+
+
 def test_paged_chain_and_cpu_fallback(model_and_params):
     """Chain shape + the CPU probe contract: off-TPU, the engine's traffic
     resolves to the gather anchor; in interpret mode the Pallas rung

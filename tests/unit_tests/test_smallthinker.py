@@ -322,16 +322,29 @@ def test_the_model_declares_its_cache_per_group(world):
         ("full", 2, None), ("window", 6, WINDOW)]
     assert all(g.planes == {"k": (1, 16), "v": (1, 16)} for g in groups)
     eng = _engine(world)
-    assert eng.pools["full"]["k"].shape == (2, 4 * 16 + 1, BS, 1, 16)
+    # one kv head, fewer than a tile's rows: each group's pools are rows
+    assert eng.pools["full"]["k"].shape == (2, 4 * 16 + 1, BS, 16)
     # a window group resides fully at what a step of the widest width sees
     span = window_span_blocks(WINDOW, 4, BS)
     assert span == 4 and eng.pools["window"]["v"].shape == (
-        6, 4 * span + 1, BS, 1, 16)
+        6, 4 * span + 1, BS, 16)
     # a flat declaration is a cache of one unnamed group
     flat = cache_groups({"k": (2, 8), "v": (2, 8)}, 5)
     assert [(g.name, g.layers, g.window) for g in flat] == [(None, 5, None)]
     with pytest.raises(ValueError, match="do not cover"):
         cache_groups(model.paged_cache_planes(), 9)
+
+
+def test_the_engine_reports_rows_for_both_groups(world, caplog):
+    """The pools' layout is the engine's to report, by group, in
+    ``stats()`` and in its build log."""
+    import logging
+
+    with caplog.at_level(logging.INFO, logger="automodel_tpu.serving.engine"):
+        eng = _engine(world)
+    assert eng.stats()["kv_layout"] == {"full": "rows", "window": "rows"}
+    assert "paged KV cache layout: {'full': 'rows', 'window': 'rows'}" \
+        in caplog.text
 
 
 def test_window_arithmetic():

@@ -117,6 +117,57 @@ def test_paged_decode_at_the_serving_cells_shapes(width, record_property):
         f"paged_decode at the cell's shapes, width {width}"))
 
 
+@pytest.mark.parametrize("window", [None, 4096], ids=["full", "window"])
+def test_paged_decode_over_rows_at_the_smallthinker_cell(window,
+                                                        record_property):
+    """The kernel's body over a pool stored as rows (SmallThinker: 4 kv
+    heads of 128, seven query heads each, blocks of 128) at the cell's
+    shape, 48 rows over a 16k table, against the gather rung over the same
+    pool: a full layer, and a window layer whose entries behind the window
+    hold the null page as the engine's window group leaves them."""
+    from automodel_tpu.ops.paged_attention import (
+        _paged_gather_impl,
+        window_first_block,
+    )
+    from automodel_tpu.ops.paged_attention_kernel import paged_decode_pallas
+
+    B, Hq, Hk, D, BS, MB = 48, 28, 4, 128, 128, 128
+    rng = np.random.default_rng(39)
+    ctx = rng.integers(1, MB * BS, B)
+    ctx[:3] = MB * BS, 4096, 4097            # the table's end, the window's
+    ctx[-1] = 1                              # an idle row
+    need = -(-ctx // BS)
+    NB = int(need.sum()) + 1
+    free = rng.permutation(np.arange(1, NB))
+    tables = np.zeros((B, MB), np.int32)
+    for b in range(B):
+        tables[b, :need[b]], free = free[:need[b]], free[need[b]:]
+        if window:
+            tables[b, :window_first_block(int(ctx[b]) - 1, window, BS)] = 0
+    tables[-1] = 0
+
+    @jax.jit
+    def make(key):
+        kq, kk, kv = jax.random.split(key, 3)
+        pool = lambda k: jax.random.normal(
+            k, (1, NB, BS, Hk * D)).astype(jnp.bfloat16)
+        return (jax.random.normal(kq, (B, 1, Hq, D), jnp.bfloat16),
+                pool(kk), pool(kv))
+
+    q, k_pool, v_pool = make(jax.random.key(39))
+    args = (q, k_pool, v_pool, None, None, jnp.int32(0),
+            jnp.asarray(tables), jnp.asarray(ctx, jnp.int32),
+            jnp.asarray(ctx[:, None] - 1, jnp.int32))
+    out = jax.jit(lambda *a: paged_decode_pallas(
+        *a, local_window_size=window))(*args)
+    with jax.default_matmul_precision("highest"):
+        ref = jax.jit(lambda *a: _paged_gather_impl(
+            {}, *a, local_window_size=window))(*args)
+    record_property("max_err", parity._compare(
+        out, ref, parity.NATIVE_TOL["bfloat16"], True,
+        f"paged_decode over rows at the SmallThinker cell, window {window}"))
+
+
 @pytest.mark.parametrize("case", parity.chip_cases()["moe_decode.pallas"],
                          ids=lambda c: c["name"])
 def test_decode_experts_at_the_serving_cells_widths(case, record_property):
