@@ -80,7 +80,6 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 
 from automodel_tpu.distributed.shardings import constrain
 from automodel_tpu.models.layer_scan import (
@@ -93,13 +92,14 @@ from automodel_tpu.models.layer_scan import (
 from automodel_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 from automodel_tpu.ops.attention import attention
 from automodel_tpu.ops.moe import (
-    decode_expert_ffn,
     expert_ffn,
     group_and_capacity,
     group_tokens,
     held_experts_local,
     mask_padded_tokens,
     noaux_topk_routing,
+    routed_decode_block,
+    router_logits,
 )
 from automodel_tpu.ops.norms import rms_norm
 from automodel_tpu.ops.quant import maybe_qdot
@@ -479,12 +479,7 @@ class DeepseekV3ForCausalLM(LlamaForCausalLM):
         """Router hook: V3 sigmoid + aux-free bias correction; the V2
         family overrides with softmax gating."""
         cfg = self.config
-        # float32 in earnest: on a TPU a default-precision float32 product
-        # is one bfloat16 pass, and a selection among near-tied scores
-        # should not turn on that
-        scores = jax.nn.sigmoid(jnp.matmul(
-            xg.astype(jnp.float32), gate_p["kernel"].astype(jnp.float32),
-            precision=lax.Precision.HIGHEST))
+        scores = jax.nn.sigmoid(router_logits(xg, gate_p["kernel"]))
         return noaux_topk_routing(
             scores, gate_p["e_score_correction_bias"], k,
             n_group=cfg.n_group, topk_group=cfg.topk_group,
@@ -537,16 +532,11 @@ class DeepseekV3ForCausalLM(LlamaForCausalLM):
         B, S, H = x.shape
         k = cfg.num_experts_per_tok
         x2 = x.reshape(B * S, H)
-        with jax.named_scope("moe_router"):
-            weights, idx = self._held(*self._route(x2, p["gate"], k))
-            idx = jnp.where(valid.reshape(-1, 1), idx, cfg.n_held_experts)
-        with jax.named_scope("moe_experts"):
-            routed, counts = decode_expert_ffn(
-                x2, weights, idx,
-                experts["gate_proj"]["kernel"],
-                experts["up_proj"]["kernel"],
-                experts["down_proj"]["kernel"],
-                layer=at, compute_dtype=self.compute_dtype)
+        routed, counts = routed_decode_block(
+            x2, lambda: self._held(*self._route(x2, p["gate"], k)), valid,
+            experts["gate_proj"]["kernel"], experts["up_proj"]["kernel"],
+            experts["down_proj"]["kernel"], layer=at,
+            compute_dtype=self.compute_dtype)
         with jax.named_scope("moe_shared"):
             shared = self._dense_mlp(x, p["shared_experts"],
                                      "mlp.shared_experts")

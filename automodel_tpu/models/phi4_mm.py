@@ -432,17 +432,11 @@ class Phi4MMTextModel(LlamaForCausalLM):
     def _apply_rope(self, q, k, position_ids, inv_freq, rope_scale=1.0):
         from automodel_tpu.ops.rotary import apply_rope
 
-        rd = self._rotary_dim
-        if rd == q.shape[-1]:
-            return apply_rope(q, k, position_ids, inv_freq,
-                              attention_scaling=rope_scale)
         # Partial rotary: HF scales only the rotated channels (the pass-
         # through tail is concatenated unscaled).
-        q_rot, k_rot = apply_rope(q[..., :rd], k[..., :rd],
-                                  position_ids, inv_freq,
-                                  attention_scaling=rope_scale)
-        return (jnp.concatenate([q_rot, q[..., rd:]], axis=-1),
-                jnp.concatenate([k_rot, k[..., rd:]], axis=-1))
+        return apply_rope(q, k, position_ids, inv_freq,
+                          attention_scaling=rope_scale,
+                          rotary_dim=self._rotary_dim)
 
     def _decoder_layer(self, hidden, layer_params, position_ids, segment_ids,
                        attention_mask, inv_freq, adapters=None,

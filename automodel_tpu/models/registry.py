@@ -198,6 +198,15 @@ def _ensure_builtin() -> None:
                                SmallThinkerForCausalLM,
                                hf_io.smallthinker_key_map,
                                ["SmallThinkerForCausalLM"]))
+    from automodel_tpu.models.laguna import (
+        LagunaConfig,
+        LagunaForCausalLM,
+        laguna_key_map,
+    )
+
+    # gated attention in stacks by kind (head counts differ), routed experts
+    register_model(ModelFamily("laguna", LagunaConfig, LagunaForCausalLM,
+                               laguna_key_map, ["LagunaForCausalLM"]))
     from automodel_tpu.models.brumby import BrumbyConfig, BrumbyForCausalLM
 
     # Qwen3's block over power retention: no softmax attention, no KV cache

@@ -50,7 +50,6 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 
 from automodel_tpu.distributed.shardings import constrain
 from automodel_tpu.models.layer_scan import (
@@ -61,8 +60,9 @@ from automodel_tpu.models.layer_scan import (
 )
 from automodel_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 from automodel_tpu.ops.moe import (
-    decode_expert_ffn,
     moe_mlp_block,
+    routed_decode_block,
+    router_logits,
     topk_routing,
 )
 from automodel_tpu.ops.quant import quant_for
@@ -265,23 +265,17 @@ class SmallThinkerForCausalLM(LlamaForCausalLM):
                 dispatch=cfg.moe_dispatch,
                 quant=quant_for(self.quant, "block_sparse_moe.experts"),
                 router_input=u.astype(m.dtype), activation="relu")
-        with jax.named_scope("moe_router"):
-            # float32 in earnest: on a TPU a default-precision float32
-            # product is one bfloat16 pass, and a choice among near-tied
-            # logits should not turn on that
-            logits = jnp.matmul(
-                u.reshape(B * S, H),
-                router.astype(jnp.float32), precision=lax.Precision.HIGHEST)
+
+        def route():
+            logits = router_logits(u.reshape(B * S, H), router)
             weights, idx, _ = topk_routing(logits, k, norm_topk=True)
-            idx = jnp.where(valid.reshape(-1, 1), idx,
-                            cfg.moe_num_primary_experts)
-        with jax.named_scope("moe_experts"):
-            out, counts = decode_expert_ffn(
-                m.reshape(B * S, H), weights, idx,
-                experts["gate"]["kernel"], experts["up"]["kernel"],
-                experts["down"]["kernel"], layer=layer,
-                compute_dtype=self.compute_dtype, activation="relu",
-                quant=quant_for(self.quant, "block_sparse_moe.experts"))
+            return weights, idx
+
+        out, counts = routed_decode_block(
+            m.reshape(B * S, H), route, valid, experts["gate"]["kernel"],
+            experts["up"]["kernel"], experts["down"]["kernel"], layer=layer,
+            compute_dtype=self.compute_dtype, activation="relu",
+            quant=quant_for(self.quant, "block_sparse_moe.experts"))
         return out.reshape(B, S, H), counts
 
     def forward_embeds(

@@ -302,6 +302,15 @@ def paged_attention_cases() -> List[Dict]:
              window=24, released=True),
         dict(name="rows_f32_soft_cap", q_seq=1, dtype="float32", rows=True,
              soft_cap=30.0),
+        # nine and six query heads a kv head, decode and a chunk of 16
+        # (S * G = 144 and 96 rows): Laguna's window and full layers over
+        # one pool geometry, the window walk over released entries
+        *(dict(name=f"rows_g{g}_{kind}{'_window' if window else '_full'}",
+               q_seq=q_seq, dtype="bfloat16", rows=True, idle=True, B=3,
+               Hq=2 * g, Hk=2, BS=16, MB=6, window=window,
+               released=bool(window))
+          for g, window in ((9, 24), (6, None))
+          for kind, q_seq in (("decode", 1), ("chunk16", 16))),
     ]
 
 
@@ -880,6 +889,11 @@ def chip_cases() -> Dict[str, List[Dict]]:
     # engine stores its pools as rows (four kv heads fill a quarter tile)
     small = dict(B=8, Hq=28, Hk=4, D=128, BS=128, MB=128, L=2, rows=True)
     brumby = dict(B=16, Hq=40, Hk=8, L=2, layer=1, ctx=64)
+    # Laguna-S-2.1's cache as the serving cell holds it: eight kv heads
+    # (rows), blocks of 128, tables of 256 blocks (32,768 positions); nine
+    # query heads a kv head on window (512) layers, six on full layers, and
+    # the width-128 step that carries prefill chunks
+    laguna = dict(B=8, Hk=8, D=128, BS=128, MB=256, L=2, rows=True)
     mixtral_up = dict(m=4096, k=4096, n=14336, sizes=_ragged_sizes(4096, 8))
     mixtral_down = dict(m=4096, k=14336, n=4096, sizes=_ragged_sizes(4096, 8))
     moonlight_up = dict(m=4096, k=2048, n=1408, sizes=_ragged_sizes(4096, 64))
@@ -912,6 +926,13 @@ def chip_cases() -> Dict[str, List[Dict]]:
             dict(name="smallthinker_prefill_chunk64_window_released",
                  q_seq=64, dtype="bfloat16", window=4096, released=True,
                  **small),
+            *(dict(name=f"laguna_{kind}_w{q_seq}", q_seq=q_seq,
+                   dtype="bfloat16", Hq=hq, **extra, **laguna)
+              for q_seq in (1, 128)
+              for kind, hq, extra in (
+                  ("window_g9_released", 72,
+                   dict(window=512, released=True)),
+                  ("full_g6", 48, {}))),
         ],
         # Kimi-K2's latent plane as the serving cell holds it: 7 layers,
         # 576 values a token stored 640 wide, 64 heads, value 512, tables

@@ -545,6 +545,44 @@ def decode_expert_ffn(
         activation=activation, quant=quant)
 
 
+def router_logits(x: jnp.ndarray, kernel: jnp.ndarray) -> jnp.ndarray:
+    """``x @ kernel`` in float32 in earnest: on a TPU a default-precision
+    float32 product is one bfloat16 pass, and a choice among near-tied
+    logits should not turn on that."""
+    return jnp.matmul(x.astype(jnp.float32), kernel.astype(jnp.float32),
+                      precision=lax.Precision.HIGHEST)
+
+
+def routed_decode_block(
+    x: jnp.ndarray,           # [T, H] the experts' input, T = B * S
+    route,                    # () -> (weights, idx) [T, k], local ids
+    valid: jnp.ndarray,       # [B, S] bool: the step buffer's real columns
+    w_gate: jnp.ndarray,      # [L, E, H, I]: the held stacks of ALL layers
+    w_up: jnp.ndarray,
+    w_down: jnp.ndarray,
+    *,
+    layer,
+    compute_dtype: jnp.dtype = jnp.bfloat16,
+    activation: str = "silu",
+    quant=None,
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """A serving step's routed experts, under the scopes the benchmark's
+    readers name: ``moe_router`` runs ``route`` (the family's router, over
+    the ids local to the stacks) and sends a pad column's assignments to
+    the sentinel ``E``, so they join no expert; ``moe_experts`` runs
+    :func:`decode_expert_ffn` at ``layer``.  The shared expert, where a
+    family has one, stays the caller's (``moe_shared``).  Returns ``(out
+    [T, H], tokens_per_expert [E])``."""
+    with jax.named_scope("moe_router"):
+        weights, idx = route()
+        idx = jnp.where(valid.reshape(-1, 1), idx, w_gate.shape[1])
+    with jax.named_scope("moe_experts"):
+        return decode_expert_ffn(
+            x, weights, idx, w_gate, w_up, w_down,
+            layer=layer, compute_dtype=compute_dtype, activation=activation,
+            quant=quant)
+
+
 def _decode_loop_impl(request, x, weights, idx, w_gate, w_up, w_down, layer,
                       *, compute_dtype, activation, quant=None):
     """The ``moe_decode.loop`` rung (:func:`decode_expert_ffn`)."""

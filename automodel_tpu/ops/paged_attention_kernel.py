@@ -79,13 +79,14 @@ _NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
 # q lengths the fold-into-groups schedule stays profitable (and VMEM-sane)
-# for: decode (1), speculative verify (spec_k+1) and chunked prefill all
-# sit far below this; longer prefill belongs to the dense-attention path.
-_MAX_CHUNKED_Q = 64
+# for: decode (1), speculative verify (spec_k+1) and chunked prefill up to
+# a chunk of 128 (nine query heads a kv head fold to 1,152 rows, a tile of
+# 2.5 MB a kv head); longer prefill belongs to the dense-attention path.
+_MAX_CHUNKED_Q = 128
 
 
 def paged_decode_available(q_seq: int, head_dim: int) -> bool:
-    """Kernel path requires small queries (1 <= S <= 64 — decode, the
+    """Kernel path requires small queries (1 <= S <= 128 — decode, the
     speculative verify width, chunked prefill), a lane-aligned head dim,
     and TPU (or interpret mode)."""
     if not 1 <= q_seq <= _MAX_CHUNKED_Q or head_dim % _LANE:

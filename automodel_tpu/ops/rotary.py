@@ -155,11 +155,15 @@ def apply_rope(
     position_ids: jnp.ndarray,  # [B, S]
     inv_freq: jnp.ndarray,      # [D/2]
     attention_scaling: float = 1.0,
+    rotary_dim: Optional[int] = None,
 ):
     """Rotate q and k by position-dependent phases (HF half-split convention:
     the rotation pairs element i with element i + D/2).  ``attention_scaling``
     multiplies cos/sin (yarn mscale / longrope factor from
-    :func:`rope_parameters`)."""
+    :func:`rope_parameters`).  ``rotary_dim`` (partial rotary; ``inv_freq``
+    then has ``rotary_dim / 2`` entries) rotates the first that many
+    channels of each head, pairing i with i + rotary_dim/2, and passes the
+    rest through unscaled."""
     angles = position_ids[..., None].astype(jnp.float32) * inv_freq  # [B, S, D/2]
     cos = jnp.cos(angles)[:, :, None, :]  # [B, S, 1, D/2]
     sin = jnp.sin(angles)[:, :, None, :]
@@ -174,6 +178,9 @@ def apply_rope(
         # f32 and casting at the end — round-5 profiling found the f32
         # [B, S, Hq, D] rope intermediates materialized at 2x traffic in
         # every scan iteration (fwd + remat recompute).
+        if rotary_dim is not None and rotary_dim != x.shape[-1]:
+            return jnp.concatenate(
+                [rot(x[..., :rotary_dim]), x[..., rotary_dim:]], axis=-1)
         x1, x2 = jnp.split(x, 2, axis=-1)
         x1f, x2f = x1.astype(jnp.float32), x2.astype(jnp.float32)
         return jnp.concatenate(

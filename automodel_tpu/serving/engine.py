@@ -1072,11 +1072,14 @@ class DecodeEngine:
         self.scheduler.advance(plan)
         if self.grouped:
             # the keys one layer of each block group had to read this step
-            # (the context, or what of it the group's window still shows)
+            # (the context, or what of it the group's window still shows),
+            # and the (query, key) pairs it had to compute
             timers.event("serve_kv_read", step=step, rows=len(active),
                          positions=positions,
                          **{f"{name}_keys": n for name, n in
-                            self.scheduler.keys_read(plan).items()})
+                            self.scheduler.keys_read(plan).items()},
+                         **{f"{name}_pairs": n for name, n in
+                            self.scheduler.pairs_read(plan).items()})
         if self.state_planes:
             resets = sum(1 for w in active if w.start_pos == 0)
             timers.event("serve_state", step=step, rows=len(active),

@@ -366,6 +366,17 @@ class StepPlan:
         return [r for r in self.rows if r is not None]
 
 
+def _seen(start: int, n: int, window: Optional[int]) -> int:
+    """Keys the queries at positions ``start .. start + n - 1`` see, summed:
+    ``p + 1`` each, or ``min(p + 1, window)``."""
+    lo, hi = start + 1, start + n           # what each sees without a window
+    if window is None or hi <= window:
+        return (lo + hi) * n // 2
+    if lo > window:
+        return window * n
+    return (lo + window) * (window - lo + 1) // 2 + window * (hi - window)
+
+
 class Scheduler:
     """Admission + step assembly + preemption over ``max_num_seqs`` slots."""
 
@@ -775,6 +786,14 @@ class Scheduler:
         ctx = [w.start_pos + len(w.tokens) for w in plan.active]
         return {g.name: sum(c if g.window is None else min(c, g.window)
                             for c in ctx) for g in self.groups}
+
+    def pairs_read(self, plan: "StepPlan") -> Dict[Optional[str], int]:
+        """Per block group, the (query, key) pairs one of its layers must
+        compute in ``plan``'s step: over the active rows and their new
+        positions, the keys each one sees (all before it and itself, or
+        what of them the group's window shows)."""
+        return {g.name: sum(_seen(w.start_pos, len(w.tokens), g.window)
+                            for w in plan.active) for g in self.groups}
 
     def _allocate(self, req: Request, new_total: int) -> None:
         """Grow every group's table of ``req`` to ``new_total`` positions,

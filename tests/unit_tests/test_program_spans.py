@@ -404,6 +404,30 @@ def test_kernels_keep_their_instruction_names_under_scopes(one_chip):
     assert "/attn_core/paged_decode/" in scope
 
 
+@pytest.mark.parametrize("hq,window", [(72, 512), (48, None)],
+                         ids=["g9_window", "g6_full"])
+@pytest.mark.parametrize("width", [1, 128])
+def test_the_paged_kernel_compiles_at_laguna_widths(one_chip, hq, window,
+                                                    width):
+    """Nine (window) and six (full) query heads a kv head over a pool of
+    eight kv heads stored as rows, blocks of 128, 32k positions, at decode
+    and at the width-128 step: the chip's compiler takes both bodies."""
+    from automodel_tpu.ops import paged_attention_kernel
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    B, D, BS, MB, NB = 24, 128, 128, 256, 2560
+    text = jax.jit(lambda *a: paged_attention_kernel.paged_decode_pallas(
+        *a[:3], None, None, *a[3:], local_window_size=window)).lower(
+        spec((B, width, hq, D), jnp.bfloat16),
+        spec((2, NB, BS, 8 * D), jnp.bfloat16),
+        spec((2, NB, BS, 8 * D), jnp.bfloat16), spec((), jnp.int32),
+        spec((B, MB), jnp.int32), spec((B,), jnp.int32),
+        spec((B, width), jnp.int32)).compile().as_text()
+    assert len(_kernels(text)) == 1
+
+
 # -- the serving step updates its pools in place ------------------------------
 # The stacked KV pools ride the layer scan as carry: each layer scatters into
 # them and the paged kernel reads them at a prefetched layer index.  As the
@@ -728,3 +752,88 @@ def test_retention_step_updates_its_state_planes_in_place(
     assert not _pool_sized_moves(text, pools), _pool_sized_moves(text, pools)
     assert compiled.memory_analysis().temp_size_in_bytes \
         < pool_bytes(pools) / 4
+
+
+# -- gated attention in stacks by kind: the gate's scope, the pairs counted --
+# Laguna's step runs a per-head output gate inside each kind's attention
+# scope, and stamps per block group the (query, key) pairs one layer
+# computed, which the benchmark's chunked paged roofline reads.
+def _gated_model():
+    from automodel_tpu.models.laguna import LagunaConfig, LagunaForCausalLM
+
+    cfg = LagunaConfig(
+        vocab_size=256, hidden_size=64, intermediate_size=96,
+        num_hidden_layers=5, num_attention_heads=4, num_key_value_heads=2,
+        head_dim=16, num_experts=8, num_experts_per_tok=2,
+        moe_intermediate_size=32, shared_expert_intermediate_size=32,
+        mlp_only_layers=(0,), gating="per-head", sliding_window=8,
+        layer_types=("full_attention",) + ("sliding_attention",) * 3
+        + ("full_attention",), num_attention_heads_per_layer=(4, 6, 6, 6, 4),
+        max_position_embeddings=128)
+    model = LagunaForCausalLM(cfg, param_dtype=jnp.float32,
+                              compute_dtype=jnp.float32, remat=False)
+    return model, model.init(jax.random.key(0))
+
+
+def test_gated_step_names_the_gate_under_both_attention_scopes():
+    model, params = _gated_model()
+    eng = DecodeEngine(model, params, ServingConfig(
+        kv_block_size=4, max_num_seqs=2, max_model_len=64, prefill_chunk=4))
+    for width in (1, 4):
+        names = _op_names(eng.step_fn(width).lower(
+            eng.params, eng.pools, *eng._assemble(
+                StepPlan(rows=[None] * 2, step_width=width))
+        ).compile().as_text())
+        for kind in ("attn_full", "attn_window"):
+            assert any(f"/{kind}/attn_gate/" in n for n in names), kind
+        for scope in ("moe_router", "moe_experts", "moe_shared",
+                      "dense_mlp"):
+            assert any(f"/{scope}/" in n for n in names), scope
+
+
+def test_kv_read_counts_the_pairs_each_group_computes():
+    """Two rows, a window of 8, chunks of 4: each step's ``full_pairs`` and
+    ``window_pairs`` are the keys each new position sees, summed over the
+    rows; a hand count on the step whose chunk crosses the window."""
+    model, params = _gated_model()
+    timers, seen, plans = Timers(), [], []
+    real = timers.event
+    timers.event = lambda name, **st: (seen.append((name, st)),
+                                       real(name, **st))[1]
+    eng = DecodeEngine(model, params, ServingConfig(
+        kv_block_size=4, max_num_seqs=2, max_model_len=64, prefill_chunk=4),
+        timers=timers, generation=GenerationConfig(
+            max_new_tokens=4, do_sample=False, eos_token_id=None))
+    eng.lookahead = 0
+    inner = eng.scheduler.schedule
+
+    def schedule(*a, **k):
+        plan = inner(*a, **k)
+        if plan is not None and plan.active:
+            plans.append([(w.start_pos, len(w.tokens)) for w in plan.active])
+        return plan
+
+    eng.scheduler.schedule = schedule
+    rng = np.random.default_rng(2)
+    for n in (11, 3):
+        eng.submit(rng.integers(1, 255, n).tolist(), max_new_tokens=4)
+    eng.run()
+    reads = [st for name, st in seen if name == "serve_kv_read"]
+    assert len(reads) == len(plans) == eng.steps_run
+    for rows, st in zip(plans, reads):
+        assert st["full_pairs"] == sum(p + 1 for s, n in rows
+                                       for p in range(s, s + n))
+        assert st["window_pairs"] == sum(min(p + 1, 8) for s, n in rows
+                                         for p in range(s, s + n))
+    # the first step: positions 0-3 of one row and 0-2 of the other
+    assert plans[0] == [(0, 4), (0, 3)]
+    assert (reads[0]["full_pairs"], reads[0]["window_pairs"]) == (16, 16)
+    # the prompt's last chunk, positions 8-10: 9 + 10 + 11 keys a full
+    # layer, 8 each a window layer
+    at = next(i for i, rows in enumerate(plans) if (8, 3) in rows)
+    other = sum(p + 1 for s, n in plans[at] if (s, n) != (8, 3)
+                for p in range(s, s + n))
+    other_w = sum(min(p + 1, 8) for s, n in plans[at] if (s, n) != (8, 3)
+                  for p in range(s, s + n))
+    assert reads[at]["full_pairs"] == 30 + other
+    assert reads[at]["window_pairs"] == 24 + other_w
